@@ -27,16 +27,10 @@ from .plan import transitions as T
 from .types import Schema, StructField, from_arrow
 
 
-# one shared owner of the jax persistent-cache config dance: engine,
+# one shared owner of jax's persistent-cache configuration: engine,
 # bench.py children and the executor worker bootstrap all call this, so
 # the cache knobs cannot drift between entry points
 from .utils.compile_cache import enable_compilation_cache  # noqa: E402
-
-
-def _enable_compilation_cache(path: str) -> None:
-    """Back-compat alias (platform-gated: TPU-backed processes only;
-    see utils/compile_cache.py for the rationale)."""
-    enable_compilation_cache(path, force=False)
 
 
 class TpuSession:
@@ -61,7 +55,12 @@ class TpuSession:
         self._serve_lock = threading.Lock()
         self._finish_lock = threading.Lock()
         self._lazy_lock = threading.RLock()  # runtime/cluster first touch
-        _enable_compilation_cache(self.conf.get(C.COMPILATION_CACHE_DIR))
+        # the cache gate asks which backend is in use, which initializes
+        # it: a multi-host process must have joined the coordination
+        # service by then (no-op without a coordinator)
+        from .parallel.mesh import init_distributed
+        init_distributed(self.conf)
+        enable_compilation_cache(self.conf.get(C.COMPILATION_CACHE_DIR))
         # post-mortem plane (metrics/bundle.py, docs/monitoring.md):
         # armed only on the DRIVER (executor workers set ring.PROCESS_ROLE
         # before building their session) and only when a bundle dir is

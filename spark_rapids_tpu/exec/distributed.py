@@ -45,10 +45,12 @@ from ..metrics import names as MN
 
 
 def resolve_mesh(conf) -> Optional["jax.sharding.Mesh"]:
-    """Mesh from session conf, or None when disabled/unavailable.
+    """Mesh from session conf, or None when disabled.
 
     `spark.rapids.sql.tpu.mesh.devices` = 0 disables; N > 1 requires N
-    local devices (power of two, so sharded capacities divide evenly)."""
+    devices (power of two, so sharded capacities divide evenly) and
+    raises when fewer exist: a mesh that was asked for never quietly
+    becomes a single-chip run."""
     from .. import config as C
     from ..parallel.mesh import init_distributed
     n = conf.get(C.MESH_DEVICES)
@@ -61,8 +63,11 @@ def resolve_mesh(conf) -> Optional["jax.sharding.Mesh"]:
     # multi-host: join the coordination service BEFORE enumerating devices
     # so jax.devices() is the global pod list (no-op without a coordinator)
     init_distributed(conf)
-    if len(jax.devices()) < n:
-        return None  # planner falls back to single-chip execution
+    have = len(jax.devices())
+    if have < n:
+        raise RuntimeError(
+            f"{C.MESH_DEVICES.key}={n} but jax reports {have} "
+            f"{jax.devices()[0].platform} device(s)")
     return make_mesh(n)
 
 

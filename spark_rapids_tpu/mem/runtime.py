@@ -34,25 +34,33 @@ from .stores import (BufferCatalog, DeviceMemoryStore, DiskStore,
 
 
 def _detect_hbm_bytes() -> int:
-    """Total device memory of the first accelerator, if discoverable."""
+    """Total device memory of the first device, from its own
+    memory_stats().  On the tpu platform missing stats are an error: a
+    guessed pool would mis-size the accounting against the real chip.
+    Backends without device memory (the CPU reports no stats) account
+    against a nominal 16GiB."""
+    import jax
+    dev = jax.devices()[0]
+    on_tpu = dev.platform == "tpu"
     try:
-        import jax
-        dev = jax.devices()[0]
-        stats = dev.memory_stats()
-        if stats:
-            for key in ("bytes_limit", "bytes_reservable_limit"):
-                if key in stats and stats[key]:
-                    return int(stats[key])
-    except Exception as e:  # noqa: BLE001 — any backend may lack stats
-        # on real hardware a silent 16GiB default mis-sizes the accounted
-        # pool against the actual chip: make the downgrade observable
+        stats = dev.memory_stats() or {}
+    except Exception as e:  # noqa: BLE001 — a backend may lack stats
+        if on_tpu:
+            raise
         from ..metrics.registry import count_swallowed
         count_swallowed("numHbmDetectFallbacks", "spark_rapids_tpu.mem",
-                        "device memory_stats unavailable (%r); defaulting "
-                        "pool sizing to 16GiB — set "
-                        "spark.rapids.memory.tpu.poolSizeBytes explicitly "
-                        "on real hardware", e, warn=True)
-    return 16 << 30  # v5e-class default when stats are unavailable
+                        "device memory_stats unavailable (%r); sizing the "
+                        "accounted pool from the nominal 16GiB", e,
+                        warn=True)
+        stats = {}
+    for key in ("bytes_limit", "bytes_reservable_limit"):
+        if stats.get(key):
+            return int(stats[key])
+    if on_tpu:
+        raise RuntimeError(
+            f"{dev} reports no memory limit (memory_stats()={stats!r}); "
+            "set spark.rapids.memory.tpu.poolSizeBytes explicitly")
+    return 16 << 30
 
 
 def configured_pool_bytes(conf) -> int:

@@ -19,7 +19,7 @@ peak attribution, spill cascade chains (triggering reservation site +
 victim buffer ids), churn ratio, victim quality, and the headroom
 estimate (metrics/memledger.py).
 `python -m spark_rapids_tpu.metrics roofline <journal-dir>
-[--platform cpu|tpu] [--json]` — reconstruct each query journal's
+[--device-kind KIND] [--json]` — reconstruct each query journal's
 roofline-attribution ledger offline (metrics/roofline.py): operator
 spans give measured per-node SELF seconds, the finish-time metric dumps
 give each node's cost declaration (bytes per resource + estimated
@@ -446,24 +446,24 @@ def roofline_main(argv) -> int:
     from .roofline import ledger_from_events, platform_peaks, render
     from .timeline import load_journal_dir
     usage = ("usage: python -m spark_rapids_tpu.metrics roofline "
-             "<journal-dir> [--platform cpu|tpu] [--json]")
-    platform = None
+             "<journal-dir> [--device-kind KIND] [--json]")
+    device_kind = None
     as_json = False
     jdir = None
     i = 0
     while i < len(argv):
         arg = argv[i]
-        if arg == "--platform":
+        if arg == "--device-kind":
             if i + 1 >= len(argv):
                 print(usage, file=sys.stderr)
                 return 2
-            platform = argv[i + 1]
-            from .roofline import known_platforms
-            if platform not in known_platforms():
-                # an unknown name would silently fall back to CPU peaks
-                # and skew every utilization figure ~40x
-                print(f"unknown --platform {platform!r}; expected one "
-                      f"of {'/'.join(known_platforms())}",
+            device_kind = argv[i + 1]
+            from .roofline import known_device_kinds
+            if device_kind not in known_device_kinds():
+                # another device's peaks would skew every utilization
+                # figure by orders of magnitude
+                print(f"unknown --device-kind {device_kind!r}; expected "
+                      f"one of {' / '.join(known_device_kinds())}",
                       file=sys.stderr)
                 return 2
             i += 2
@@ -484,7 +484,7 @@ def roofline_main(argv) -> int:
     if not shards:
         print(f"no query-*.jsonl journals in {jdir}", file=sys.stderr)
         return 1
-    peaks = platform_peaks(platform)
+    peaks = platform_peaks(device_kind)
     reports = []
     for sh in shards:
         rows = ledger_from_events(sh["events"], peaks)

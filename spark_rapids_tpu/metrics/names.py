@@ -536,14 +536,6 @@ NUM_CODEC_RESELECTIONS = register_metric(
 # Process-wide counters for swallowed-failure sites that have no operator
 # Metrics object in scope; every TPU006 fix pairs a log line with one of
 # these so the silence is observable (docs/lint.md).
-NUM_PALLAS_FALLBACKS = register_metric(
-    "numPallasFallbacks", COUNTER, ESSENTIAL,
-    "pallas kernel BUILDS that raised at jit-trace time and compiled "
-    "the XLA lowering instead (exec/aggregate.py _masked_cumsum) — "
-    "counted once per compiled (shape, dtype) program, not per batch: "
-    "the fallback is baked into the cached program, so EVERY later "
-    "execution of that kernel replays it; any nonzero value on real "
-    "chips means the hand-written kernel is not actually running")
 NUM_NATIVE_TEARDOWN_ERRORS = register_metric(
     "numNativeTeardownErrors", COUNTER, ESSENTIAL,
     "native address-space allocator handles whose destroy failed at "
@@ -555,9 +547,9 @@ NUM_WORKER_STDOUT_NOISE = register_metric(
     "is crashing before announcing)")
 NUM_HBM_DETECT_FALLBACKS = register_metric(
     "numHbmDetectFallbacks", COUNTER, ESSENTIAL,
-    "runtimes that could not read device memory_stats and fell back to "
-    "the v5e-class 16GiB default pool size (mem/runtime.py) — on real "
-    "hardware this means the accounted pool is NOT sized to the chip")
+    "runtimes on a non-tpu backend whose memory_stats() raised and that "
+    "sized the accounted pool from the nominal 16GiB (mem/runtime.py); "
+    "on the tpu platform missing stats raise instead")
 NUM_SCAN_PRUNE_STAT_ERRORS = register_metric(
     "numScanPruneStatErrors", COUNTER, ESSENTIAL,
     "predicate-pushdown stat computations that raised, keeping the row "

@@ -76,41 +76,48 @@ def set_cost_accounting(on: bool) -> None:
 def cost_accounting_enabled() -> bool:
     return _COST_ACCOUNTING[0]
 
-# Nominal per-platform peaks: bytes/s for byte resources, ops/s for
-# flops.  TPU figures are v5e-class (819 GB/s HBM, PCIe-class link,
-# ~197 TFLOP/s bf16 halved for f32); CPU figures are one-core-container
-# ballpark.  All overridable via spark.rapids.sql.tpu.roofline.peak*
+# Per-device peaks, keyed by `device_kind` as jax reports it: bytes/s for
+# byte resources, ops/s for flops.  An unknown kind RAISES: utilization
+# against another chip's peaks is a wrong number, not an estimate.
+#   "TPU v5 lite" (v5e): 819 GB/s HBM, 197 TFLOP/s bf16 and 1,600 Gbit/s
+#   (200 GB/s) chip-to-chip interconnect are the published figures
+#   (Google Cloud documentation, "TPU v5e"); the host link and socket
+#   wire rows are nominal (not published, not measured here).
+#   "cpu" is the host backend's own row (its device_kind), a ballpark
+#   for offline analysis and the CPU test environment.
+# All overridable via spark.rapids.sql.tpu.roofline.peak*
 # (docs/tuning-guide.md) — the ledger's RANKING is robust to peak error,
 # the absolute utilization percentages are only as good as the peaks.
-_PLATFORM_PEAKS: Dict[str, Dict[str, float]] = {
-    "tpu": {"hbm": 819e9, "h2d": 8e9, "d2h": 8e9, "wire": 1e9,
-            "ici": 100e9, "flops": 98e12},
+_DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"hbm": 819e9, "h2d": 8e9, "d2h": 8e9, "wire": 1e9,
+                    "ici": 200e9, "flops": 197e12},
     "cpu": {"hbm": 20e9, "h2d": 20e9, "d2h": 20e9, "wire": 1e9,
             "ici": 20e9, "flops": 50e9},
 }
 
 
-def known_platforms() -> tuple:
-    return tuple(sorted(_PLATFORM_PEAKS))
+def known_device_kinds() -> tuple:
+    return tuple(sorted(_DEVICE_PEAKS))
 
 
-def detect_platform() -> str:
-    try:
-        import jax
-        return jax.devices()[0].platform
-    except Exception:  # noqa: BLE001 — offline analysis has no backend
-        return "cpu"
+def detect_device_kind() -> str:
+    import jax
+    return jax.devices()[0].device_kind
 
 
-def platform_peaks(platform: Optional[str] = None,
+def platform_peaks(device_kind: Optional[str] = None,
                    conf=None) -> Dict[str, float]:
     """Per-resource peaks (bytes/s, flops/s) for the ledger's
-    denominators: the platform's nominal table, with any nonzero
+    denominators: the device kind's table row, with any nonzero
     spark.rapids.sql.tpu.roofline.peak* conf override applied."""
-    if platform is None:
-        platform = detect_platform()
-    base = _PLATFORM_PEAKS.get(platform, _PLATFORM_PEAKS["cpu"])
-    peaks = dict(base)
+    if device_kind is None:
+        device_kind = detect_device_kind()
+    if device_kind not in _DEVICE_PEAKS:
+        raise KeyError(
+            f"no roofline peaks for device kind {device_kind!r} (known: "
+            f"{', '.join(known_device_kinds())}); add its published "
+            "figures to metrics/roofline.py _DEVICE_PEAKS")
+    peaks = dict(_DEVICE_PEAKS[device_kind])
     if conf is not None:
         from .. import config as C
         overrides = {

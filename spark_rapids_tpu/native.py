@@ -4,12 +4,17 @@ The reference's host hot paths live in C++ (RMM allocator, libcudf host
 scaffolding, UCX); ours live in libtpu_host_runtime.so: best-fit
 address-space allocator, spill file I/O, multi-threaded row gather, Spark
 murmur3 batch hashing.  The library is compiled on first use with the
-image's g++ and cached next to its source; every caller has a pure-Python
-fallback, so a missing toolchain degrades performance, never correctness.
+image's g++ into native/, named by a hash of its source so a copied or
+updated tree can never load a stale binary (the binary is not tracked by
+git).  Every caller has a pure-Python fallback, so a missing toolchain
+degrades performance, never correctness — and says so once at WARNING.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -18,7 +23,6 @@ import numpy as np
 
 _ROOT = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
-_LIB_PATH = os.path.join(_ROOT, "libtpu_host_runtime.so")
 _SRC_PATH = os.path.join(_ROOT, "src", "host_runtime.cpp")
 
 _lock = threading.Lock()
@@ -26,15 +30,35 @@ _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC_PATH, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_ROOT, f"libtpu_host_runtime.{digest}.so")
+
+
+def _build(lib_path: str) -> None:
+    # per-pid temp + atomic replace: test workers and executor processes
+    # may all find the library missing at once
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-             "-o", _LIB_PATH, _SRC_PATH],
-            check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
+             "-o", tmp, _SRC_PATH],
+            check=True, capture_output=True, timeout=300)
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(os.path.join(_ROOT, "libtpu_host_runtime.*.so")):
+        if stale != lib_path:
+            os.unlink(stale)
+
+
+def _load():
+    lib_path = _lib_path()
+    if not os.path.exists(lib_path):
+        _build(lib_path)
+    return ctypes.CDLL(lib_path)
 
 
 def get_lib():
@@ -44,14 +68,15 @@ def get_lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH) or (
-                os.path.exists(_SRC_PATH)
-                and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_LIB_PATH)):
-            if not os.path.exists(_SRC_PATH) or not _build():
-                return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+            lib = _load()
+        except (OSError, subprocess.SubprocessError) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            logging.getLogger("spark_rapids_tpu.native").warning(
+                "native host runtime unavailable (%r %s); using the "
+                "pure-Python fallbacks: allocator, spill I/O, row gather, "
+                "hashing and the parquet/orc/csv decoders run slower",
+                e, detail.decode(errors="replace")[-500:])
             return None
         lib.asalloc_create.restype = ctypes.c_void_p
         lib.asalloc_create.argtypes = [ctypes.c_int64]

@@ -30,10 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map  # jax>=0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..columnar import Column, ColumnarBatch
 from ..ops.hashing import hash_columns_double
@@ -370,7 +367,10 @@ def distributed_aggregate_partial_step(agg, mesh: Mesh,
                 else default_quota(state.capacity, n)
             gathered, overflow = exchange_compact(state, bucket, q, axis)
         merged = agg._merge_kernel(gathered)
-        ng = jax.lax.pmax(jnp.sum(merged.sel.astype(jnp.int32)), axis)
+        # int32 accumulator: under x64 a plain sum widens to int64, and
+        # XLA:TPU lowers 64-bit all-reduces for Sum only (a pmax over
+        # s64 is refused: "Supported lowering only of Sum all reduce")
+        ng = jax.lax.pmax(jnp.sum(merged.sel, dtype=jnp.int32), axis)
         return merged, overflow, ng
 
     return shard_map(step, mesh=mesh, in_specs=(P(axis),),
@@ -384,7 +384,10 @@ def distributed_aggregate_combine_step(agg, mesh: Mesh,
     (state, partial) -> (merged state at concat capacity, max_groups)."""
     def step(a: ColumnarBatch, b: ColumnarBatch):
         merged = agg._merge_kernel(_concat_local(a, b, agg._state_schema))
-        ng = jax.lax.pmax(jnp.sum(merged.sel.astype(jnp.int32)), axis)
+        # int32 accumulator: under x64 a plain sum widens to int64, and
+        # XLA:TPU lowers 64-bit all-reduces for Sum only (a pmax over
+        # s64 is refused: "Supported lowering only of Sum all reduce")
+        ng = jax.lax.pmax(jnp.sum(merged.sel, dtype=jnp.int32), axis)
         return merged, ng
 
     return shard_map(step, mesh=mesh, in_specs=(P(axis), P(axis)),

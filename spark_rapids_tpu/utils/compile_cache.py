@@ -1,74 +1,78 @@
 """Shared persistent-XLA-compilation-cache setup.
 
-ONE idempotent helper owns the `jax_compilation_cache_dir` /
-`jax_persistent_cache_*` config dance so the knobs cannot drift between
-call sites: `engine.TpuSession` (platform-gated), the serving tier's
-QueryScheduler (a restarted server replays kernels from disk), the
-executor worker bootstrap (shuffle/worker.py), and bench.py's children
-(force=True — the bench explicitly wants warm compiles on every backend
-it measures, including its CPU oracle).
+ONE idempotent helper owns jax's persistent-cache configuration so the
+knobs cannot drift between call sites: `engine.TpuSession`, the serving
+tier's QueryScheduler (a restarted server replays kernels from disk), the
+executor worker bootstrap (shuffle/worker.py) and bench.py's children.
 
-Platform gate rationale (force=False): compiles on a TPU backend cost
-tens of seconds and replay byte-identically, but XLA:CPU AOT replay
-warns about machine-feature mismatches (SIGILL risk) and the CPU test
-environment already fights compile-cache memory pressure — so on a
-CPU-only process the cache stays off unless the caller forces it.
+Where the cache lives (the path is part of jax's cache key, so a
+directory that moves never hits):
 
-Re-pointing: the active directory is re-pointable within a process — a
-server picking up a conf change (or a test pointing at a tmpdir) calls
-enable_compilation_cache with the new path and jax follows.  The old
-module-global latch made the first path sticky forever, which silently
-kept a stale directory; `active_cache_dir()` reports what is actually in
-effect and `reset_for_tests()` restores the pristine state.
+  * `JAX_COMPILATION_CACHE_DIR` set in the environment: jax reads the
+    variable itself; this helper leaves its handling alone and only
+    lowers the write thresholds.  No code re-points the directory.
+  * unset: the caller's path (`spark.rapids.sql.tpu.compilationCache.dir`)
+    or, when that is empty, `.jax_cache/` at the root of this checkout.
+
+Backend gate (force=False): on an accelerator a compile costs seconds to
+minutes (64-bit sorts and scans compile slowest, see CHANGES.md PR 22)
+and replays byte-identically, but XLA:CPU AOT replay warns about
+machine-feature mismatches and the CPU test environment already fights
+compile-cache memory pressure — so when the backend IN USE is the CPU
+the cache stays off unless the caller forces it.  The gate asks the
+backend itself: environment variables are empty on a machine that simply
+has a chip.
+
+Re-pointing (environment variable unset): a server picking up a conf
+change, or a test pointing at a tmpdir, calls enable_compilation_cache
+with the new path and jax follows.  `active_cache_dir()` reports what is
+in effect and `reset_for_tests()` restores the pristine state.
 """
 from __future__ import annotations
 
+import os
 import threading
 from typing import Optional
 
-# the path this process's jax config currently points at (None = cache
-# never enabled by this helper); the lock serializes concurrent enables
-# from scheduler construction vs. worker first-touch (TPU009)
+#: fixed default inside the checkout (listed in .gitignore)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+# the directory this process's cache is known to use (None = never
+# enabled by this helper); the lock serializes concurrent enables from
+# scheduler construction vs. worker first-touch (TPU009)
 _STATE = {"path": None}
 _STATE_LOCK = threading.Lock()
 
 
-def enable_compilation_cache(path: str, force: bool = False) -> bool:
-    """Point jax's persistent compilation cache at `path` (idempotent
-    per path, best-effort; returns True when THIS call enabled or
-    re-pointed the cache).  Keyed by HLO hash, shared across processes:
-    a second session replays every kernel this one compiled."""
-    if not path:
-        return False
-    if _STATE["path"] == path:
+def enable_compilation_cache(path: str = "", force: bool = False) -> bool:
+    """Turn jax's persistent compilation cache on (idempotent per
+    directory; returns True when THIS call enabled or re-pointed it).
+    Keyed by HLO hash, shared across processes: a second session replays
+    every kernel this one compiled."""
+    import jax
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    target = env_dir or path or DEFAULT_CACHE_DIR
+    if _STATE["path"] == target:
         return False  # already in effect — idempotent fast path
-    try:
-        import os
-
-        import jax
-        if not force:
-            platforms = jax.config.jax_platforms \
-                or os.environ.get("JAX_PLATFORMS", "")
-            if not platforms or platforms == "cpu":
-                # NOT latched: a later force=True call (bench child) may
-                # still enable the cache in this process
-                return False
-        with _STATE_LOCK:
-            if _STATE["path"] == path:
-                return False  # a concurrent enabler won the race
-            jax.config.update("jax_compilation_cache_dir", path)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1)
-            _STATE["path"] = path
-        return True
-    except Exception:
-        return False  # an optimization, never a dependency
+    if not force and jax.default_backend() == "cpu":
+        # NOT latched: a later force=True call (bench child) may still
+        # enable the cache in this process
+        return False
+    with _STATE_LOCK:
+        if _STATE["path"] == target:
+            return False  # a concurrent enabler won the race
+        if not env_dir:
+            jax.config.update("jax_compilation_cache_dir", target)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
+        _STATE["path"] = target
+    return True
 
 
 def active_cache_dir() -> Optional[str]:
-    """The directory this helper last pointed jax at, or None."""
+    """The directory this helper last enabled, or None."""
     return _STATE["path"]
 
 
@@ -77,8 +81,7 @@ def reset_for_tests() -> None:
     next enable_compilation_cache() call can re-point cleanly from a
     known state."""
     _STATE["path"] = None
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:  # pragma: no cover — jax may be torn down
-        pass  # tpulint: disable=TPU006 best-effort detach in test teardown; the latch above is already cleared
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir", None)

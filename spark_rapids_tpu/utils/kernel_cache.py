@@ -39,8 +39,8 @@ _STAGE_EXECUTABLES_MAX = 512
 # XLA cost analysis of each compiled whole-stage program, keyed like
 # _STAGE_EXECUTABLES (pruned with it): {"flops": float, "bytes": float,
 # "source": "hlo"} — the roofline ledger's per-stage cost declaration
-# (metrics/roofline.py).  Empty dict when the AOT path (and therefore
-# Compiled.cost_analysis) was unavailable for the program.
+# (metrics/roofline.py).  Empty dict when the backend exposes no
+# Compiled.cost_analysis for the program.
 _STAGE_COSTS: Dict[tuple, dict] = {}
 
 # process-wide counters bench.py's fusion/serve stages read (stats()):
@@ -76,11 +76,16 @@ def stats() -> Dict[str, int]:
 
 
 def input_signature(args) -> tuple:
-    """Static (shape, dtype) signature of a pytree of arguments — the
-    shape-bucket key of a whole-stage executable."""
+    """Static (shape, dtype, placement) signature of a pytree of arguments
+    — the shape-bucket key of a whole-stage executable.  Placement is part
+    of it because an AOT executable is compiled for its inputs' shardings
+    and REJECTS the same shapes on another device: after a mesh exchange
+    partition i lives on device i, and every device needs its own
+    executable (a jitted function would re-specialize by itself)."""
     leaves = jax.tree_util.tree_flatten(args)[0]
     return tuple((tuple(getattr(x, "shape", ())),
-                  str(getattr(x, "dtype", type(x).__name__)))
+                  str(getattr(x, "dtype", type(x).__name__)),
+                  getattr(x, "sharding", None))
                  for x in leaves)
 
 
@@ -92,8 +97,8 @@ def stage_executable(key: tuple, builder: Callable[[], Callable],
     On a cache miss the program is traced, lowered and compiled EXPLICITLY
     (jax AOT API) so the build is observable: numStageCompiles /
     stageCompileTime on `metrics` and a `compile` journal event with the
-    trace-vs-compile time split.  Falls back to a plain jitted function if
-    the AOT API is unavailable.  Returns a callable taking *args.
+    trace-vs-compile time split.  A compile error surfaces with the
+    stage's name.  Returns a callable taking *args.
 
     `donate_argnums` lowers the program with input/output buffer aliasing
     on those argument positions (mem/donation.py owns the safety proof —
@@ -109,7 +114,6 @@ def stage_executable(key: tuple, builder: Callable[[], Callable],
             _STAGE_EXECUTABLES.move_to_end(k)
             _COUNTERS["stage_hits"] += 1
             return fn
-    aot = True
     from ..metrics import names as MN
     from ..metrics.journal import journal_event
     timer = (metrics.timer(MN.STAGE_COMPILE_TIME) if metrics is not None
@@ -119,27 +123,24 @@ def stage_executable(key: tuple, builder: Callable[[], Callable],
     if timer is not None:
         timer.__enter__()
     try:
-        try:
-            traced = jfn.trace(*args)
-            t_traced = time.perf_counter()
-            lowered = traced.lower()
-        except AttributeError:  # older jax: lower() traces internally
-            lowered = jfn.lower(*args)
-            t_traced = time.perf_counter()
+        traced = jfn.trace(*args)
+        t_traced = time.perf_counter()
+        lowered = traced.lower()
         t_lowered = time.perf_counter()
         fn = lowered.compile()
         t_compiled = time.perf_counter()
-    except Exception:
-        # AOT path unavailable for this program/backend: the jitted
-        # function is the executable (compile happens on first call,
-        # folded into the timer by the caller's first dispatch)
-        fn = jfn
-        aot = False
-        t_traced = t_lowered = t_compiled = time.perf_counter()
+    except Exception as e:
+        # a program the compiler refuses surfaces with the stage's name
+        # on it (type kept: the OOM ladder keys on MemoryError) — never a
+        # quiet retreat to a lazily-jitted function that would hit the
+        # same refusal at first dispatch, unnamed
+        e.add_note(f"while building whole-stage program {name!r} for the "
+                   f"{jax.default_backend()} backend")
+        raise
     finally:
         if timer is not None:
             timer.__exit__(None, None, None)
-    cost = _extract_cost_analysis(fn) if aot else {}
+    cost = _extract_cost_analysis(fn)
     with _CACHE_LOCK:
         _COUNTERS["stage_compiles"] += 1
     if metrics is not None:
@@ -186,7 +187,7 @@ def stage_cost(key: tuple, args: tuple,
     """The XLA cost analysis recorded when stage_executable compiled the
     program for (key, signature-of-args) — same key mangling, so a caller
     that just dispatched can attribute the dispatch's HLO-derived cost.
-    {} when unknown (evicted, AOT-less backend, never compiled)."""
+    {} when unknown (evicted, never compiled, no cost analysis)."""
     if donate_argnums:
         key = key + ("donate", tuple(donate_argnums))
     k = (key, input_signature(args))

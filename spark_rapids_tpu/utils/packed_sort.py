@@ -33,8 +33,7 @@ are static).
 
 A Pallas tiled bitonic variant (`ops/pallas_kernels.bitonic_sort_u64`)
 can take the single-pass sort when `spark.rapids.sql.tpu.pallas.enabled`
-is on; any pallas failure (64-bit emulation on current chips, CPU
-backend) falls back to `lax.sort` per call, like the cumsum kernel.
+is on and the backend is a TPU; a kernel that fails to lower raises.
 """
 from __future__ import annotations
 
@@ -79,17 +78,12 @@ def plan_passes(total_bits: int, cap: int) -> int:
 
 
 def _sort_words(keys):
-    """Single-operand u64 sort, optionally through the Pallas tiled
-    bitonic network (gated; any failure falls back to lax.sort)."""
+    """Single-operand u64 sort, through the Pallas tiled bitonic network
+    when the flag is on and the backend is a TPU (a kernel that fails to
+    lower raises: the flag never quietly means lax.sort)."""
     if _PALLAS_SORT[0] and jax.default_backend() == "tpu":
         from ..ops.pallas_kernels import bitonic_sort_u64
-        try:
-            return bitonic_sort_u64(keys)
-        except Exception as e:  # noqa: BLE001 — any pallas failure falls back
-            from ..metrics.registry import count_swallowed
-            count_swallowed("numPallasFallbacks", "spark_rapids_tpu.pallas",
-                            "pallas bitonic_sort_u64 failed (%r); using "
-                            "lax.sort", e)
+        return bitonic_sort_u64(keys)
     return jax.lax.sort(keys, dimension=0, is_stable=False)
 
 

@@ -1,0 +1,259 @@
+"""Main-path programs compile for a described (not attached) TPU v5e.
+
+The chip's compiler is installed here and compiles for a topology that is
+only described, so these tests guard every PR at no chip time: what XLA:TPU
+refuses (f64->int bitcasts, 64-bit all-reduces other than Sum, programs too
+big for 16 GiB) fails HERE, not minutes into a chip call.  A compile that
+passes is not a chip run; `chip_smoke.py` is.
+
+How the programs are obtained: chip_smoke.py's own queries run once through
+`TpuSession` on the CPU backend at the smoke's size (TPC-H SF1 widths), with
+`jax.default_backend` steered to "tpu" so the engine takes its TPU branches
+and `jax.jit` wrapped so every program is recorded with its argument
+shapes; each test then lowers one recorded program for the described chip.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the topology
+is described inside a module-scoped fixture that skips when it cannot be,
+never at import; everything compiles in the test's own process; the
+persistent compilation cache is off around the compiles; no other test file
+describes a topology.
+"""
+from __future__ import annotations
+
+import functools
+import os
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+SMOKE_ROWS = 6_000_000          # chip_smoke.LINEITEM_ROWS
+HBM_BYTES = 16 << 30            # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it is locked
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    """Nine engine sites ask `jax.default_backend()` and would see `cpu`
+    while lowering here: steer them from the test, not through an option
+    of the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+class _Recorded(NamedTuple):
+    """One jitted program as the engine called it."""
+    name: str
+    jitted: object      # the jax.jit object
+    args: tuple         # argument pytrees, arrays as ShapeDtypeStructs
+    kwargs: dict
+
+    def rows(self) -> int:
+        dims = [x.shape[0] for x in jax.tree_util.tree_leaves(
+            (self.args, self.kwargs)) if getattr(x, "shape", ())]
+        return max(dims or [0])
+
+
+def _shapes(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+        if hasattr(a, "shape") and hasattr(a, "dtype") else a, tree)
+
+
+def _recording_jit(records):
+    real_jit = jax.jit
+
+    class Recorder:
+        def __init__(self, fun, **kw):
+            inner = fun.func if isinstance(fun, functools.partial) else fun
+            self._name = getattr(inner, "__qualname__", repr(inner))
+            self._jit = real_jit(fun, **kw)
+
+        def _record(self, a, k):
+            records.append(_Recorded(self._name, self._jit,
+                                     _shapes(a), _shapes(k)))
+
+        def __call__(self, *a, **k):
+            self._record(a, k)
+            return self._jit(*a, **k)
+
+        def trace(self, *a, **k):      # kernel_cache.stage_executable's AOT
+            self._record(a, k)
+            return self._jit.trace(*a, **k)
+
+        def __getattr__(self, n):
+            return getattr(self._jit, n)
+
+    return lambda fun, **kw: Recorder(fun, **kw)
+
+
+@pytest.fixture(scope="module")
+def smoke_programs(topo):
+    """{query: [programs]} recorded from chip_smoke.py's q6 and join query
+    at SF1 widths, run on the CPU with the engine on its TPU branches."""
+    import chip_smoke
+    from benchmarks.tpch import bulk
+    from spark_rapids_tpu.columnar.contiguous import pack_batch
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.utils import kernel_cache
+    from spark_rapids_tpu.utils.scan_cache import MEMORY_SCAN_CACHE
+
+    def forget_compiled():
+        # kernels are cached by structural key, which does not know the
+        # backend gate: CPU-branch kernels must not leak into the
+        # recording, nor TPU-branch ones out of it
+        kernel_cache.clear()
+        jax.clear_caches()
+        MEMORY_SCAN_CACHE.clear()
+
+    records: dict = {}
+    mp = pytest.MonkeyPatch()
+    forget_compiled()
+    # the session is made BEFORE the steering: its compile-cache gate asks
+    # the backend too, and this process must not start persisting XLA:CPU
+    # executables
+    session = TpuSession(chip_smoke.base_conf())
+    try:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        lineitem = bulk.make_lineitem(SMOKE_ROWS, seed=22,
+                                      n_orders=SMOKE_ROWS // 4)
+        orders = bulk.make_orders(SMOKE_ROWS // 4, seed=22)
+        li, od = session.from_arrow(lineitem), session.from_arrow(orders)
+        for name, query in (("q6", lambda: bulk.q6(li)),
+                            ("q3_join", lambda: bulk.q3_shape(li, od))):
+            records[name] = []
+            mp.setattr(jax, "jit", _recording_jit(records[name]))
+            assert query().collect()
+        # the contiguous pack (shuffle/spill/broadcast unit) of one
+        # reader batch of orders: ints, plus a double column for the
+        # f32-pair branch
+        from spark_rapids_tpu.columnar import ColumnarBatch
+        records["pack"] = []
+        mp.setattr(jax, "jit", _recording_jit(records["pack"]))
+        batch = ColumnarBatch.from_arrow(
+            lineitem.select(["l_orderkey", "l_shipdate",
+                             "l_extendedprice"]).slice(0, 1 << 20))
+        pack_batch(batch)
+    finally:
+        mp.undo()
+        forget_compiled()
+    return records
+
+
+def _compile_for_chip(prog: _Recorded, sharding):
+    place = functools.partial(
+        jax.tree_util.tree_map,
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+        if isinstance(a, jax.ShapeDtypeStruct) else a)
+    compiled = prog.jitted.lower(*place(prog.args),
+                                 **place(prog.kwargs)).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
+    return compiled
+
+
+def _largest(programs, name_part):
+    """The recorded program of that kind with the most rows: the shape
+    the smoke's full-capacity batches take."""
+    hits = [p for p in programs if name_part in p.name]
+    assert hits, (name_part, sorted({p.name for p in programs}))
+    return max(hits, key=_Recorded.rows)
+
+
+@pytest.mark.parametrize("query,kernel,min_rows", [
+    # scan -> filter -> aggregate over all six 1M-row batches, one program
+    ("q6", "whole", 1 << 20),
+    # the join's fused window+count kernel over a full probe batch
+    ("q3_join", "_probe_kernel", 1 << 20),
+    # the 64-bit sort: packed u64 keys, revenue (a double: the f32-pair
+    # keys, where the f64 comparator took 9 minutes) DESC then o_orderdate
+    ("q3_join", "TpuSortExec", 1 << 20),
+    # the contiguous pack of a full reader batch (f64 leaves as f32 pairs)
+    ("pack", "pack_batch", 1 << 20),
+])
+def test_smoke_program_compiles_for_v5e(smoke_programs, one_chip,
+                                        no_persistent_cache, tpu_branches,
+                                        query, kernel, min_rows):
+    prog = _largest(smoke_programs[query], kernel)
+    assert prog.rows() >= min_rows, \
+        f"{prog.name} recorded at {prog.rows()} rows: not the smoke's size"
+    _compile_for_chip(prog, one_chip)
+
+
+def test_f64_bitcast_is_what_the_tpu_branches_avoid(one_chip,
+                                                    no_persistent_cache):
+    """The reason for the nine `jax.default_backend()` gates, pinned: the
+    day XLA:TPU takes an f64->int bitcast this fails and they can go."""
+    x = jax.ShapeDtypeStruct((1024,), jnp.float64, sharding=one_chip)
+    with pytest.raises(Exception, match="X64"):
+        jax.jit(lambda v: jax.lax.bitcast_convert_type(v, jnp.int64)) \
+            .lower(x).compile()
+
+
+def _pallas_cumsum(sharding):
+    from spark_rapids_tpu.ops.pallas_kernels import cumsum_1d
+    x = jax.ShapeDtypeStruct((1 << 20,), jnp.int32, sharding=sharding)
+    return jax.jit(cumsum_1d).lower(x)
+
+
+def _pallas_seg_agg(sharding):
+    from spark_rapids_tpu.ops.pallas_kernels import seg_agg_1d
+    gid = jax.ShapeDtypeStruct((1 << 20,), jnp.int32, sharding=sharding)
+    val = jax.ShapeDtypeStruct((1 << 20,), jnp.float32, sharding=sharding)
+    return jax.jit(lambda g, v: seg_agg_1d(g, [v], ["sum"])).lower(gid, val)
+
+
+def _pallas_bitonic(sharding):
+    from spark_rapids_tpu.ops.pallas_kernels import bitonic_sort_u64
+    keys = jax.ShapeDtypeStruct((1 << 20,), jnp.uint64, sharding=sharding)
+    return jax.jit(bitonic_sort_u64).lower(keys)
+
+
+@pytest.mark.parametrize("lower", [
+    pytest.param(_pallas_cumsum, id="cumsum_1d", marks=pytest.mark.xfail(
+        strict=True, reason="Unimplemented primitive in Pallas TPU lowering "
+        "for KernelType.TC: cumsum")),
+    pytest.param(_pallas_seg_agg, id="seg_agg_1d", marks=pytest.mark.xfail(
+        strict=True, reason="Unimplemented primitive in Pallas TPU lowering "
+        "for KernelType.TC: dynamic_slice")),
+    pytest.param(_pallas_bitonic, id="bitonic_sort_u64",
+                 marks=pytest.mark.xfail(
+                     strict=True, reason="Unimplemented primitive in Pallas "
+                     "TPU lowering for KernelType.TC: rev")),
+])
+def test_pallas_kernel_lowers_for_v5e(one_chip, no_persistent_cache, lower):
+    """The three Pallas kernels have only ever run with interpret=True: the
+    installed Pallas TPU lowering refuses each before Mosaic is reached.
+    Strict xfail: the day one lowers, the mark has to come off (and
+    spark.rapids.sql.tpu.pallas.enabled means something on a chip)."""
+    lower(one_chip).compile()

@@ -20,7 +20,6 @@ from spark_rapids_tpu.plan.logical import col, functions as f, lit  # noqa: E402
 
 MESH_CONF = {"spark.rapids.sql.tpu.mesh.devices": "8"}
 
-from conftest import needs_pcast  # noqa: E402 — shared capability gate
 
 
 def _plan_str(session, df):
@@ -68,11 +67,13 @@ class TestDistributedPlanning:
         q = df.group_by("k").agg(f.sum(col("v")).alias("s"))
         assert "Distributed" not in _plan_str(s, q)
 
-    def test_mesh_larger_than_devices_falls_back(self):
+    def test_mesh_larger_than_devices_is_an_error(self):
+        # a mesh that was asked for never quietly runs on one chip
         s = TpuSession({"spark.rapids.sql.tpu.mesh.devices": "64"})
         df = gen_df(s, seed=1, n=100, k=T.IntegerType, v=T.LongType)
         q = df.group_by("k").agg(f.sum(col("v")).alias("s"))
-        assert "Distributed" not in _plan_str(s, q)
+        with pytest.raises(RuntimeError, match="mesh.devices=64"):
+            _plan_str(s, q)
 
     def test_non_pow2_mesh_rejected(self):
         s = TpuSession({"spark.rapids.sql.tpu.mesh.devices": "6"})
@@ -109,7 +110,6 @@ class TestDistributedExecution:
                     .group_by("k").agg(f.sum(col("v2")).alias("s")))
         assert_tpu_and_cpu_are_equal(q, conf=MESH_CONF)
 
-    @needs_pcast
     @pytest.mark.parametrize("how", ["inner", "left", "left_semi",
                                      "left_anti"])
     def test_join_types(self, how):
@@ -121,7 +121,6 @@ class TestDistributedExecution:
             q, conf={**MESH_CONF,
                      "spark.sql.autoBroadcastJoinThreshold": "-1"})
 
-    @needs_pcast
     def test_join_then_agg_distributed(self):
         def q(s):
             a = gen_df(s, seed=16, n=1000, k=T.IntegerType, v=T.LongType)
@@ -165,7 +164,6 @@ class TestDistributedExecution:
         tpu = run(dict(MESH_CONF))
         assert_rows_equal(cpu, tpu, ignore_order=True, approx_float=True)
 
-    @needs_pcast
     def test_tpch_q3_on_mesh(self):
         """Joins + aggregate + sort through the mesh planner."""
         from benchmarks.tpch import QUERIES, load_tables

@@ -32,7 +32,6 @@ STREAM_CONF = {
     "spark.rapids.sql.variableFloatAgg.enabled": "true",
 }
 
-from conftest import needs_pcast  # noqa: E402 — shared capability gate
 
 
 def test_streaming_agg_multi_chunk_matches_oracle():
@@ -65,7 +64,6 @@ def test_streaming_agg_many_groups():
     assert_tpu_and_cpu_are_equal(q, conf=STREAM_CONF)
 
 
-@needs_pcast
 def test_streaming_join_multi_chunk_matches_oracle():
     conf = {**STREAM_CONF, "spark.sql.autoBroadcastJoinThreshold": "-1"}
 
@@ -76,7 +74,6 @@ def test_streaming_join_multi_chunk_matches_oracle():
     assert_tpu_and_cpu_are_equal(q, conf=conf)
 
 
-@needs_pcast
 def test_streaming_left_join_and_semi():
     conf = {**STREAM_CONF, "spark.sql.autoBroadcastJoinThreshold": "-1"}
 
@@ -93,7 +90,6 @@ def test_streaming_left_join_and_semi():
     assert_tpu_and_cpu_are_equal(semi, conf=conf)
 
 
-@needs_pcast
 def test_streaming_agg_then_join_query():
     """Composed query: distributed agg feeding a distributed join, both
     streaming."""

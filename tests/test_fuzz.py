@@ -237,23 +237,12 @@ def test_fuzz_distributed_mesh(seed):
     """A smaller SPMD tier: the same random plans through the 8-device
     mesh planner (distributed agg/join/sort swap in where eligible)."""
     cpu = _run(seed + 1000, {"spark.rapids.sql.enabled": "false"})
-    try:
-        tpu = _run(seed + 1000, {
-            "spark.rapids.sql.variableFloatAgg.enabled": "true",
-            "spark.rapids.sql.tpu.mesh.devices": "8",
-            "spark.rapids.sql.tpu.mesh.inputChunkRows": "256",
-            "spark.rapids.sql.reader.batchSizeRows": "128",
-            "spark.sql.autoBroadcastJoinThreshold": "-1"})
-    except AttributeError as e:
-        # capability gate (known seed failure): a random plan that draws
-        # a distributed join needs jax.lax.pcast (exec/join.py _pvary),
-        # absent in this env's jax — same gate as tests/test_parallel.py
-        import jax
-        if "pcast" in str(e) and not hasattr(jax.lax, "pcast"):
-            pytest.skip("jax.lax.pcast unavailable in jax "
-                        f"{jax.__version__}; this seed's plan lowers a "
-                        "distributed join")
-        raise
+    tpu = _run(seed + 1000, {
+        "spark.rapids.sql.variableFloatAgg.enabled": "true",
+        "spark.rapids.sql.tpu.mesh.devices": "8",
+        "spark.rapids.sql.tpu.mesh.inputChunkRows": "256",
+        "spark.rapids.sql.reader.batchSizeRows": "128",
+        "spark.sql.autoBroadcastJoinThreshold": "-1"})
     try:
         assert_rows_equal(cpu, tpu, ignore_order=True, approx_float=True)
     except AssertionError as e:

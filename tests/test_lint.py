@@ -730,19 +730,46 @@ def test_count_swallowed_logs_and_counts(caplog):
     ENGINE_COUNTERS.reset()
 
 
+class _BoomDev:
+    platform = "cpu"
+
+    def memory_stats(self):
+        raise RuntimeError("no stats on this backend")
+
+
+class _BoomTpu(_BoomDev):
+    platform = "tpu"
+
+
+class _EmptyTpu(_BoomTpu):
+    def memory_stats(self):
+        return None
+
+
 def test_hbm_detect_fallback_counts(monkeypatch):
     from spark_rapids_tpu.mem import runtime as rt
     from spark_rapids_tpu.metrics.registry import ENGINE_COUNTERS
-
-    class _BoomDev:
-        def memory_stats(self):
-            raise RuntimeError("no stats on this backend")
 
     import jax
     before = ENGINE_COUNTERS.get("numHbmDetectFallbacks")
     monkeypatch.setattr(jax, "devices", lambda: [_BoomDev()])
     assert rt._detect_hbm_bytes() == 16 << 30
     assert ENGINE_COUNTERS.get("numHbmDetectFallbacks") == before + 1
+
+
+@pytest.mark.parametrize("dev", [_BoomTpu, _EmptyTpu])
+def test_hbm_detect_on_tpu_raises_instead_of_guessing(monkeypatch, dev):
+    """On the tpu platform a pool sized from a guess would hide the
+    device: missing memory_stats() is an error there."""
+    from spark_rapids_tpu.mem import runtime as rt
+    from spark_rapids_tpu.metrics.registry import ENGINE_COUNTERS
+
+    import jax
+    before = ENGINE_COUNTERS.get("numHbmDetectFallbacks")
+    monkeypatch.setattr(jax, "devices", lambda: [dev()])
+    with pytest.raises(RuntimeError):
+        rt._detect_hbm_bytes()
+    assert ENGINE_COUNTERS.get("numHbmDetectFallbacks") == before
 
 
 # --------------------------------------------------------------------------

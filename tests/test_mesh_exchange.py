@@ -483,3 +483,32 @@ def test_aggregate_over_exchange_parity():
     _tiers(q, extra={**MULTI,
                      "spark.rapids.sql.variableFloatAgg.enabled": "true"},
            check_counters=False)
+
+
+def test_stage_executable_is_keyed_by_placement():
+    """After a mesh exchange partition i lives on device i.  An AOT
+    executable is compiled for its inputs' shardings and rejects the same
+    shapes on another device ("compiled for input shardings that
+    disagree"), so placement is part of the whole-stage cache key: found
+    by the 4-device rehearsal of chip_smoke.py at SF1, where partitions
+    0 and 1 first had equal capacities."""
+    import jax
+    import numpy as np
+
+    from spark_rapids_tpu.utils import kernel_cache as KC
+    key = ("placement-regression",)
+    before = KC.stats()["stage_compiles"]
+    outs = []
+    for d in jax.devices()[:2]:
+        x = jax.device_put(np.arange(8.0), d)
+        fn = KC.stage_executable(key, lambda: (lambda v: v * 2), (x,),
+                                 name="placement")
+        outs.append(fn(x))
+    assert [next(iter(o.devices())).id for o in outs] == \
+        [d.id for d in jax.devices()[:2]]
+    assert KC.stats()["stage_compiles"] == before + 2
+    # same device again: a hit, not a third compile
+    x = jax.device_put(np.arange(8.0), jax.devices()[1])
+    KC.stage_executable(key, lambda: (lambda v: v * 2), (x,),
+                        name="placement")(x)
+    assert KC.stats()["stage_compiles"] == before + 2

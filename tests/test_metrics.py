@@ -343,3 +343,20 @@ def test_bench_observability_shape():
                 "wire_bytes_sent", "wire_bytes_received", "queries"):
         assert key in obs and isinstance(obs[key], int), key
     assert obs["queries"] >= 1
+
+
+def test_lazy_fold_takes_scalars_from_several_devices():
+    """After a mesh exchange partition i's row count lives on device i;
+    the fold must not stack scalars across devices (found by the
+    4-device rehearsal of chip_smoke.py at SF1: the fold raised
+    "incompatible devices" and the query's metrics were lost)."""
+    import jax
+    import numpy as np
+
+    from spark_rapids_tpu.metrics import names as MN
+    from spark_rapids_tpu.metrics.registry import Metrics
+    m = Metrics()
+    for i, d in enumerate(jax.devices()[:4]):
+        m.add_lazy(MN.NUM_OUTPUT_ROWS, jax.device_put(np.int32(10 + i), d))
+    m.add_lazy(MN.NUM_OUTPUT_ROWS, jax.numpy.int32(5))
+    assert m.values[MN.NUM_OUTPUT_ROWS] == 10 + 11 + 12 + 13 + 5

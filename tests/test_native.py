@@ -86,3 +86,35 @@ def test_spill_tier_uses_native_io(tmp_path):
     rt.host_store.synchronous_spill(0)
     assert rt.catalog.lookup_tier(bid) == StorageTier.DISK
     assert rt.get_batch(bid).to_pylist() == [(i,) for i in range(500)]
+
+
+def test_library_is_named_by_source_hash_and_untracked():
+    """A copied tree must never load a stale binary: the library's name
+    carries its source's hash, and git does not track it."""
+    import hashlib
+    import os
+    with open(N._SRC_PATH, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert os.path.basename(N._lib_path()) == \
+        f"libtpu_host_runtime.{digest}.so"
+    assert os.path.exists(N._lib_path())
+
+
+def test_missing_toolchain_warns_once_and_falls_back(monkeypatch, caplog):
+    import logging
+
+    def no_toolchain():
+        raise OSError("g++: command not found")
+    monkeypatch.setattr(N, "_load", no_toolchain)
+    monkeypatch.setattr(N, "_lib", None)
+    monkeypatch.setattr(N, "_tried", False)
+    with caplog.at_level(logging.WARNING, logger="spark_rapids_tpu.native"):
+        assert N.get_lib() is None
+        assert N.get_lib() is None  # latched: no second attempt, no spam
+    warns = [r for r in caplog.records if "pure-Python fallbacks" in
+             r.getMessage()]
+    assert len(warns) == 1
+    # the fallbacks still answer
+    out = N.gather_rows(np.arange(10, dtype=np.int64),
+                        np.array([3, 1], dtype=np.int32))
+    assert out.tolist() == [3, 1]

@@ -25,8 +25,6 @@ from spark_rapids_tpu.parallel.mesh import (DATA_AXIS, make_mesh,
 
 N_DEV = 8
 
-from conftest import needs_pcast  # noqa: E402 — shared capability gate
-
 
 @pytest.fixture(scope="module")
 def mesh():
@@ -308,7 +306,6 @@ def _join_oracle(lk, lv, rk, rv, join_type):
     return sorted(rows, key=lambda r: tuple((x is None, x) for x in r))
 
 
-@needs_pcast
 @pytest.mark.parametrize("join_type", ["inner", "left", "left_semi",
                                        "left_anti"])
 def test_distributed_join_matches_oracle(mesh, join_type):
@@ -328,7 +325,6 @@ def test_distributed_join_matches_oracle(mesh, join_type):
     assert got == want
 
 
-@needs_pcast
 def test_distributed_join_retry_on_skew(mesh):
     """One hot key: max_dup must grow via the retry loop, result stays exact."""
     nl, nr, cap = 64, 256, 256

@@ -99,9 +99,13 @@ def _q1(df):
 
 def test_platform_peaks_defaults_and_conf_override():
     cpu = RL.platform_peaks("cpu")
-    tpu = RL.platform_peaks("tpu")
+    tpu = RL.platform_peaks("TPU v5 lite")
     assert set(RL.RESOURCES) <= set(cpu) and set(RL.RESOURCES) <= set(tpu)
     assert tpu["hbm"] == pytest.approx(819e9)
+    assert tpu["flops"] == pytest.approx(197e12)
+    # a device that is not in the table is an error, not a default
+    with pytest.raises(KeyError, match="TPU v9"):
+        RL.platform_peaks("TPU v9")
     s = _session({"spark.rapids.sql.tpu.roofline.peakHbmGBs": "123.5",
                   "spark.rapids.sql.tpu.roofline.peakWireGBs": "2.5"})
     over = RL.platform_peaks("cpu", conf=s.conf)
@@ -220,7 +224,7 @@ def test_offline_roofline_cli_matches_live_ledger(tmp_path):
                    os.path.abspath(__file__))))
     proc = subprocess.run(
         [sys.executable, "-m", "spark_rapids_tpu.metrics", "roofline",
-         jdir, "--platform", "cpu", "--json"],
+         jdir, "--device-kind", "cpu", "--json"],
         capture_output=True, text=True, env=env, timeout=180)
     assert proc.returncode == 0, proc.stderr
     rep = json.loads(proc.stdout)

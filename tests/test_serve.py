@@ -622,8 +622,9 @@ def test_concurrent_journals_stay_per_query(tmp_path):
         s.shutdown_serving()
 
 
-def test_compile_cache_repoint_and_reset(tmp_path):
+def test_compile_cache_repoint_and_reset(tmp_path, monkeypatch):
     from spark_rapids_tpu.utils import compile_cache as CC
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     CC.reset_for_tests()
     a, b = str(tmp_path / "a"), str(tmp_path / "b")
     try:
@@ -637,11 +638,52 @@ def test_compile_cache_repoint_and_reset(tmp_path):
         assert CC.active_cache_dir() == b
         import jax
         assert jax.config.jax_compilation_cache_dir == b
-        # platform gate still holds without force on a CPU process
+        # no path given: the fixed directory inside the checkout
+        assert CC.enable_compilation_cache(force=True)
+        assert CC.active_cache_dir() == CC.DEFAULT_CACHE_DIR
+        assert os.path.dirname(CC.DEFAULT_CACHE_DIR) == os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))
+        # backend gate still holds without force on a CPU process
         CC.reset_for_tests()
         assert not CC.enable_compilation_cache(a, force=False)
         assert CC.active_cache_dir() is None
     finally:
+        CC.reset_for_tests()
+
+
+@pytest.mark.parametrize("backend,force", [("tpu", False), ("cpu", True)])
+def test_compile_cache_gate_asks_the_backend(tmp_path, monkeypatch,
+                                             backend, force):
+    """A machine with a chip sets neither jax_platforms nor JAX_PLATFORMS:
+    the gate must ask the backend in use, not the environment."""
+    import jax
+    from spark_rapids_tpu.utils import compile_cache as CC
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    CC.reset_for_tests()
+    try:
+        assert CC.enable_compilation_cache(str(tmp_path), force=force)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        CC.reset_for_tests()
+
+
+def test_compile_cache_leaves_env_dir_to_jax(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself; the helper
+    never re-points the directory, whatever path the conf carries."""
+    import jax
+    from spark_rapids_tpu.utils import compile_cache as CC
+    CC.reset_for_tests()
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    try:
+        assert CC.enable_compilation_cache(str(tmp_path / "conf"),
+                                           force=True)
+        assert CC.active_cache_dir() == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         CC.reset_for_tests()
 
 

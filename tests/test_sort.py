@@ -151,3 +151,43 @@ def test_external_sort_range_partitioned():
     nb = sum(1 for _ in node.execute(ExecContext(s.conf,
                                                  runtime=s.runtime)))
     assert nb > 1, "external sort did not partition"
+
+
+@pytest.mark.parametrize("asc", [True, False])
+def test_double_sort_on_the_tpu_branch_rides_the_packed_path(monkeypatch, asc):
+    """XLA:TPU has no f64->int bitcast, so there doubles sort by the two
+    32-bit keys of their f32 pair (exec/sort.py f64_pair_keys) on the
+    packed single-operand path: the multi-operand f64 lexsort it replaces
+    took 9 minutes to compile for a v5e.  Steered from the test: the
+    permutation must equal the CPU branch's over values the device holds
+    (f32 pairs), NaN/inf/zeros/duplicates/nulls included."""
+    import jax
+    import numpy as np
+
+    from spark_rapids_tpu.columnar import ColumnarBatch
+    from spark_rapids_tpu.exec.sort import sort_order
+    from spark_rapids_tpu.ops import expressions as E
+    from spark_rapids_tpu.types import (DoubleType, LongType, Schema,
+                                        StructField)
+    rng = np.random.RandomState(5)
+    x = rng.uniform(-1e5, 1e5, 1000)
+    hi = x.astype(np.float32)
+    x = hi.astype(np.float64) + (x - hi).astype(np.float32)  # f32 pairs
+    x[:40] = np.repeat(x[40:50], 4)
+    x[100:106] = [np.nan, np.inf, -np.inf, 0.0, -0.0, np.nan]
+    vals = [None if i % 97 == 0 else float(v) for i, v in enumerate(x)]
+    schema = Schema([StructField("d", DoubleType),
+                     StructField("i", LongType)])
+    batch = ColumnarBatch.from_pydict(
+        {"d": vals, "i": list(range(len(vals)))}, schema)
+    keys = [E.BoundReference(0, DoubleType), E.BoundReference(1, LongType)]
+
+    def order():
+        stats: dict = {}
+        out = np.asarray(sort_order(batch, keys, [asc, True],
+                                    [asc, True], stats=stats))
+        assert stats["packed"]
+        return out
+    on_cpu = order()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    np.testing.assert_array_equal(order(), on_cpu)
