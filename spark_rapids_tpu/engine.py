@@ -8,6 +8,7 @@ transitions) and executes the physical plan.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import sys
 import threading
@@ -25,6 +26,7 @@ from .plan.overrides import PlanMeta, plan_schema
 from .plan.physical import convert
 from .plan import transitions as T
 from .types import Schema, StructField, from_arrow
+from .utils.tracing import named_range
 
 
 # one shared owner of jax's persistent-cache configuration: engine,
@@ -189,16 +191,17 @@ class TpuSession:
         # mask the real error — and the journal must come off the active
         # stack regardless (QueryExecution.finish guarantees that part)
         try:
-            qe.finish(error)
-            with self._finish_lock:
-                # concurrent serving: N query threads finish at once;
-                # the read-modify-write counter folds must not race
-                self.last_execution = qe
-                self._last_qe = qe
-                self.queries_executed += 1
-                for k, v in qe.aggregate().items():
-                    self.query_metrics_total[k] = \
-                        self.query_metrics_total.get(k, 0) + v
+            with named_range("finish", q=qe.query_id):
+                qe.finish(error)
+                with self._finish_lock:
+                    # concurrent serving: N query threads finish at once;
+                    # the read-modify-write counter folds must not race
+                    self.last_execution = qe
+                    self._last_qe = qe
+                    self.queries_executed += 1
+                    for k, v in qe.aggregate().items():
+                        self.query_metrics_total[k] = \
+                            self.query_metrics_total.get(k, 0) + v
             if self.conf.explain == "METRICS" and error is None:
                 print(qe.explain_with_metrics(), file=sys.stderr)
             if error is not None and self._postmortem is not None:
@@ -364,49 +367,53 @@ class TpuSession:
         and the device semaphore (wait time attributed to THIS query's
         root-node metrics)."""
         import pyarrow as pa
-        runtime = self.runtime
-        on_device = isinstance(physical, TpuExec)
-        # adaptive execution wraps at EXECUTE time (never in
-        # physical_plan()): map stages materialize first and the reduce
-        # side re-plans from observed sizes (adaptive/executor.py)
-        from .adaptive.executor import maybe_wrap_adaptive
-        physical = maybe_wrap_adaptive(physical, self.conf)
-        if on_device:
-            physical = B.DeviceToHostExec(physical)
-        qe = self._begin_execution(physical, runtime)
-        if future is not None:
-            future.query_id = qe.query_id
-        if sched_attrs and qe.journal is not None:
-            # the scheduling decision, journaled into THIS query's
-            # journal under its own trace context (kind `sched`)
-            qe.journal.instant("sched", "admitted", **sched_attrs)
-        ctx = ExecContext(self.conf, runtime=runtime,
-                          cluster=self.cluster, journal=qe.journal,
-                          query_execution=qe)
-        # lifecycle token of a scheduler-run query (serve/lifecycle.py):
-        # installed on the ledger query scope so every tier's checkpoint
-        # reaches it thread-locally; None for blocking collect() paths
-        # and when the serve.lifecycle.enabled kill switch is off
-        lifecycle = getattr(future, "lifecycle", None) \
-            if future is not None else None
-        if lifecycle is not None:
-            lifecycle.journal = qe.journal
+        with named_range("begin"):
+            runtime = self.runtime
+            on_device = isinstance(physical, TpuExec)
+            # adaptive execution wraps at EXECUTE time (never in
+            # physical_plan()): map stages materialize first and the reduce
+            # side re-plans from observed sizes (adaptive/executor.py)
+            from .adaptive.executor import maybe_wrap_adaptive
+            physical = maybe_wrap_adaptive(physical, self.conf)
+            if on_device:
+                physical = B.DeviceToHostExec(physical)
+            qe = self._begin_execution(physical, runtime)
+            if future is not None:
+                future.query_id = qe.query_id
+            if sched_attrs and qe.journal is not None:
+                # the scheduling decision, journaled into THIS query's
+                # journal under its own trace context (kind `sched`)
+                qe.journal.instant("sched", "admitted", **sched_attrs)
+            ctx = ExecContext(self.conf, runtime=runtime,
+                              cluster=self.cluster, journal=qe.journal,
+                              query_execution=qe)
+            # lifecycle token of a scheduler-run query (serve/lifecycle.py):
+            # installed on the ledger query scope so every tier's checkpoint
+            # reaches it thread-locally; None for blocking collect() paths
+            # and when the serve.lifecycle.enabled kill switch is off
+            lifecycle = getattr(future, "lifecycle", None) \
+                if future is not None else None
+            if lifecycle is not None:
+                lifecycle.journal = qe.journal
         error = None
         qscope = None
         try:
             with runtime.ledger.query_scope(f"q{qe.query_id}",
                                             budget_bytes,
                                             lifecycle=lifecycle) as qscope:
-                if on_device:
-                    # device semaphore: this "task" holds a device slot
-                    # for the duration of its device work (reference:
-                    # GpuSemaphore.acquireIfNecessary, released on task
-                    # completion).  Blocked-wait time lands on the
-                    # query's own root-node metrics, not the runtime
-                    # globals (per-query attribution under concurrency).
-                    with runtime.semaphore.held(metrics=physical.metrics):
-                        tables = list(physical.execute_cpu(ctx))
-                else:
+                with named_range("execute", q=qe.query_id), \
+                        contextlib.ExitStack() as slot:
+                    if on_device:
+                        # device semaphore: this "task" holds a device
+                        # slot for the duration of its device work
+                        # (reference: GpuSemaphore.acquireIfNecessary,
+                        # released on task completion).  Blocked-wait
+                        # time lands on the query's own root-node
+                        # metrics, not the runtime globals (per-query
+                        # attribution under concurrency).
+                        with named_range("semaphore", q=qe.query_id):
+                            slot.enter_context(runtime.semaphore.held(
+                                metrics=physical.metrics))
                     tables = list(physical.execute_cpu(ctx))
         except BaseException as e:
             error = e
@@ -713,12 +720,15 @@ class DataFrame:
         return self.session.plan(self.plan)
 
     def to_arrow(self):
-        physical = self.session.plan(self.plan)
-        return self.session._collect_physical(physical, self.schema)
+        with named_range("plan"):
+            physical = self.session.plan(self.plan)
+            schema = self.schema
+        return self.session._collect_physical(physical, schema)
 
     def collect(self) -> List[tuple]:
         table = self.to_arrow()
-        return [tuple(r.values()) for r in table.to_pylist()]
+        with named_range("rows", rows=table.num_rows):
+            return [tuple(r.values()) for r in table.to_pylist()]
 
     def to_pandas(self):
         return self.to_arrow().to_pandas()
@@ -741,14 +751,16 @@ class DataFrame:
             raise RuntimeError(
                 f"set {C.EXPORT_COLUMNAR_RDD.key}=true to export device "
                 "columnar data")
-        physical = self.session.plan(self.plan)
-        runtime = self.session.runtime
-        from .adaptive.executor import maybe_wrap_adaptive
-        physical = maybe_wrap_adaptive(physical, self.session.conf)
-        qe = self.session._begin_execution(physical, runtime)
-        ctx = ExecContext(self.session.conf, runtime=runtime,
-                          cluster=self.session.cluster, journal=qe.journal,
-                          query_execution=qe)
+        with named_range("plan"):
+            physical = self.session.plan(self.plan)
+        with named_range("begin"):
+            runtime = self.session.runtime
+            from .adaptive.executor import maybe_wrap_adaptive
+            physical = maybe_wrap_adaptive(physical, self.session.conf)
+            qe = self.session._begin_execution(physical, runtime)
+            ctx = ExecContext(self.session.conf, runtime=runtime,
+                              cluster=self.session.cluster,
+                              journal=qe.journal, query_execution=qe)
         error = None
         try:
             if isinstance(physical, TpuExec):

@@ -1135,8 +1135,8 @@ class TpuHashAggregateExec(TpuExec):
             # through to the sort-based program below and latches the
             # key dirty so later executions skip the probe.
             fnb = cached_kernel(key + ("bucket",), build_bucket)
-            with self.metrics.timer(MN.COMPUTE_AGG_TIME), \
-                    named_range("agg_whole_stage_bucket"):
+            with named_range("agg_whole_stage_bucket", self.metrics,
+                             MN.COMPUTE_AGG_TIME):
                 from ..utils.kernel_cache import record_dispatch
                 record_dispatch()
                 all_clean, out = (fnb(pvals, *all_leaves) if pre_params
@@ -1149,8 +1149,8 @@ class TpuHashAggregateExec(TpuExec):
         fn = cached_kernel(key, build,
                            **({"donate_argnums": donate_leaf_argnums}
                               if donate_leaf_argnums else {}))
-        with self.metrics.timer(MN.COMPUTE_AGG_TIME), \
-                named_range("agg_whole_stage"):
+        with named_range("agg_whole_stage", self.metrics,
+                         MN.COMPUTE_AGG_TIME):
             from ..utils.kernel_cache import record_dispatch
             record_dispatch()
             if donate_leaf_argnums:
@@ -1228,9 +1228,9 @@ class TpuHashAggregateExec(TpuExec):
                             * self._cost_weight())
                 with self.metrics.timer(MN.CONCAT_TIME):
                     both = concat_batches(parts)
-                with self.metrics.timer(MN.MERGE_AGG_TIME), \
-                        self.metrics.timer(MN.SEG_AGG_TIME), \
-                        named_range("agg_merge"):
+                with self.metrics.timer(MN.SEG_AGG_TIME), \
+                        named_range("agg_merge", self.metrics,
+                                    MN.MERGE_AGG_TIME):
                     return merge(both)
             # retry-only: partial states are merge inputs, not splittable
             # row ranges (splitting them would change nothing — the merge
@@ -1325,8 +1325,7 @@ class TpuHashAggregateExec(TpuExec):
             # num_rows_host device sync entirely)
             if batch.capacity >= 8192:
                 batch = batch.maybe_shrink(batch.num_rows_host())
-            with self.metrics.timer(MN.COMPUTE_AGG_TIME), \
-                    named_range("agg_update"):
+            with named_range("agg_update", self.metrics, MN.COMPUTE_AGG_TIME):
                 partials = run_retryable(ctx, self.metrics, "aggUpdate",
                                          attempt_update, [batch],
                                          split=update_split)

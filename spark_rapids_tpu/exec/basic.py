@@ -235,8 +235,7 @@ class RowLocalExec(TpuExec):
                     lambda: functools.partial(E.eval_with_row_offset,
                                               self.batch_fn()))
                 self._record_batch_cost(batch)
-                with self.metrics.timer(MN.TOTAL_TIME), \
-                        named_range(self.name):
+                with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                     record_dispatch()
                     out = fn(batch, jnp.int64(offset))
                 offset += batch.num_rows_host()
@@ -253,8 +252,7 @@ class RowLocalExec(TpuExec):
                 fn = cached_kernel(key + (E.current_input_file(),),
                                    self.batch_fn)
                 self._record_batch_cost(batch)
-                with self.metrics.timer(MN.TOTAL_TIME), \
-                        named_range(self.name):
+                with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                     record_dispatch()
                     out = fn(batch)
                 record_output_batch(self.metrics, out, ctx.runtime)
@@ -267,7 +265,7 @@ class RowLocalExec(TpuExec):
         fn = self.parameterized_kernel()
         for batch in self.children[0].execute(ctx):
             self._record_batch_cost(batch)
-            with self.metrics.timer(MN.TOTAL_TIME), named_range(self.name):
+            with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                 record_dispatch()
                 out = fn(batch)
             record_output_batch(self.metrics, out, ctx.runtime)
@@ -572,10 +570,13 @@ class DeviceToHostExec(CpuExec):
     def execute_cpu(self, ctx):
         for batch in self.children[0].execute(ctx):
             # cost declaration: the D2H edge reads the batch out of HBM
-            # and moves it over the link to the host
-            record_cost(self.metrics, d2h=batch.device_size_bytes(),
-                        hbm_read=batch.device_size_bytes())
-            with self.metrics.timer(MN.D2H_TIME):
+            # and moves it over the link to the host (shape metadata,
+            # never a read)
+            nbytes = batch.device_size_bytes()
+            record_cost(self.metrics, d2h=nbytes, hbm_read=nbytes)
+            # the wait for the device and the copy to the host
+            with named_range("d2h", self.metrics, MN.D2H_TIME,
+                             bytes=nbytes):
                 table = batch.to_arrow()
             self.metrics.add(MN.NUM_OUTPUT_ROWS, table.num_rows)
             self.metrics.add(MN.NUM_OUTPUT_BATCHES, 1)

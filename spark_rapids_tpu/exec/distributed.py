@@ -38,7 +38,7 @@ from ..parallel.distributed import (run_distributed_aggregate,
                                     run_distributed_sort)
 from ..utils.tracing import named_range
 from .aggregate import TpuHashAggregateExec
-from .base import ExecContext, record_output_batch
+from .base import ExecContext, record_cost, record_output_batch
 from .join import TpuHashJoinExec, _empty_batch
 from .sort import TpuSortExec
 from ..metrics import names as MN
@@ -69,6 +69,15 @@ def resolve_mesh(conf) -> Optional["jax.sharding.Mesh"]:
             f"{C.MESH_DEVICES.key}={n} but jax reports {have} "
             f"{jax.devices()[0].platform} device(s)")
     return make_mesh(n)
+
+
+def _ici_declarer(metrics):
+    """The SPMD drivers' `on_exchange`: every row exchange they dispatch
+    (overflow retries included: those bytes moved too) declares its
+    interconnect bytes on the operator's metrics, from host-known metadata
+    (parallel/distributed.exchange_ici_bytes), as exec/exchange.py does for
+    the generic exchange."""
+    return lambda nbytes: record_cost(metrics, ici=nbytes)
 
 
 def _stage_chunk(batches, mesh, min_cap: int):
@@ -133,11 +142,12 @@ class TpuDistributedAggregateExec(TpuHashAggregateExec):
         chunk_rows = max(int(ctx.conf.get(C.MESH_INPUT_CHUNK_ROWS)), n)
         chunks = _sharded_chunks(self.children[0], ctx, self.mesh, n,
                                  chunk_rows)
-        with self.metrics.timer(MN.DISTRIBUTED_AGG_TIME), \
-                named_range("dist_agg"):
+        with named_range("dist_agg", self.metrics,
+                         MN.DISTRIBUTED_AGG_TIME):
             out = run_distributed_aggregate_streaming(
                 self, self.mesh, chunks, use_allgather=self.use_allgather,
-                cache_key=("dist",) + self.kernel_key())
+                cache_key=self.kernel_key(),
+                on_exchange=_ici_declarer(self.metrics))
         if out is None:
             # delegate empty-input semantics (global 1-row / grouped none)
             yield from super().execute(ctx)
@@ -173,8 +183,8 @@ class TpuDistributedJoinExec(TpuHashJoinExec):
             yield from super().execute(ctx)
             return
         produced = False
-        with self.metrics.timer(MN.DISTRIBUTED_JOIN_TIME), \
-                named_range("dist_join"):
+        with named_range("dist_join", self.metrics,
+                         MN.DISTRIBUTED_JOIN_TIME):
             # stream the probe side: every supported join type
             # (inner/left/left_semi/left_anti) is per-left-row independent,
             # so per-chunk results compose by concatenation
@@ -183,7 +193,8 @@ class TpuDistributedJoinExec(TpuHashJoinExec):
                     _sharded_chunks(self.children[0], ctx, self.mesh, n,
                                     chunk_rows),
                     right, use_allgather=self.use_allgather,
-                    cache_key=("dist",) + self.kernel_key()):
+                    cache_key=self.kernel_key(),
+                    on_exchange=_ici_declarer(self.metrics)):
                 produced = True
                 record_output_batch(self.metrics, out, ctx.runtime)
                 yield out
@@ -211,11 +222,12 @@ class TpuDistributedSortExec(TpuSortExec):
         batch = _drain_to_sharded(self.children[0], ctx, self.mesh, n)
         if batch is None:
             return
-        with self.metrics.timer(MN.DISTRIBUTED_SORT_TIME), \
-                named_range("dist_sort"):
+        with named_range("dist_sort", self.metrics,
+                         MN.DISTRIBUTED_SORT_TIME):
             out = run_distributed_sort(
                 self.sort_exprs, self.ascending, self.nulls_first,
                 self.mesh, batch, use_allgather=self.use_allgather,
-                cache_key=("dist",) + self.kernel_key())
+                cache_key=self.kernel_key(),
+                on_exchange=_ici_declarer(self.metrics))
         record_output_batch(self.metrics, out, ctx.runtime)
         yield out

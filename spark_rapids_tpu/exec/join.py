@@ -402,7 +402,7 @@ class TpuHashJoinExec(TpuExec):
                         flops=cap * max(1, cap.bit_length()))
             return build_fn(rb)
 
-        with self.metrics.timer(MN.BUILD_TIME), named_range("join_build"):
+        with named_range("join_build", self.metrics, MN.BUILD_TIME):
             if ctx is not None:
                 build, bkeys, h1s = run_retryable(
                     ctx, self.metrics, "joinBuild", attempt_build,
@@ -476,7 +476,7 @@ class TpuHashJoinExec(TpuExec):
 
         b_hit_accum = None  # full join: OR of per-batch build-hit masks
         for lbatch in lbatches:
-            with self.metrics.timer(MN.JOIN_TIME), named_range("join_stream"):
+            with named_range("join_stream", self.metrics, MN.JOIN_TIME):
                 if ctx is not None:
                     results = run_retryable(ctx, self.metrics, "joinProbe",
                                             probe_one, [lbatch],
@@ -495,8 +495,7 @@ class TpuHashJoinExec(TpuExec):
         if self.join_type == "full":
             if b_hit_accum is None:
                 b_hit_accum = jnp.zeros(build.capacity, jnp.bool_)
-            with self.metrics.timer(MN.JOIN_TIME), \
-                    named_range("join_full_tail"):
+            with named_range("join_full_tail", self.metrics, MN.JOIN_TIME):
                 tail = self._full_remainder(build, b_hit_accum)
             n = tail.num_rows_host()
             if n:

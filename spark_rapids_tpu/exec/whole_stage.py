@@ -169,11 +169,17 @@ class TpuWholeStageExec(FusedPipelineExec):
             key += ("params", E.parameter_signature(params))
             slots = [p.slot for p in params]
             pvals = E.parameter_values(params)
-            builder = bound_param_builder(self.batch_fn, slots)
+            chain = bound_param_builder(self.batch_fn, slots)
         else:
             key = self.kernel_key() + ("whole_stage_exec",)
             pvals = None
-            builder = self.batch_fn
+            chain = self.batch_fn
+
+        def builder():
+            # written here, so kernel_cache names the program after this
+            # module's layer (`stage.wholeStage-<id>`), whatever row-local
+            # chain of exec/basic.py it fuses
+            return chain()
         split = split_batch_rows if self._can_split() else None
         self.metrics.add(MN.NUM_FUSED_STAGES, 1)
         n_batches = 0
@@ -232,8 +238,8 @@ class TpuWholeStageExec(FusedPipelineExec):
             in_rows = (batch.known_rows if batch.known_rows is not None
                        else batch.capacity) if moderate else 0
             dispatch_cost[0] = {}
-            with self.metrics.timer(MN.TOTAL_TIME), \
-                    named_range(f"whole_stage_{self.stage_id}"):
+            with named_range(f"whole_stage_{self.stage_id}", self.metrics,
+                             MN.TOTAL_TIME):
                 try:
                     outs = run_retryable(ctx, self.metrics, "wholeStage",
                                          attempt, [batch], split=split)

@@ -42,6 +42,7 @@ from ..columnar.batch import bucket_rows
 from ..columnar.column import bucket_strlen
 from ..types import Schema, StringType
 from ..metrics import names as MN
+from ..utils.tracing import named_range
 
 _NL = 0x0A
 _CR = 0x0D
@@ -201,8 +202,7 @@ def _decode_chunk(raw_dev, starts: np.ndarray, lengths: np.ndarray,
                     return c.mask_invalid()
                 parser = castmod._DISPATCH[("string", dtype.name)]
                 return parser(c, dtype)
-            import jax
-            return jax.jit(fn)
+            return fn
 
         fn = cached_kernel(key, make)
         cols.append(fn(raw_dev, jnp.asarray(s), jnp.asarray(ln), sel,
@@ -251,12 +251,8 @@ def device_csv_batches(files, schema: Schema, options: dict, conf,
             while off < rows or (rows == 0 and off == 0):
                 hi = min(off + max_rows, rows)
                 qchunk = quoted[off:hi] if quoted is not None else None
-                if metrics is not None:
-                    with metrics.timer(MN.SCAN_TIME):
-                        batch = _decode_chunk(raw_dev, starts[off:hi],
-                                              lengths[off:hi], schema,
-                                              conf, qchunk)
-                else:
+                with named_range("scan_decode", metrics, MN.SCAN_TIME,
+                                 rows=hi - off):
                     batch = _decode_chunk(raw_dev, starts[off:hi],
                                           lengths[off:hi], schema, conf,
                                           qchunk)

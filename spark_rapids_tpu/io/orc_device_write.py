@@ -149,7 +149,6 @@ def _compact_strings(col: Column, live) -> Tuple[np.ndarray, np.ndarray]:
     """Device: pack live non-null strings' bytes contiguously (no length
     prefixes — ORC carries lengths in a separate RLE stream) and return
     (payload bytes, lengths int64[nn])."""
-    import jax
     import jax.numpy as jnp
 
     from ..utils.kernel_cache import cached_kernel
@@ -175,7 +174,7 @@ def _compact_strings(col: Column, live) -> Tuple[np.ndarray, np.ndarray]:
             lens_out = jnp.zeros(cap, dtype=jnp.int64)
             lens_out = lens_out.at[pos].set(sizes, mode="drop")
             return out, lens_out, total, jnp.sum(ok.astype(jnp.int64))
-        return jax.jit(k)
+        return k
 
     fn = cached_kernel(key, make)
     ok = col.valid & live
@@ -188,7 +187,6 @@ def _compact_strings(col: Column, live) -> Tuple[np.ndarray, np.ndarray]:
 def _compact_bools(col: Column, live) -> Tuple[np.ndarray, int]:
     """Device: compacted live non-null booleans as bytes (bit packing is
     MSB-first per the ORC spec, done host-side on the 1-bit stream)."""
-    import jax
     import jax.numpy as jnp
 
     from ..utils.kernel_cache import cached_kernel
@@ -202,7 +200,7 @@ def _compact_bools(col: Column, live) -> Tuple[np.ndarray, int]:
             out = jnp.zeros(cap, dtype=jnp.uint8)
             out = out.at[pos].set(data.astype(jnp.uint8), mode="drop")
             return out, jnp.sum(ok.astype(jnp.int64))
-        return jax.jit(k)
+        return k
 
     fn = cached_kernel(key, make)
     ok = col.valid & live

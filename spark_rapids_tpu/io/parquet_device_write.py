@@ -158,7 +158,6 @@ def _rle_def_levels(valid: np.ndarray) -> bytes:
 def _compact_values(col: Column, live) -> Tuple[np.ndarray, int, dict]:
     """Device: scatter the column's live non-null values into PLAIN payload
     order; returns (host payload array, non-null count, device stats)."""
-    import jax
     import jax.numpy as jnp
 
     from ..utils.kernel_cache import cached_kernel
@@ -195,7 +194,7 @@ def _compact_values(col: Column, live) -> Tuple[np.ndarray, int, dict]:
                                  starts[:, None] + 4 + posw, cap * slot)
                 out = out.at[idxw].set(data.astype(jnp.uint8), mode="drop")
                 return out, total, jnp.sum(ok.astype(jnp.int64))
-            return jax.jit(k)
+            return k
 
         fn = cached_kernel(key, make)
         ok = col.valid & live
@@ -214,7 +213,7 @@ def _compact_values(col: Column, live) -> Tuple[np.ndarray, int, dict]:
                 out = jnp.zeros(cap, dtype=jnp.uint8)
                 out = out.at[pos].set(data.astype(jnp.uint8), mode="drop")
                 return out, jnp.sum(ok.astype(jnp.int64))
-            return jax.jit(k)
+            return k
 
         fn = cached_kernel(key, make)
         ok = col.valid & live
@@ -239,7 +238,7 @@ def _compact_values(col: Column, live) -> Tuple[np.ndarray, int, dict]:
             mn = jnp.min(jnp.where(ok, data, hi))
             mx = jnp.max(jnp.where(ok, data, lo))
             return out, jnp.sum(ok.astype(jnp.int64)), mn, mx
-        return jax.jit(k)
+        return k
 
     fn = cached_kernel(key, make)
     ok = col.valid & live

@@ -28,6 +28,7 @@ from ..exec.base import CpuExec, ExecContext, TpuExec
 from ..types import Schema, StructField, from_arrow, to_arrow
 from ..plan import logical as L
 from ..metrics import names as MN
+from ..utils.tracing import named_range
 
 
 # --------------------------------------------------------------------------
@@ -612,9 +613,8 @@ def _device_orc_batches(path: str, schema: Schema, options: dict, conf,
                     host_names.append(f.name)  # evolution: nulls via host
                     continue
                 try:
-                    from contextlib import nullcontext
-                    with metrics.timer(MN.SCAN_TIME) if metrics is not None \
-                            else nullcontext():
+                    with named_range("scan_decode", metrics, MN.SCAN_TIME,
+                                     rows=rows):
                         out_cols[f.name] = decode_column(
                             info, si, f.name, f.dtype, cap)
                     if metrics is not None:
@@ -669,10 +669,21 @@ def _device_parquet_batches(files, schema: Schema, options: dict, conf,
     partitions = options.get("__partitions__") or {}
     part_names = {n for vals in partitions.values() for n in vals}
 
-    files = list(files)
-    yield from _device_parquet_files(
-        files, schema, options, conf, metrics, max_rows, max_bytes,
+    chunks = _device_parquet_files(
+        list(files), schema, options, conf, metrics, max_rows, max_bytes,
         predicates, partitions, part_names)
+    try:
+        while True:
+            # one span (and the scan timer) per row-group chunk: footer and
+            # page parsing, decompression, the H2D enqueues and the decode
+            # dispatches; on the prefetch thread when prefetch is on
+            with named_range("scan_decode", metrics, MN.SCAN_TIME):
+                chunk = next(chunks, None)
+            if chunk is None:
+                return
+            yield chunk
+    finally:
+        chunks.close()
 
 
 def _device_parquet_files(files, schema, options, conf, metrics, max_rows,

@@ -318,6 +318,73 @@ def monitoring_doc() -> str:
         "device timeline `utils.tracing.profile_trace` captures; pass",
         "`journal=` to `profile_trace` to emit both into one log dir.",
         "",
+        "## Profiler spans and program names",
+        "",
+        "The profiler's trace (`.xplane.pb`) is where the program's spans",
+        "and the device's ops share one clock.  Capture one with",
+        "`with utils.tracing.profile_trace(log_dir): df.collect()` and",
+        "read it with `python chipbench/xplane.py <file.xplane.pb>` (or",
+        "TensorBoard's profile plugin / Perfetto).",
+        "",
+        "`utils.tracing.named_range` is the only way the program opens a",
+        "span: a `jax.profiler.TraceAnnotation` named `srt:<name>` plus a",
+        "`jax.named_scope(<name>)`, optionally feeding a timer metric",
+        "(`named_range(name, metrics, MN.X)`: span and timer are one",
+        "site).  `^srt:` separates the program's spans from JAX's runtime",
+        "spans.  A span never reads or syncs the device; host-known counts",
+        "ride as annotation arguments (`q=<query id>`, the journal's, on",
+        "the phase spans; `rows=`, `bytes=`).  The span that caused a span",
+        "is the one it nests in on its thread.",
+        "",
+        "Span | Where | Covers",
+        "-----|-------|-------",
+        "`srt:plan` | `DataFrame.to_arrow` / `to_device_batches`, the "
+        "scheduler's worker | `session.plan` (pushdown, tagging, overrides, "
+        "transitions, fusion) and the output schema; the query id does not "
+        "exist yet",
+        "`srt:begin` | `TpuSession._collect_physical` / "
+        "`to_device_batches` | adaptive wrap, `QueryExecution.__init__` "
+        "(node ids, metric levels, runtime snapshot, journal open), the "
+        "`ExecContext`",
+        "`srt:execute` | `_collect_physical` | the drain of "
+        "`physical.execute_cpu(ctx)`: every operator span nests inside it",
+        "`srt:semaphore` | inside `srt:execute` | the wait for the device "
+        "slot",
+        "`srt:d2h` | `DeviceToHostExec` | `batch.to_arrow()`: the wait for "
+        "the device and the copy to the host (timer `d2hTime`)",
+        "`srt:finish` | `TpuSession._finish_execution` | `qe.finish`, "
+        "`qe.aggregate`, the session's counter fold",
+        "`srt:metrics_fold` | `Metrics._fold_lazy_locked`, only when "
+        "something is pending | the eager `stack/astype/sum/device_get` of "
+        "the lazy row counts",
+        "`srt:rows` | `DataFrame.collect` | arrow to Python tuples",
+        "`srt:scan_decode` | `io/scan.py` device-decode paths (Parquet per "
+        "row-group chunk, ORC per column, CSV per chunk), on the prefetch "
+        "thread when prefetch is on | footer and page parsing, "
+        "decompression, H2D enqueue, decode dispatches (timer `scanTime`)",
+        "`srt:<operator>` | `exec/` | `srt:agg_whole_stage`, "
+        "`srt:agg_whole_stage_bucket`, `srt:agg_update`, `srt:agg_merge`, "
+        "`srt:whole_stage_<id>`, `srt:join_build`, `srt:join_stream`, "
+        "`srt:join_full_tail`, `srt:dist_agg`, `srt:dist_join`, "
+        "`srt:dist_sort`, and a row-local operator's own name",
+        "",
+        "Every executable the program compiles goes through",
+        "`utils/kernel_cache.named_jit` (`cached_kernel`,",
+        "`stage_executable`, the SPMD steps) and is named",
+        "`<layer>.<role>`: the trace's `XLA Modules` line and JAX's",
+        "`PjitFunction(...)` host spans read `jit_agg.whole_stage`,",
+        "`jit_scan.pq_bp`, `jit_join.hashjoin_probe`,",
+        "`jit_dist.join_probe`, `jit_mem.contig_pack`,",
+        "`jit_stage.wholeStage-3`.  `<layer>` comes from the module the",
+        "program's builder lives in (`kernel_cache._LAYER_OF_MODULE`: `scan`,",
+        "`agg`, `join`, `sort`, `stage`, `dist`, `mem`, `expr`; an unmapped",
+        "module raises).  `<role>` is the cache key's string head (an",
+        "operator's class name shortened: `TpuHashJoinExec` -> `hashjoin`)",
+        "plus the word the call site appended after the key's last tuple",
+        "(`probe`, `merge`, `bucket`), or `stage_executable`'s `name`.  A",
+        "launch that carries no such name is an eager `jnp` op outside any",
+        "compiled program.",
+        "",
         "## Metric catalog",
         "",
         "Name | Kind | Level | Description",

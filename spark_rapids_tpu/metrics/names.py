@@ -96,7 +96,11 @@ NUM_PARTITIONS_WRITTEN = register_metric(
 TOTAL_TIME = register_metric(
     "totalTime", TIMER, MODERATE, "operator wall-clock time")
 SCAN_TIME = register_metric(
-    "scanTime", TIMER, MODERATE, "scan decode + H2D time")
+    "scanTime", TIMER, MODERATE,
+    "scan decode + H2D time: host decode plus the H2D copy on the host "
+    "path; on the device-decode paths (Parquet, ORC, CSV) the host time "
+    "inside `srt:scan_decode` (page parsing, decompression, H2D enqueue, "
+    "decode dispatches), summed over the decode threads")
 CONCAT_TIME = register_metric(
     "concatTime", TIMER, MODERATE, "batch coalesce/concat time")
 SORT_TIME = register_metric(
@@ -457,7 +461,10 @@ ICI_BYTES_MOVED = register_metric(
     "LOGICAL bytes routed through mesh-exchange collectives (the 'ici' "
     "roofline resource) — the same codec-invariant figure the AQE map "
     "statistics carry, so the mesh and socket tiers declare comparable "
-    "data movement for the same exchange")
+    "data movement for the same exchange; the SPMD aggregate, join and "
+    "sort declare the bytes their row exchanges' collectives move, from "
+    "metadata (rows per peer x row width x n x (n-1): the quota block of "
+    "the all-to-all, the whole shard of the all-gather variant)")
 EST_FLOPS = register_metric(
     "estFlops", COUNTER, MODERATE,
     "estimated floating/integer operations executed by the operator's "

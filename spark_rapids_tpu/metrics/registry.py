@@ -236,14 +236,16 @@ class Metrics:
             return
         import jax
         import jax.numpy as jnp
-        groups: Dict[tuple, list] = {}
-        for name, pend in pending:
-            for x in pend:
-                x = jnp.asarray(x)
-                groups.setdefault((name, x.sharding), []).append(x)
-        sums = [jnp.sum(jnp.stack(xs).astype(jnp.float64))
-                for xs in groups.values()]
-        host = jax.device_get(sums)  # tpulint: disable=TPU001 THE designed single device->host transfer of the lazy-metric fold; reporting paths sync once, hot loops never
+        from ..utils.tracing import named_range
+        with named_range("metrics_fold"):
+            groups: Dict[tuple, list] = {}
+            for name, pend in pending:
+                for x in pend:
+                    x = jnp.asarray(x)
+                    groups.setdefault((name, x.sharding), []).append(x)
+            sums = [jnp.sum(jnp.stack(xs).astype(jnp.float64))
+                    for xs in groups.values()]
+            host = jax.device_get(sums)  # tpulint: disable=TPU001 THE designed single device->host transfer of the lazy-metric fold; reporting paths sync once, hot loops never
         for (name, _placement), v in zip(groups, host):
             self._values[name] = self._values.get(name, 0) + float(v)
         for _name, pend in pending:

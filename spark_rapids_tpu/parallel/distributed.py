@@ -146,6 +146,20 @@ def exchange_by_bucket(batch: ColumnarBatch, bucket, axis: str = DATA_AXIS
     return ColumnarBatch(cols, recv_sel, batch.schema)
 
 
+def exchange_ici_bytes(batch: ColumnarBatch, n: int,
+                       rows_per_peer: int) -> int:
+    """Bytes ONE row exchange of `batch`'s columns puts on the interconnect,
+    from metadata alone (shapes x dtype widths, never a device read): every
+    device sends `rows_per_peer` rows to each of its n-1 peers —
+    exchange_compact's quota block, i.e. the all-to-all's n*quota-row
+    operand x (n-1)/n; for exchange_by_bucket's all-gather the whole local
+    shard, i.e. the gathered size less the device's own part.  A row's
+    width is the batch's static footprint per row: data, validity, string
+    lengths, and the selection mask the exchange moves with them."""
+    row_bytes = batch.device_size_bytes() // max(batch.capacity, 1)
+    return n * (n - 1) * rows_per_peer * row_bytes
+
+
 def key_buckets(key_cols: Sequence[Column], live, n: int):
     """Owner device of each row: h1(keys) % n (dead rows -> garbage, masked
     by sel downstream)."""
@@ -274,14 +288,15 @@ def distributed_aggregate_step(agg, mesh: Mesh, axis: str = DATA_AXIS,
                      out_specs=(P(axis), P()))
 
 
-def _jit_step(builder, cache_key):
-    """jit a distributed step, optionally through the process-wide kernel
-    cache (planner-integrated execs pass a structural key so repeated
-    queries reuse the compiled SPMD program instead of retracing)."""
+def _jit_step(builder, role, cache_key, *shape):
+    """jit a distributed step as the program `dist.<role>`, through the
+    process-wide kernel cache when the planner-integrated execs pass their
+    structural key (repeated queries then reuse the compiled SPMD program
+    instead of retracing); `shape` is what the step was specialised to."""
+    from ..utils.kernel_cache import cached_kernel, named_jit
     if cache_key is None:
-        return jax.jit(builder())
-    from ..utils.kernel_cache import cached_kernel
-    return cached_kernel(cache_key, builder)
+        return named_jit(builder, role)
+    return cached_kernel((role,) + cache_key + shape, builder)
 
 
 def run_distributed_aggregate(agg, mesh: Mesh, batch: ColumnarBatch,
@@ -297,12 +312,11 @@ def run_distributed_aggregate(agg, mesh: Mesh, batch: ColumnarBatch,
     local_cap = batch.capacity // n
     quota = None if use_allgather else default_quota(local_cap, n)
     while True:
-        ck = None if cache_key is None else \
-            cache_key + (n, local_cap, quota, use_allgather)
         step = _jit_step(
             lambda: distributed_aggregate_step(
                 agg, mesh, axis=axis, pre=pre, quota=quota,
-                use_allgather=use_allgather), ck)
+                use_allgather=use_allgather),
+            "agg", cache_key, n, local_cap, quota, use_allgather)
         with mesh:
             out, overflow = step(batch)
         if use_allgather or int(overflow) == 0:
@@ -418,7 +432,7 @@ def distributed_finalize_step(agg, mesh: Mesh, axis: str = DATA_AXIS):
 def run_distributed_aggregate_streaming(agg, mesh: Mesh, chunks,
                                         pre=None, axis: str = DATA_AXIS,
                                         use_allgather: bool = False,
-                                        cache_key=None):
+                                        cache_key=None, on_exchange=None):
     """Host driver: stream sharded input chunks through the mesh.
 
     Per chunk: partial step (update/exchange/merge) with quota
@@ -437,14 +451,18 @@ def run_distributed_aggregate_streaming(agg, mesh: Mesh, chunks,
         local_cap = chunk.capacity // n
         quota = None if use_allgather else default_quota(local_cap, n)
         while True:
-            ck = None if cache_key is None else \
-                cache_key + ("spartial", n, local_cap, quota, use_allgather)
             pstep = _jit_step(
                 lambda: distributed_aggregate_partial_step(
                     agg, mesh, axis=axis, pre=pre, quota=quota,
-                    use_allgather=use_allgather), ck)
+                    use_allgather=use_allgather),
+                "agg_partial", cache_key, n, local_cap, quota,
+                use_allgather)
             with mesh:
                 partial, overflow, ng = pstep(chunk)
+            if on_exchange is not None:
+                # the exchanged partial state has the result's columns
+                on_exchange(exchange_ici_bytes(
+                    partial, n, local_cap if use_allgather else quota))
             if use_allgather or int(overflow) == 0:
                 break
             quota = min(local_cap, quota * 2)
@@ -453,11 +471,9 @@ def run_distributed_aggregate_streaming(agg, mesh: Mesh, chunks,
         else:
             a_cap = state.capacity // n
             b_cap = partial.capacity // n
-            ck = None if cache_key is None else \
-                cache_key + ("scombine", n, a_cap, b_cap)
             cstep = _jit_step(
                 lambda: distributed_aggregate_combine_step(agg, mesh, axis),
-                ck)
+                "agg_combine", cache_key, n, a_cap, b_cap)
             with mesh:
                 state, ng = cstep(state, partial)
             state_ng = int(ng)
@@ -466,18 +482,15 @@ def run_distributed_aggregate_streaming(agg, mesh: Mesh, chunks,
         state_local = state.capacity // n
         target = bucket_rows(max(state_ng, 1))
         if target < state_local:
-            ck = None if cache_key is None else \
-                cache_key + ("sshrink", n, state_local, target)
             sstep = _jit_step(
-                lambda: distributed_shrink_step(mesh, target, axis), ck)
+                lambda: distributed_shrink_step(mesh, target, axis),
+                "agg_shrink", cache_key, n, state_local, target)
             with mesh:
                 state = sstep(state)
     if state is None:
         return None
-    ck = None if cache_key is None else \
-        cache_key + ("sfinal", n, state.capacity // n)
     fstep = _jit_step(lambda: distributed_finalize_step(agg, mesh, axis),
-                      ck)
+                      "agg_final", cache_key, n, state.capacity // n)
     with mesh:
         return fstep(state)
 
@@ -556,13 +569,12 @@ def run_distributed_join(join, mesh: Mesh, left: ColumnarBatch,
     if out_cap is None:
         out_cap = max(n * quota_l, 1024)
     while True:
-        ck = None if cache_key is None else \
-            cache_key + (n, lcap, rcap, max_dup, out_cap, quota_l, quota_r,
-                         use_allgather)
         step = _jit_step(
             lambda: distributed_join_step(
                 join, mesh, max_dup, out_cap, quota_l, quota_r, axis=axis,
-                use_allgather=use_allgather), ck)
+                use_allgather=use_allgather),
+            "join", cache_key, n, lcap, rcap, max_dup, out_cap, quota_l,
+            quota_r, use_allgather)
         with mesh:
             out, l_ovf, r_ovf, dup_ovf, cap_ovf = step(left, right)
         retry = False
@@ -655,7 +667,7 @@ def run_distributed_join_streaming(join, mesh: Mesh, left_chunks,
                                    axis: str = DATA_AXIS, max_dup: int = 8,
                                    out_cap=None,
                                    use_allgather: bool = False,
-                                   cache_key=None):
+                                   cache_key=None, on_exchange=None):
     """Host driver: exchange the build side once (quota overflow-retry),
     then stream probe chunks through the mesh, yielding one sharded output
     batch per chunk.  Retry knobs (left quota / dup window / out capacity)
@@ -664,14 +676,16 @@ def run_distributed_join_streaming(join, mesh: Mesh, left_chunks,
     rcap = right.capacity // n
     quota_r = default_quota(rcap, n)
     while True:
-        ck = None if cache_key is None else \
-            cache_key + ("jbuild", n, rcap, quota_r, use_allgather)
         bstep = _jit_step(
             lambda: distributed_join_build_exchange_step(
                 join, mesh, quota_r, axis=axis,
-                use_allgather=use_allgather), ck)
+                use_allgather=use_allgather),
+            "join_build", cache_key, n, rcap, quota_r, use_allgather)
         with mesh:
             rex, rovf = bstep(right)
+        if on_exchange is not None:
+            on_exchange(exchange_ici_bytes(
+                right, n, rcap if use_allgather else quota_r))
         if use_allgather or int(rovf) == 0:
             break
         if quota_r >= rcap:  # pragma: no cover - cap always fits
@@ -686,15 +700,17 @@ def run_distributed_join_streaming(join, mesh: Mesh, left_chunks,
         if out_cap is None:
             out_cap = max(n * quota_l, 1024)
         while True:
-            ck = None if cache_key is None else \
-                cache_key + ("jprobe", n, lcap, rcap, max_dup, out_cap,
-                             quota_l, quota_r, use_allgather)
             pstep = _jit_step(
                 lambda: distributed_join_probe_step(
                     join, mesh, max_dup, out_cap, quota_l, axis=axis,
-                    use_allgather=use_allgather), ck)
+                    use_allgather=use_allgather),
+                "join_probe", cache_key, n, lcap, rcap, max_dup, out_cap,
+                quota_l, quota_r, use_allgather)
             with mesh:
                 out, l_ovf, dup_ovf, cap_ovf = pstep(chunk, rex)
+            if on_exchange is not None:
+                on_exchange(exchange_ici_bytes(
+                    chunk, n, lcap if use_allgather else quota_l))
             retry = False
             if not use_allgather and int(l_ovf) > 0:
                 if quota_l >= lcap:  # pragma: no cover - cap always fits
@@ -802,21 +818,25 @@ def distributed_sort_step(sort_exprs, ascending, nulls_first, mesh: Mesh,
 def run_distributed_sort(sort_exprs, ascending, nulls_first, mesh: Mesh,
                          batch: ColumnarBatch, axis: str = DATA_AXIS,
                          use_allgather: bool = False,
-                         cache_key=None) -> ColumnarBatch:
+                         cache_key=None, on_exchange=None) -> ColumnarBatch:
     """Host driver for the SPMD sort with quota overflow-retry."""
     n = mesh.shape[axis]
     local_cap = batch.capacity // n
     # range partitions are less uniform than hash: start with a wider quota
     quota = default_quota(local_cap, n, factor=4)
     while True:
-        ck = None if cache_key is None else \
-            cache_key + (n, local_cap, quota, use_allgather)
         step = _jit_step(
             lambda: distributed_sort_step(
                 sort_exprs, ascending, nulls_first, mesh, quota, axis=axis,
-                use_allgather=use_allgather), ck)
+                use_allgather=use_allgather),
+            "sort", cache_key, n, local_cap, quota, use_allgather)
         with mesh:
             out, overflow = step(batch)
+        if on_exchange is not None:
+            # the row exchange only: the sampled range bounds' all-gather
+            # is n*n_samples scalars
+            on_exchange(exchange_ici_bytes(
+                batch, n, local_cap if use_allgather else quota))
         if use_allgather or int(overflow) == 0:
             return out
         if quota >= local_cap:  # pragma: no cover - cannot overflow at cap

@@ -42,6 +42,7 @@ from typing import List, Optional
 
 from .. import config as C
 from ..metrics import names as MN
+from ..utils.tracing import named_range
 from .lifecycle import (QueryCancelled, QueryDeadlineExceeded,
                         QueryLifecycle, QueryTimeout)
 from .plan_cache import PlanCache
@@ -484,7 +485,7 @@ class QueryScheduler:
             # one DataFrame, and planning lazily writes into their
             # __dict__ (plan_schema's _cached_schema) — fingerprinting
             # vars() concurrently would race that first-touch insert
-            with self._plan_lock:
+            with self._plan_lock, named_range("plan"):
                 if self.plan_cache is not None:
                     normalized, values, hit = self.plan_cache.lookup(
                         logical, session.conf)

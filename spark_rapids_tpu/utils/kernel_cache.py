@@ -13,6 +13,8 @@ jit itself underneath one cache entry.
 from __future__ import annotations
 
 import contextlib
+import functools
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -51,6 +53,88 @@ _STAGE_COSTS: Dict[tuple, dict] = {}
 # shows up here as stage/kernel hits instead of fresh builds)
 _COUNTERS = {"builds": 0, "stage_compiles": 0, "dispatches": 0,
              "kernel_hits": 0, "stage_hits": 0, "donated_buffers": 0}
+
+
+# --- program names ----------------------------------------------------------
+# Every executable this module jits is named `<layer>.<role>`, so a
+# profiler trace's `XLA Modules` line and jax's `PjitFunction(...)` host
+# spans read `jit_agg.whole_stage_bucket`, `jit_scan.pq_bp`,
+# `jit_dist.join_probe` instead of the closure's name (`jit_k`).  What
+# stays un-named in a trace is then an eager op outside any compiled
+# program.  THE table: the module a program's builder lives in (relative
+# to the package; a package name covers its modules) -> its layer.
+_LAYER_OF_MODULE = {
+    "io": "scan",
+    "exec.aggregate": "agg",
+    "streaming.state": "agg",
+    "exec.join": "join",
+    "exec.sort": "sort",
+    "exec.window": "sort",
+    "exec.whole_stage": "stage",
+    "exec.exchange": "stage",
+    "exec.distributed": "dist",
+    "parallel.distributed": "dist",
+    "shuffle.mesh_exchange": "dist",
+    "columnar.contiguous": "mem",
+    "exec.basic": "expr",
+    "exec.generate": "expr",
+}
+_PACKAGE = __name__.split(".")[0] + "."
+_OPERATOR_CLASS = re.compile(r"(?:Tpu)?(\w+?)Exec")
+_ROLE_WORD = re.compile(r"[A-Za-z][\w-]{0,31}")
+
+
+def program_layer(builder) -> str:
+    """The layer of the program `builder` builds, from the module the
+    builder was written in.  An unmapped module is an error: a program
+    nobody can place must not reach a trace as a silent `misc`."""
+    while isinstance(builder, functools.partial):
+        builder = builder.func
+    module = getattr(builder, "__module__", None) or ""
+    rel = module.removeprefix(_PACKAGE)
+    while rel:
+        if rel in _LAYER_OF_MODULE:
+            return _LAYER_OF_MODULE[rel]
+        rel = rel.rpartition(".")[0]
+    raise KeyError(
+        f"kernel_cache: no layer for a program built in {module!r}; add "
+        f"the module to kernel_cache._LAYER_OF_MODULE")
+
+
+def program_role(key: tuple) -> str:
+    """The role a cache key states: its string head (`pq_bp`,
+    `contig_pack`, `whole_stage`; an operator's class name is shortened,
+    `TpuHashJoinExec` -> `hashjoin`) and, where the call site appended a
+    word after the key's last structural (tuple) element, that word
+    (`... + ("probe", guess)` -> `hashjoin_probe`)."""
+    head = key[0] if key else None
+    if not isinstance(head, str) or not _ROLE_WORD.fullmatch(head):
+        raise ValueError(
+            f"kernel_cache: a cache key starts with the program's role, "
+            f"a short word; got {head!r}")
+    op = _OPERATOR_CLASS.fullmatch(head)
+    role = op[1].lower() if op else head
+    tuples = [i for i, x in enumerate(key) if isinstance(x, tuple)]
+    if tuples and tuples[-1] + 1 < len(key):
+        word = key[tuples[-1] + 1]
+        if isinstance(word, str) and _ROLE_WORD.fullmatch(word):
+            role += "_" + word
+    return role
+
+
+def named_jit(builder: Callable[[], Callable], role: str, **jit_kw):
+    """`jax.jit` of what `builder` builds, named `<layer>.<role>`: the one
+    way a program of this package is jitted.  The name is what `jax.jit`
+    reads for the executable (`jit_<name>`) and its `PjitFunction(<name>)`
+    host span; it goes on a wrapper, because bound methods and partials
+    take no `__name__`."""
+    fn = builder()
+
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = \
+        f"{program_layer(builder)}.{role}"
+    return jax.jit(program, **jit_kw)
 
 
 def record_dispatch(n: int = 1) -> None:
@@ -118,7 +202,7 @@ def stage_executable(key: tuple, builder: Callable[[], Callable],
     from ..metrics.journal import journal_event
     timer = (metrics.timer(MN.STAGE_COMPILE_TIME) if metrics is not None
              else None)
-    jfn = jax.jit(builder(), donate_argnums=donate_argnums)
+    jfn = named_jit(builder, name, donate_argnums=donate_argnums)
     t0 = time.perf_counter()
     if timer is not None:
         timer.__enter__()
@@ -277,11 +361,12 @@ def cached_kernel(key: tuple, builder: Callable[[], Callable],
     reflected in the key by the caller: a donated kernel always deletes
     its donated inputs, so it can never share an entry with the
     non-donated variant."""
+    role_key = key
     if jit_kw.get("donate_argnums"):
         key = key + ("donate", tuple(jit_kw["donate_argnums"]))
     fn = _CACHE.get(key)
     if fn is None:
-        fn = jax.jit(builder(), **jit_kw)
+        fn = named_jit(builder, program_role(role_key), **jit_kw)
         with _CACHE_LOCK:
             if key in _CACHE:
                 return _CACHE[key]

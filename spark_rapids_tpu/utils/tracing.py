@@ -11,20 +11,50 @@ from __future__ import annotations
 import contextlib
 import time
 
+import jax
 
-@contextlib.contextmanager
-def named_range(name: str, metrics=None, metric_name: str = None):
-    """A profiler range; optionally accumulates elapsed seconds into a
-    Metrics object (NvtxWithMetrics equivalent)."""
-    import jax
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
-            with jax.named_scope(name):
-                yield
-    finally:
-        if metrics is not None:
-            metrics.add(metric_name or name, time.perf_counter() - t0)
+#: every span the program opens carries this prefix in the profiler's
+#: trace, so one pattern (`^srt:`) separates the program's spans from
+#: JAX's runtime spans and a harness's own
+SPAN_PREFIX = "srt:"
+
+
+class named_range:
+    """THE span primitive: a `jax.profiler.TraceAnnotation` named
+    `srt:<name>` on the profiler's clock (the one the device trace shares)
+    plus a `jax.named_scope(name)` for whatever is traced inside; optionally
+    accumulates elapsed seconds into a Metrics object under `metric_name`
+    (NvtxWithMetrics equivalent), so a span and its timer are one site.
+
+    `args` (`q=<query id>`, `rows=`, `bytes=`) land in the annotation and
+    must be host-known: a span never reads the device and never syncs it.
+    The span that caused a span is the one it nests in on its thread."""
+
+    __slots__ = ("_annotation", "_scope", "_metrics", "_metric_name", "_t0")
+
+    def __init__(self, name: str, metrics=None, metric_name: str = None,
+                 **args):
+        self._annotation = jax.profiler.TraceAnnotation(SPAN_PREFIX + name,
+                                                        **args)
+        self._scope = jax.named_scope(name)
+        self._metrics = metrics
+        self._metric_name = metric_name or name
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._annotation.__enter__()
+        self._scope.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        try:
+            self._scope.__exit__(*exc)
+            self._annotation.__exit__(*exc)
+        finally:
+            if self._metrics is not None:
+                self._metrics.add(self._metric_name,
+                                  time.perf_counter() - self._t0)
+        return False
 
 
 @contextlib.contextmanager
@@ -35,7 +65,6 @@ def profile_trace(log_dir: str, journal=None):
     Chrome trace-event file in `log_dir`, so the engine's
     operator/retry/spill/fetch timeline sits next to the XLA device
     timeline in the same viewer."""
-    import jax
     jax.profiler.start_trace(log_dir)
     try:
         yield

@@ -192,14 +192,14 @@ def _largest(programs, name_part):
 
 @pytest.mark.parametrize("query,kernel,min_rows", [
     # scan -> filter -> aggregate over all six 1M-row batches, one program
-    ("q6", "whole", 1 << 20),
+    ("q6", "agg.whole_stage", 1 << 20),
     # the join's fused window+count kernel over a full probe batch
-    ("q3_join", "_probe_kernel", 1 << 20),
+    ("q3_join", "join.hashjoin_probe", 1 << 20),
     # the 64-bit sort: packed u64 keys, revenue (a double: the f32-pair
     # keys, where the f64 comparator took 9 minutes) DESC then o_orderdate
-    ("q3_join", "TpuSortExec", 1 << 20),
+    ("q3_join", "sort.sort", 1 << 20),
     # the contiguous pack of a full reader batch (f64 leaves as f32 pairs)
-    ("pack", "pack_batch", 1 << 20),
+    ("pack", "mem.contig_pack", 1 << 20),
 ])
 def test_smoke_program_compiles_for_v5e(smoke_programs, one_chip,
                                         no_persistent_cache, tpu_branches,

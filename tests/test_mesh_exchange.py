@@ -497,11 +497,17 @@ def test_stage_executable_is_keyed_by_placement():
 
     from spark_rapids_tpu.utils import kernel_cache as KC
     key = ("placement-regression",)
+
+    def double():
+        return lambda v: v * 2
+    # kernel_cache names a program after the layer its builder's module
+    # belongs to; this one stands in for the mesh exchange's
+    double.__module__ = "spark_rapids_tpu.shuffle.mesh_exchange"
     before = KC.stats()["stage_compiles"]
     outs = []
     for d in jax.devices()[:2]:
         x = jax.device_put(np.arange(8.0), d)
-        fn = KC.stage_executable(key, lambda: (lambda v: v * 2), (x,),
+        fn = KC.stage_executable(key, double, (x,),
                                  name="placement")
         outs.append(fn(x))
     assert [next(iter(o.devices())).id for o in outs] == \
@@ -509,6 +515,6 @@ def test_stage_executable_is_keyed_by_placement():
     assert KC.stats()["stage_compiles"] == before + 2
     # same device again: a hit, not a third compile
     x = jax.device_put(np.arange(8.0), jax.devices()[1])
-    KC.stage_executable(key, lambda: (lambda v: v * 2), (x,),
+    KC.stage_executable(key, double, (x,),
                         name="placement")(x)
     assert KC.stats()["stage_compiles"] == before + 2

@@ -1,0 +1,320 @@
+"""What a profiler trace shows of a query (utils/tracing.named_range,
+utils/kernel_cache.named_jit): the collect path's phases as `srt:` spans on
+the profiler's clock, every compiled program under `<layer>.<role>`, and
+the two counters the spans and the SPMD operators feed (`scanTime`,
+`iciBytesMoved`)."""
+import glob
+import os
+import re
+import statistics
+import sys
+from pathlib import Path
+
+import jax
+import numpy as np
+import pyarrow as pa
+import pyarrow.parquet as papq
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+from spark_rapids_tpu import types as T  # noqa: E402
+from spark_rapids_tpu.engine import TpuSession  # noqa: E402
+from spark_rapids_tpu.metrics import names as MN  # noqa: E402
+from spark_rapids_tpu.parallel.distributed import default_quota  # noqa: E402
+from spark_rapids_tpu.plan.logical import col, functions as F  # noqa: E402
+from spark_rapids_tpu.utils import kernel_cache as KC  # noqa: E402
+from spark_rapids_tpu.utils.tracing import SPAN_PREFIX, named_range  # noqa: E402
+
+pytestmark = pytest.mark.tracing
+
+COLLECT = "test:collect"
+PROGRAM_NAME = re.compile(r"^(scan|agg|join|sort|stage|dist|mem|expr|obs)\.")
+ROWS = 400_000
+
+
+def lineitem(n=ROWS, seed=5):
+    rng = np.random.default_rng(seed)
+    return pa.table({
+        "l_quantity": rng.integers(1, 51, n).astype(np.float64),
+        "l_extendedprice": rng.uniform(900.0, 105000.0, n),
+        "l_discount": rng.integers(0, 11, n) / 100.0,
+        "l_shipdate": rng.integers(8036, 10562, n).astype(np.int64)})
+
+
+def q6(frame):
+    return (frame.filter((col("l_shipdate") >= 8766)
+                         & (col("l_shipdate") < 9131)
+                         & (col("l_discount") >= 0.05)
+                         & (col("l_discount") <= 0.07)
+                         & (col("l_quantity") < 24.0))
+            .agg(F.sum(col("l_extendedprice") * col("l_discount"))
+                 .alias("revenue")))
+
+
+def traced(df, tmp_path, queries=5):
+    """Warm `df`, then `queries` collects under the profiler, each inside a
+    `test:collect` annotation.  -> per host thread, its events as
+    (start_ns, end_ns, name, stats) by start."""
+    for _ in range(2):
+        df.collect()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(queries):
+            with jax.profiler.TraceAnnotation(COLLECT):
+                df.collect()
+    finally:
+        jax.profiler.stop_trace()
+    [pb] = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(pb)
+    [host] = [p for p in data.planes if p.name == "/host:CPU"]
+    return [sorted((int(e.start_ns), int(e.start_ns + e.duration_ns),
+                    e.name, dict(e.stats)) for e in line.events)
+            for line in host.lines]
+
+
+def phases(threads):
+    """-> per traced collect, (the collect's span, the program's spans
+    inside it on the querying thread), and every thread's span names."""
+    [thread] = [th for th in threads if any(e[2] == COLLECT for e in th)]
+    out = []
+    for c in (e for e in thread if e[2] == COLLECT):
+        out.append((c, [e for e in thread if e[2].startswith(SPAN_PREFIX)
+                        and c[0] <= e[0] and e[1] <= c[1]]))
+    return out, {e[2] for th in threads for e in th}
+
+
+def inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def check_phases(collects, session):
+    for collect, spans in collects:
+        by = {}
+        for e in spans:
+            by.setdefault(e[2], []).append(e)
+        plan, begin, execute, finish, rows = (
+            by["srt:" + n][0] for n in ("plan", "begin", "execute",
+                                        "finish", "rows"))
+        # one after the other, and the operators' spans inside the drain
+        order = [plan, begin, execute, finish, rows]
+        assert all(a[1] <= b[0] for a, b in zip(order, order[1:]))
+        for name in ("srt:semaphore", "srt:d2h", "srt:agg_whole_stage"):
+            assert all(inside(e, execute) for e in by[name]), name
+        assert by["srt:semaphore"][0][1] <= by["srt:agg_whole_stage"][0][0]
+        assert by["srt:agg_whole_stage"][0][1] <= by["srt:d2h"][0][0]
+        for e in by.get("srt:metrics_fold", []):
+            assert inside(e, finish)
+        # host-known counts ride in the annotation
+        assert int(by["srt:d2h"][0][3]["bytes"]) > 0
+        assert int(rows[3]["rows"]) == 1
+        assert execute[3]["q"] == finish[3]["q"]
+    # the spans of one query share the journal's id
+    last = collects[-1][1]
+    assert {int(e[3]["q"]) for e in last if "q" in e[3]} == {
+        session.last_execution.query_id}
+    covered = []
+    for collect, spans in collects:
+        top = [e for e in spans
+               if not any(o is not e and inside(e, o) for o in spans)]
+        covered.append(sum(e[1] - e[0] for e in top)
+                       / (collect[1] - collect[0]))
+    assert statistics.median(covered) >= 0.95, covered
+
+
+def test_a_warm_query_lies_under_the_programs_phase_spans(tmp_path):
+    session = TpuSession({"spark.rapids.sql.variableFloatAgg.enabled": "true"})
+    collects, names = phases(traced(q6(session.from_arrow(lineitem())),
+                                    tmp_path))
+    assert len(collects) == 5
+    check_phases(collects, session)
+    # the one program of the query runs under its layer's name; what jax
+    # names by itself is an eager op
+    assert "PjitFunction(agg.whole_stage)" in names
+    assert not {n for n in names
+                if re.match(r"PjitFunction\((k|kern|whole\w*)\)", n)}
+
+
+def test_a_parquet_query_adds_the_scans_span_and_timer(tmp_path):
+    path = str(tmp_path / "lineitem.parquet")
+    papq.write_table(lineitem(), path, compression="snappy")
+    session = TpuSession({"spark.rapids.sql.variableFloatAgg.enabled": "true"})
+    threads = traced(q6(session.read.parquet(path)), tmp_path / "trace")
+    collects, names = phases(threads)
+    check_phases(collects, session)
+    decodes = [e for th in threads for e in th if e[2] == "srt:scan_decode"]
+    assert decodes
+    # each decode ends before the query that asked for it does
+    assert all(any(c[0] <= e[0] and e[1] <= c[1] for c, _ in collects)
+               for e in decodes if e[0] >= collects[0][0][0])
+    agg = session.last_execution.aggregate()
+    assert agg[MN.NUM_DEVICE_DECODED_COLUMNS] > 0
+    # the timer is the span's own: one query's is part of the five's spans
+    assert 0 < agg[MN.SCAN_TIME] <= sum(e[1] - e[0] for e in decodes) / 1e9
+
+
+def test_named_range_is_a_span_and_a_timer_and_never_a_sync():
+    from spark_rapids_tpu.metrics.registry import DEVICE_SYNCS, Metrics
+    m = Metrics()
+    before = DEVICE_SYNCS.count
+    with named_range("scan_decode", m, MN.SCAN_TIME, rows=3):
+        pass
+    with named_range("agg_update", m):      # unregistered name: recorded
+        pass
+    assert m.values[MN.SCAN_TIME] > 0 and "agg_update" in m.values
+    assert DEVICE_SYNCS.count == before
+    import inspect
+    from spark_rapids_tpu.utils import tracing
+    src = inspect.getsource(tracing.named_range)
+    assert not re.search(r"block_until_ready|device_get|np\.asarray", src)
+
+
+# -- program names -----------------------------------------------------------
+
+def run_program_zoo(tmp_path):
+    """Aggregate (fused and streaming), join, sort, window, Parquet scan and
+    write, and the SPMD operators: the programs of the query, join, sort,
+    scan and mesh paths."""
+    rng = np.random.default_rng(11)
+    n = 5000
+    facts = pa.table({"k": rng.integers(0, 50, n).astype(np.int32),
+                      "v": rng.integers(0, 1000, n).astype(np.int64),
+                      "d": rng.uniform(0, 1, n)})
+    dims = pa.table({"k": np.arange(50, dtype=np.int32),
+                     "w": np.arange(50, dtype=np.int64)})
+    path = str(tmp_path / "facts.parquet")
+    papq.write_table(facts, path, compression="snappy",
+                     use_dictionary=True)
+    for conf in ({}, {"spark.rapids.sql.tpu.wholeStage.enabled": "false"},
+                 {"spark.rapids.sql.tpu.fusion.enabled": "false"},
+                 {"spark.rapids.sql.tpu.mesh.devices": "4",
+                  "spark.sql.autoBroadcastJoinThreshold": "-1"}):
+        s = TpuSession({"spark.rapids.sql.variableFloatAgg.enabled": "true",
+                        **conf})
+        f, d = s.from_arrow(facts), s.from_arrow(dims)
+        (f.filter(col("v") > 10).group_by("k")
+         .agg(F.sum(col("v")).alias("s"), F.avg(col("d")).alias("a"))
+         .order_by("k").collect())
+        f.join(d, on="k").order_by("v", "k").limit(7).collect()
+        s.read.parquet(path).filter(col("v") < 500).group_by("k").agg(
+            F.count(col("v")).alias("c")).collect()
+        f.select((col("v") + 1).alias("v1"), col("k")).repartition(
+            4, "k").collect()
+    s.read.parquet(path).write.parquet(str(tmp_path / "out"))
+
+
+def test_every_compiled_program_is_named_after_its_layer(tmp_path,
+                                                         monkeypatch):
+    built = []
+    real = KC.named_jit
+
+    def recording(builder, role, **kw):
+        fn = real(builder, role, **kw)
+        built.append(fn.__name__)
+        return fn
+    monkeypatch.setattr(KC, "named_jit", recording)
+    KC.clear()
+    run_program_zoo(tmp_path)
+    assert len(set(built)) >= 15, sorted(set(built))
+    assert all(PROGRAM_NAME.match(n) for n in built), sorted(set(built))
+    layers = {n.split(".")[0] for n in built}
+    assert layers >= {"scan", "agg", "join", "sort", "stage", "dist", "mem",
+                      "expr"}, layers
+    # what the cache holds is what was named: nothing jitted on the side
+    assert {fn.__name__ for fn in KC._CACHE.values()} <= set(built)
+    assert set(KC._LAYER_OF_MODULE.values()) <= set(
+        PROGRAM_NAME.pattern[2:-3].split("|"))
+
+
+def test_a_program_from_an_unmapped_module_is_an_error():
+    def builder():
+        return lambda x: x + 1
+    with pytest.raises(KeyError, match="no layer for a program built in"):
+        KC.cached_kernel(("unmapped_probe", 1), builder)
+    with pytest.raises(KeyError, match="_LAYER_OF_MODULE"):
+        KC.stage_executable(("unmapped_probe",), builder, (1,))
+    builder.__module__ = "spark_rapids_tpu.io.some_new_reader"
+    assert KC.program_layer(builder) == "scan"      # a package's modules
+    assert ("unmapped_probe", 1) not in KC._CACHE
+
+
+@pytest.mark.parametrize("key, role", [
+    (("pq_bp", 3, 1024), "pq_bp"),
+    (("contig_pack", (("a", "int"),)), "contig_pack"),
+    (("TpuHashJoinExec", "inner", "packed", (1,), (2,), "probe", 4),
+     "hashjoin_probe"),
+    (("whole_stage", 2, 1024, (), "treedef", "TpuHashAggregateExec", "xla",
+      ((1,),), "bucket"), "whole_stage_bucket"),
+    (("TpuSortExec", "packed", "xla", (1,), (True,), (False,)), "sort"),
+    (("TpuProjectExec", ((1,),), "/data/part-0.parquet"), "project"),
+    (("join_probe", "TpuHashJoinExec", "inner", (1,), 4, 256, True),
+     "join_probe"),
+])
+def test_a_cache_key_states_the_programs_role(key, role):
+    assert KC.program_role(key) == role
+
+
+def test_a_cache_key_without_a_role_is_an_error():
+    with pytest.raises(ValueError, match="starts with the program's role"):
+        KC.program_role(((1, 2), "late"))
+    with pytest.raises(ValueError):
+        KC.program_role(())
+
+
+# -- iciBytesMoved -----------------------------------------------------------
+
+def row_bytes(*dtypes):
+    """Data + validity byte per column, + the selection mask's byte."""
+    return sum(np.dtype(t).itemsize + 1 for t in dtypes) + 1
+
+
+@pytest.mark.parametrize("allgather", [False, True],
+                         ids=["all_to_all", "all_gather"])
+def test_spmd_operators_declare_their_ici_bytes(allgather):
+    n, rows = 4, 1000
+    cap = 1024                       # bucket_rows(1000)
+    local = cap // n
+    session = TpuSession({
+        "spark.rapids.sql.tpu.mesh.devices": str(n),
+        "spark.rapids.sql.tpu.mesh.useAllGather": str(allgather).lower(),
+        "spark.sql.autoBroadcastJoinThreshold": "-1"})
+    left = session.from_arrow(pa.table({
+        "k": np.arange(rows, dtype=np.int32),
+        "v": np.arange(rows, dtype=np.int64)}))
+    right = session.from_arrow(pa.table({
+        "k": np.arange(rows, dtype=np.int32),
+        "w": np.arange(rows, dtype=np.int64) * 3}))
+
+    def moved(df, op):
+        got = df.collect()
+        [node] = [m for m in session.last_execution.node_metrics()
+                  if m["op"] == op]
+        return got, node["metrics"][MN.ICI_BYTES_MOVED]
+
+    def expected(rows_per_peer_a2a, width, exchanges=1):
+        per_peer = local if allgather else rows_per_peer_a2a
+        return exchanges * n * (n - 1) * per_peer * width
+
+    # sort: one exchange of the input rows, quota from factor 4
+    got, ici = moved(left.order_by("v"), "TpuDistributedSortExec")
+    assert [r[1] for r in got] == list(range(rows))
+    assert ici == expected(default_quota(local, n, factor=4),
+                           row_bytes("i4", "i8"))
+    # join: the build side once, then the one probe chunk
+    got, ici = moved(left.join(right, on="k"), "TpuDistributedJoinExec")
+    assert len(got) == rows
+    assert ici == expected(default_quota(local, n), row_bytes("i4", "i8"),
+                           exchanges=2)
+    # aggregate: the partial state (key, sum) of the one chunk
+    agg_df = left.group_by("k").agg(F.sum(col("v")).alias("s"))
+    got, ici = moved(agg_df, "TpuDistributedAggregateExec")
+    assert sorted(got) == [(i, i) for i in range(rows)]
+    [agg] = [x for x in session.last_execution.nodes
+             if type(x).__name__ == "TpuDistributedAggregateExec"]
+    width = sum(f.dtype.np_dtype.itemsize + 1
+                for f in agg._state_schema) + 1
+    assert ici == expected(default_quota(local, n), width)
+    assert session.query_metrics_total[MN.ICI_BYTES_MOVED] >= ici
