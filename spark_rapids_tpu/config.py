@@ -283,7 +283,7 @@ AGG_MERGE_FAN_IN = _conf(
     "and host syncs across more input batches.", int)
 AGG_BUCKET_GROUPS = _conf(
     "spark.rapids.sql.tpu.agg.bucketGroups", True,
-    "Low-cardinality grouped-aggregate fast path: rows scatter into "
+    "Low-cardinality grouped-aggregate fast path: rows belong to "
     "hash buckets and per-bucket states replace the per-batch sort when "
     "every bucket holds one distinct key (checked exactly per batch; "
     "dirty batches fall back to the sort path).  Applies to "

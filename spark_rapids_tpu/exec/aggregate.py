@@ -78,24 +78,29 @@ def _type_min(dt):
                        j)
 
 
-def _key_equal_at(c: Column, idx):
-    """Row i's key value-equals the key at row idx[i] (Spark grouping
-    equality: nulls equal, NaN equal, -0.0 == 0.0 — the same contract as
-    _col_differs_from_prev, against an arbitrary gathered row)."""
+def _key_equal_slots(c: Column, rows):
+    """[G, cap]: row i's key value-equals the key at row rows[g] (Spark
+    grouping equality: nulls equal, NaN equal, -0.0 == 0.0 — the same
+    contract as _col_differs_from_prev).  A broadcast compare against the
+    G gathered keys: no index operand has cap elements."""
     from ..ops.hashing import _normalize_bits
-    vg = jnp.take(c.valid, idx)
-    both_null = (~c.valid) & (~vg)
-    valid_mismatch = c.valid != vg
+    vg = jnp.take(c.valid, rows)[:, None]
+    v = c.valid[None, :]
     if c.dtype.is_string:
-        dg = jnp.take(c.data, idx, axis=0)
-        lg = jnp.take(c.lengths, idx)
-        dd = jnp.all(c.data == dg, axis=1) & (c.lengths == lg)
+        # 4 bytes to a word, one [G, cap] compare per word: a reduce over
+        # the byte axis would materialise its [G, cap] result
+        cap, L = c.data.shape
+        w = jax.lax.bitcast_convert_type(
+            c.data.reshape(cap, L // 4, 4), jnp.uint32)
+        wg = jnp.take(w, rows, axis=0)
+        lg = jnp.take(c.lengths, rows)
+        dd = c.lengths[None, :] == lg[:, None]
+        for j in range(L // 4):
+            dd &= w[None, :, j] == wg[:, j, None]
     else:
         bits = _normalize_bits(c)
-        dd = bits == jnp.take(bits, idx)
-    return jnp.where(both_null, True,
-                     jnp.where(valid_mismatch, False,
-                               jnp.where(c.valid, dd, True)))
+        dd = bits[None, :] == jnp.take(bits, rows)[:, None]
+    return jnp.where(v & vg, dd, v == vg)
 
 
 def group_rows(key_cols: Sequence[Column], live, value_cols=None):
@@ -608,10 +613,15 @@ class TpuHashAggregateExec(TpuExec):
     # ---- low-cardinality bucket fast path ---------------------------------
 
     _BUCKETS = 1024
+    # occupied buckets one pass of _bucket_update_kernel reduces: the
+    # largest of 8, 16, 32 at which a 1M-row pass on a v5e stays under a
+    # tenth of what the scatter form it replaced took (7.0 ms against
+    # 980 ms, PERF.md PR 26)
+    _DENSE_GROUPS = 32
 
     def _bucketable(self) -> bool:
         """Aggregate set eligible for the bucket fast path: mergeable
-        scatter-computable states (sum/count/avg, non-string min/max),
+        states a masked reduce computes (sum/count/avg, non-string min/max),
         no distinct dedup, no arrival-order state."""
         if not self.grouping:
             return False
@@ -625,71 +635,138 @@ class TpuHashAggregateExec(TpuExec):
         return True
 
     def _bucket_update_kernel(self, batch: ColumnarBatch):
-        """-> (clean: bool[], state batch at capacity _BUCKETS).
+        """-> (took: int32[], state batch at capacity _BUCKETS).
 
-        The sort-free grouped update: rows scatter into h1-hash buckets;
-        `clean` is an EXACT per-batch check that every live row's key
-        VALUE-equals its bucket representative's (so each occupied bucket
-        holds one distinct group, with Spark key semantics: nulls equal,
-        NaN equal, -0.0 == 0.0).  When clean, per-bucket segment
-        reductions are the partial state — same schema as the sort path,
-        so the merge/finalize kernels take either.  More distinct groups
-        than buckets forces a collision, so high-cardinality batches
-        fail the check and take the sort path; no cardinality estimate
-        is needed.  XLA lowers the segment ops to scatter-adds; on TPU
-        the alternative one-hot-matmul formulation rides the MXU, but
-        scatter keeps the state layout identical across backends."""
-        B = self._BUCKETS
+        The sort-free grouped update: every live row belongs to the
+        h1-hash bucket `sid`, and the per-bucket reductions are the
+        partial state IF every occupied bucket holds one distinct group.
+        That is checked EXACTLY per batch (`clean`: each live row's key
+        VALUE-equals its bucket representative's, with Spark key
+        semantics: nulls equal, NaN equal, -0.0 == 0.0).  More distinct
+        groups than buckets forces a collision, so high-cardinality
+        batches fail the check and take the sort path; no cardinality
+        estimate is needed.  The state has the sort path's schema, so
+        the merge/finalize kernels take either.
+
+        Nothing is scattered or gathered per row.  A pass takes the next
+        _DENSE_GROUPS occupied buckets in order (successive masked minima
+        of `sid`), compares each row against those buckets' ids and their
+        representatives' keys (a [G, cap] broadcast compare inside its
+        fusion) and makes every aggregate a masked reduce along the rows;
+        a `while_loop` on the device runs as many passes as the batch's
+        own occupancy asks for and stops at the first dirty one.
+
+        What the chip showed (v5e, TPC-H Q1, 1M-row batches of 6 groups;
+        PERF.md, PR 26): as `segment_sum/min/max` over `sid` XLA lowers
+        the reductions to serial scatter-adds of 74-80 ms each and each
+        scatter-set of the probe costs 5 ms, a batch 980 ms; one pass
+        here takes the same batch in 7.0 ms.
+
+        `took`: -1 dirty, 1 clean in one pass, 0 clean in more: the one
+        integer a caller reads where it read `clean`."""
+        B, G = self._BUCKETS, self._DENSE_GROUPS
         keys = [g.eval(batch) for g in self.grouping]
+        cols = [a.child.eval(batch) if a.child is not None else None
+                for a in self.aggregates]
         live = batch.sel
         cap = batch.capacity
         h1, _h2 = hash_columns_double(keys, live)
         ids = (h1 & jnp.uint64(B - 1)).astype(jnp.int32)
         sid = jnp.where(live, ids, B)  # B = trash bucket for dead rows
         iota = jnp.arange(cap, dtype=jnp.int32)
-        rep = jnp.zeros(B, jnp.int32).at[sid].set(iota, mode="drop")
-        occ = jnp.zeros(B, jnp.bool_).at[sid].set(True, mode="drop")
-        rep_of_row = jnp.take(rep, ids)
-        eq = jnp.ones(cap, jnp.bool_)
-        for k in keys:
-            eq &= _key_equal_at(k, rep_of_row)
-        clean = jnp.all(jnp.where(live, eq, True))
+        agg_fields = self._state_schema.fields[len(keys):]
 
-        def seg(vals, mask, reducer, fill):
-            full = jnp.where(mask, vals, fill)
-            return reducer(full, sid, num_segments=B + 1)[:B]
+        def next_bucket(prev, _):
+            nxt = jnp.min(jnp.where(sid > prev, sid, B))
+            return nxt, nxt
 
+        def one_pass(carry):
+            prev, _more, passes, clean, occ, rep, state = carry
+            # the next G + 1 occupied buckets in order, B where no more
+            # are: slot g stands for the g-th, the last one only says
+            # whether another pass has to follow
+            _, lowest = jax.lax.scan(next_bucket, prev, None, length=G + 1)
+            slot_bucket = lowest[:G]
+            match = sid[None, :] == slot_bucket[:, None]      # [G, cap]
+            rep_row = jnp.max(jnp.where(match, iota[None, :], 0), axis=1)
+            eq = jnp.ones((G, cap), jnp.bool_)
+            for k in keys:
+                eq &= _key_equal_slots(k, rep_row)
+            clean &= jnp.all(jnp.where(match & live[None, :], eq, True))
+
+            def reduce(op, vals, mask, fill):
+                reducer = {"sum": jnp.sum, "min": jnp.min,
+                           "max": jnp.max}[op]
+                return reducer(jnp.where(match & mask[None, :],
+                                         vals[None, :], fill), axis=1)
+
+            def count(mask):
+                # cap < 2**31: int32 sums, widened after
+                return reduce("sum", mask.astype(jnp.int32), live,
+                              jnp.int32(0)).astype(jnp.int64)
+
+            def put(into, r):
+                # unused slots hold B, which "drop" keeps out of the state
+                return into.at[slot_bucket].set(r, mode="drop")
+            slots = self._bucket_states(cols, live, count, reduce)
+            state = [(put(d, c.data), put(v, c.valid))
+                     for (d, v), c in zip(state, slots)]
+            return (slot_bucket[G - 1], lowest[G] < B, passes + 1, clean,
+                    put(occ, slot_bucket < B), put(rep, rep_row), state)
+
+        def unfinished(carry):
+            _prev, more, _passes, clean, *_ = carry
+            return more & clean
+
+        empty = jnp.zeros(B, jnp.bool_)
+        _, _, passes, clean, occ, rep, state = jax.lax.while_loop(
+            unfinished, one_pass,
+            (jnp.int32(-1), jnp.bool_(True), jnp.int32(0), jnp.bool_(True),
+             empty, jnp.zeros(B, jnp.int32),
+             [(jnp.zeros(B, f.dtype.jnp_dtype), empty)
+              for f in agg_fields]))
+        key_state = [k.take(rep) for k in keys]
+        agg_state = [Column(d, v, f.dtype)
+                     for (d, v), f in zip(state, agg_fields)]
+        state_cols = [c.with_valid(c.valid & occ).mask_invalid()
+                      if not c.dtype.is_string else c
+                      for c in key_state + agg_state]
+        took = jnp.where(clean, (passes == 1).astype(jnp.int32),
+                         jnp.int32(-1))
+        return took, ColumnarBatch(state_cols, occ, self._state_schema)
+
+    def _bucket_states(self, cols, live, count, reduce) -> List[Column]:
+        """Aggregate state columns of one pass of _bucket_update_kernel,
+        a row a slot, over the pass's two reducers: `count(mask)` ->
+        int64 rows of each slot's bucket under mask, `reduce(op, vals,
+        mask, fill)` -> the bucket's sum/min/max of vals under mask
+        (`fill` where none)."""
         state_cols: List[Column] = []
-        for k in keys:
-            kk = k.take(rep)
-            state_cols.append(kk)
-        for a in self.aggregates:
-            col = a.child.eval(batch) if a.child is not None else None
+        for a, col in zip(self.aggregates, cols):
             f = a.func
             if f == "Count":
-                contribute = live if col is None else live & col.valid
-                cnt = seg(contribute.astype(jnp.int64), live,
-                          jax.ops.segment_sum, jnp.int64(0))
-                state_cols.append(Column(cnt, jnp.ones(B, jnp.bool_),
+                cnt = count(live if col is None else live & col.valid)
+                state_cols.append(Column(cnt, jnp.ones_like(cnt, jnp.bool_),
                                          LongType))
                 continue
             contribute = live & col.valid
-            nvalid = seg(contribute.astype(jnp.int64), live,
-                         jax.ops.segment_sum, jnp.int64(0))
+            nvalid = count(contribute)
             if f in ("Sum", "Average"):
                 out_t = DoubleType if f == "Average" else a.dtype
                 v = col.data.astype(out_t.jnp_dtype)
-                s = seg(v, contribute, jax.ops.segment_sum,
-                        jnp.zeros((), out_t.jnp_dtype))
+                s = reduce("sum", v, contribute,
+                           jnp.zeros((), out_t.jnp_dtype))
                 state_cols.append(Column(s, nvalid > 0, out_t)
                                   .mask_invalid())
                 if f == "Average":
                     state_cols.append(Column(nvalid,
-                                             jnp.ones(B, jnp.bool_),
+                                             jnp.ones_like(nvalid, jnp.bool_),
                                              LongType))
             else:  # Min / Max (numeric)
                 dt = a.child.dtype
                 v = col.data
+                op = f.lower()
+                fill = _type_max(dt) if f == "Min" else _type_min(dt)
                 if dt.is_floating:
                     # Spark float ordering: NaN greatest, -0.0 == 0.0
                     # (the sort path's [nan_flag, value] key, as direct
@@ -698,33 +775,19 @@ class TpuHashAggregateExec(TpuExec):
                     isnan = jnp.isnan(v)
                     v = jnp.where(v == 0.0, jnp.zeros((), v.dtype), v)
                     nn_mask = contribute & ~isnan
-                    n_nonnan = seg(nn_mask.astype(jnp.int64), live,
-                                   jax.ops.segment_sum, jnp.int64(0))
-                    if f == "Min":
-                        m = seg(v, nn_mask, jax.ops.segment_min,
-                                _type_max(dt))
-                        # all-NaN group: min is NaN
-                        m = jnp.where((nvalid > 0) & (n_nonnan == 0),
-                                      jnp.asarray(jnp.nan, v.dtype), m)
-                    else:
-                        m = seg(v, nn_mask, jax.ops.segment_max,
-                                _type_min(dt))
-                        # any NaN in group: max is NaN (NaN greatest)
-                        m = jnp.where(nvalid > n_nonnan,
-                                      jnp.asarray(jnp.nan, v.dtype), m)
+                    n_nonnan = count(nn_mask)
+                    m = reduce(op, v, nn_mask, fill)
+                    # min: NaN only for an all-NaN group; max: NaN for
+                    # any NaN in the group (NaN greatest)
+                    nan_wins = ((nvalid > 0) & (n_nonnan == 0)
+                                if f == "Min" else nvalid > n_nonnan)
+                    m = jnp.where(nan_wins, jnp.asarray(jnp.nan, v.dtype),
+                                  m)
                 else:
-                    if f == "Min":
-                        m = seg(v, contribute, jax.ops.segment_min,
-                                _type_max(dt))
-                    else:
-                        m = seg(v, contribute, jax.ops.segment_max,
-                                _type_min(dt))
+                    m = reduce(op, v, contribute, fill)
                 state_cols.append(Column(m, nvalid > 0, dt)
                                   .mask_invalid())
-        sel = occ
-        state_cols = [c.with_valid(c.valid & sel).mask_invalid()
-                      if not c.dtype.is_string else c for c in state_cols]
-        return clean, ColumnarBatch(state_cols, sel, self._state_schema)
+        return state_cols
 
     def _merge_kernel(self, state: ColumnarBatch) -> ColumnarBatch:
         """state batch (concat of partials) -> merged state batch."""
@@ -1086,10 +1149,12 @@ class TpuHashAggregateExec(TpuExec):
                     if pre is not None:
                         b = pre(b)
                     return bupdate(b)
-                outs = _unrolled(leaves, one)
-                cleans, partials = outs
+                took, partials = _unrolled(leaves, one)
                 both = _flatten_stacked(partials, state_schema)
-                return jnp.all(cleans), finalize(merge(both))
+                # one integer for the host's one read: the batches done
+                # in one dense pass, or -1 if any was dirty
+                n_dense = jnp.where(jnp.all(took >= 0), jnp.sum(took), -1)
+                return n_dense, finalize(merge(both))
             return _with_params(whole_bucket)
 
         # treedef in the key: the per-batch structure is baked into the
@@ -1139,9 +1204,11 @@ class TpuHashAggregateExec(TpuExec):
                              MN.COMPUTE_AGG_TIME):
                 from ..utils.kernel_cache import record_dispatch
                 record_dispatch()
-                all_clean, out = (fnb(pvals, *all_leaves) if pre_params
-                                  else fnb(*all_leaves))
-            if bool(all_clean):
+                n_dense, out = (fnb(pvals, *all_leaves) if pre_params
+                                else fnb(*all_leaves))
+            n_dense = int(n_dense)  # host sync: dirty, or one-pass batches
+            if n_dense >= 0:
+                self.metrics.add(MN.AGG_DENSE_BATCHES, n_dense)
                 self.metrics.add(MN.NUM_FUSED_STAGES, 1)
                 record_output_batch(self.metrics, out, ctx.runtime)
                 return out, None
@@ -1296,8 +1363,10 @@ class TpuHashAggregateExec(TpuExec):
             with self.metrics.timer(MN.SEG_AGG_TIME):
                 bfn = hot["bucket_fn"]
                 if bfn is not None:
-                    clean, bstate = bfn(b)
-                    if bool(clean):  # host sync: pick the sort-free state
+                    took, bstate = bfn(b)
+                    took = int(took)  # host sync: pick the sort-free state
+                    if took >= 0:
+                        self.metrics.add(MN.AGG_DENSE_BATCHES, took)
                         partial = bstate
                     else:
                         # dirty latch: a high-cardinality shape stays

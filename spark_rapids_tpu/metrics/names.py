@@ -279,6 +279,13 @@ NUM_PACKED_SORTS = register_metric(
     "sort dispatches that took the packed-key path (sort keys fused "
     "into 64-bit words + embedded row ids, single-operand sort passes) "
     "instead of the N-pass variadic lexsort")
+AGG_DENSE_BATCHES = register_metric(
+    "aggDenseBatches", COUNTER, ESSENTIAL,
+    "input batches whose grouped-aggregate bucket update finished in one "
+    "dense pass (at most 32 occupied buckets: masked reductions against "
+    "each bucket's representative, nothing scattered or gathered per row; "
+    "a batch of more takes further passes); read from the same device "
+    "value as the batch's clean check, never a sync of its own")
 SEG_AGG_TIME = register_metric(
     "segAggTime", TIMER, MODERATE,
     "segmented-aggregation kernel time inside grouped-aggregate "

@@ -2,6 +2,7 @@
 import pytest
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
 from spark_rapids_tpu.plan.logical import col, functions as f, lit
 
 from compare import assert_tpu_and_cpu_are_equal, run_both, assert_rows_equal
@@ -403,3 +404,307 @@ def test_cube_grouping_sets():
     assert (None, "x", 40) in rows   # b-only set: a rolled up
     assert (1, None, 30) in rows     # a-only set
     assert (None, None, 100) in rows
+
+
+# ---- the bucket update: dense passes over the occupied buckets -------------
+
+def _bucket_of(values, dtype):
+    """Bucket of each key value, as _bucket_update_kernel hashes it."""
+    import numpy as np
+    from spark_rapids_tpu.columnar import ColumnarBatch
+    from spark_rapids_tpu.ops.hashing import hash_columns_double
+    b = ColumnarBatch.from_pydict(
+        {"k": list(values)}, T.Schema([T.StructField("k", dtype)]))
+    h1, _ = hash_columns_double([b.column(0)], b.sel)
+    return [int(x) & (TpuHashAggregateExec._BUCKETS - 1)
+            for x in np.asarray(h1)[:len(values)]]
+
+
+def _int_keys_in_distinct_buckets(n):
+    keys, seen = [], set()
+    for v, bk in zip(range(4096), _bucket_of(range(4096), T.LongType)):
+        if bk not in seen:
+            seen.add(bk)
+            keys.append(v)
+        if len(keys) == n:
+            return keys
+    raise AssertionError("not enough buckets")
+
+
+def _two_int_keys_in_one_bucket():
+    first = {}
+    for v, bk in zip(range(4096), _bucket_of(range(4096), T.LongType)):
+        if bk in first:
+            return first[bk], v
+        first[bk] = v
+    raise AssertionError("no collision")
+
+
+_NAN = float("nan")
+_G = TpuHashAggregateExec._DENSE_GROUPS
+_BATCH = 64          # rows a reader batch in these cases
+
+
+def _numeric_aggs(df, tag=""):
+    """Count, Sum, Average, Min, Max over a long and a double column."""
+    return df.agg(f.count(lit(1)).alias("c" + tag),
+                  f.count(col("d")).alias("cd"),
+                  f.sum(col("v")).alias("sv"),
+                  f.sum(col("d")).alias("sd"),
+                  f.avg(col("d")).alias("ad"),
+                  f.min(col("v")).alias("mnv"),
+                  f.max(col("v")).alias("mxv"),
+                  f.min(col("d")).alias("mnd"),
+                  f.max(col("d")).alias("mxd"))
+
+
+def _rows_for(keys_per_batch, key_dtype=T.LongType):
+    """One batch of _BATCH rows per key list, keys cycling; v and d carry
+    nulls, NaN, +-0.0 and both signs."""
+    import random
+    rng = random.Random(7)
+    k, v, d = [], [], []
+    for keys in keys_per_batch:
+        for i in range(_BATCH):
+            k.append(keys[i % len(keys)])
+            v.append(None if i % 11 == 3 else rng.randint(-10**6, 10**6))
+            d.append(rng.choice([None, _NAN, 0.0, -0.0])
+                     if i % 7 == 2 else rng.uniform(-1e3, 1e3))
+    schema = T.Schema([T.StructField("k", key_dtype),
+                       T.StructField("v", T.LongType),
+                       T.StructField("d", T.DoubleType)])
+    return {"k": k, "v": v, "d": d}, schema
+
+
+def _case_one_group():
+    data, schema = _rows_for([[5]] * 3)
+    return dict(data=data, schema=schema, dense=[1, 1, 1],
+                q=lambda df: _numeric_aggs(df.group_by("k")))
+
+
+def _case_exactly_g_groups():
+    keys = _int_keys_in_distinct_buckets(_G)
+    data, schema = _rows_for([keys, keys])
+    return dict(data=data, schema=schema, dense=[1, 1],
+                q=lambda df: _numeric_aggs(df.group_by("k")))
+
+
+def _case_g_plus_one_groups():
+    """Batch 1 holds G + 1 groups and takes a second pass, batch 2 holds
+    G."""
+    keys = _int_keys_in_distinct_buckets(_G + 1)
+    data, schema = _rows_for([keys, keys[:-1]])
+    return dict(data=data, schema=schema, dense=[0, 1],
+                q=lambda df: _numeric_aggs(df.group_by("k")))
+
+
+def _case_two_full_passes():
+    """Batch 1 holds 2 G groups, a row each: two full passes and no third;
+    batch 2 one pass and a part of the next."""
+    keys = _int_keys_in_distinct_buckets(2 * _G)
+    assert len(keys) == _BATCH
+    data, schema = _rows_for([keys, keys[:_G + 5]])
+    return dict(data=data, schema=schema, dense=[0, 0],
+                q=lambda df: _numeric_aggs(df.group_by("k")))
+
+
+def _case_two_keys_in_one_bucket():
+    """Dirty: the bucket program's answer is dropped, the sort program
+    answers and the kernel key is latched (own aliases: a key of its own)."""
+    a, b = _two_int_keys_in_one_bucket()
+    data, schema = _rows_for([[a, b], [a]])
+    return dict(data=data, schema=schema, dense=[-1, 1], dirty=True,
+                q=lambda df: _numeric_aggs(df.group_by("k"), tag="_dirty"))
+
+
+def _case_null_nan_negzero_keys():
+    keys = [None, _NAN, 0.0, -0.0, 1.5, -1.5]
+    data, schema = _rows_for([keys, keys], key_dtype=T.DoubleType)
+    # 0.0 and -0.0 are one group: 5 groups
+    return dict(data=data, schema=schema, dense=[1, 1],
+                q=lambda df: _numeric_aggs(df.group_by("k")))
+
+
+def _case_string_keys_unequal_length():
+    """Keys of one width bucket whose padded bytes agree and only the
+    length differs ("a" / "a\\0" / "a\\0\\0"), the empty string and null;
+    a second key column makes it a two-key probe."""
+    keys = ["a", "a\x00", "a\x00\x00", "", None, "ab", "abcdefg", "b"]
+    data, schema = _rows_for([keys, keys[:3]], key_dtype=T.StringType)
+    data["k2"] = [i % 2 for i in range(len(data["k"]))]
+    schema = T.Schema(list(schema) + [T.StructField("k2", T.IntegerType)])
+    return dict(data=data, schema=schema, dense=[1, 1],
+                q=lambda df: _numeric_aggs(df.group_by("k", "k2")))
+
+
+def _case_string_keys_same_bytes_one_bucket():
+    """Two keys of one bucket whose padded bytes agree and whose lengths
+    differ: only the length compare tells them apart, and it must (dirty)."""
+    import itertools
+    import string
+    stems = ["".join(p) for p in itertools.product(
+        string.ascii_letters + string.digits, repeat=2)]
+    longer = [x + "\x00" for x in stems]
+    hits = [(x, y) for x, y, bx, by in zip(
+        stems, longer, _bucket_of(stems, T.StringType),
+        _bucket_of(longer, T.StringType)) if bx == by]
+    assert hits, "no stem shares a bucket with its zero-padded twin"
+    data, schema = _rows_for([list(hits[0]), ["zz"]],
+                             key_dtype=T.StringType)
+    return dict(data=data, schema=schema, dense=[-1, 1], dirty=True,
+                q=lambda df: _numeric_aggs(df.group_by("k"), tag="_len"))
+
+
+def _case_all_dead_batch():
+    """The filter leaves batch 2 without a live row."""
+    data, schema = _rows_for([[1, 2, 3], [4], [1, 2]])
+    data["keep"] = [not (_BATCH <= i < 2 * _BATCH)
+                    for i in range(3 * _BATCH)]
+    schema = T.Schema(list(schema) + [T.StructField("keep", T.BooleanType)])
+    return dict(data=data, schema=schema, dense=[1, 1, 1],
+                q=lambda df: _numeric_aggs(
+                    df.filter(col("keep")).group_by("k")))
+
+
+def _case_all_nan_and_no_valid_groups():
+    """Group 1 all NaN, group 2 no valid value, group 3 NaN among numbers
+    and both zeros, group 4 plain."""
+    k, v, d = [], [], []
+    for i in range(2 * _BATCH):
+        g = i % 4 + 1
+        k.append(g)
+        v.append(None if g == 2 else i - 50)
+        d.append({1: _NAN, 2: None,
+                  3: [_NAN, 0.0, -0.0, 2.5, -7.0][i // 4 % 5],
+                  4: float(i) - 31.5}[g])
+    schema = T.Schema([T.StructField("k", T.LongType),
+                       T.StructField("v", T.LongType),
+                       T.StructField("d", T.DoubleType)])
+    return dict(data={"k": k, "v": v, "d": d}, schema=schema,
+                dense=[1, 1],
+                q=lambda df: _numeric_aggs(df.group_by("k")))
+
+
+_BUCKET_CASES = {
+    "one_group": _case_one_group,
+    "exactly_G_groups": _case_exactly_g_groups,
+    "G_plus_one_groups": _case_g_plus_one_groups,
+    "two_full_passes": _case_two_full_passes,
+    "two_keys_in_one_bucket": _case_two_keys_in_one_bucket,
+    "null_nan_negzero_keys": _case_null_nan_negzero_keys,
+    "string_keys_unequal_length": _case_string_keys_unequal_length,
+    "string_keys_same_bytes_one_bucket":
+        _case_string_keys_same_bytes_one_bucket,
+    "all_dead_batch": _case_all_dead_batch,
+    "all_nan_and_no_valid_groups": _case_all_nan_and_no_valid_groups,
+}
+
+
+def _find_agg(node):
+    if isinstance(node, TpuHashAggregateExec):
+        return node
+    for c in node.children:
+        r = _find_agg(c)
+        if r is not None:
+            return r
+
+
+@pytest.mark.parametrize("path", ["whole_stage", "streaming", "kernel"])
+@pytest.mark.parametrize("case", list(_BUCKET_CASES))
+def test_bucket_update_dense_passes(case, path):
+    """_bucket_update_kernel in one pass and in more against the sort
+    path's _update_kernel on the same batches (`kernel`) and against the
+    CPU executors (ops/cpu_eval.py) through the whole-stage program and
+    the streaming loop; `took` / aggDenseBatches count the batches of one
+    pass."""
+    import jax
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.exec import aggregate as A
+    from spark_rapids_tpu.exec.base import ExecContext
+    c = _BUCKET_CASES[case]()
+    conf = {**FLOAT_AGG,
+            "spark.rapids.sql.reader.batchSizeRows": str(_BATCH)}
+    if path == "streaming":
+        conf["spark.rapids.sql.tpu.wholeStage.enabled"] = "false"
+    s = TpuSession(conf)
+    query = c["q"](s.from_pydict(c["data"], c["schema"]))
+    dirty_before = set(A._BUCKET_DIRTY_KEYS)
+    try:
+        if path == "kernel":
+            agg = _find_agg(s.plan(query.plan))
+            batches = list(agg.children[0].execute(
+                ExecContext(s.conf, runtime=s.runtime)))
+            assert len(batches) == len(c["dense"])
+            bucket = jax.jit(agg._bucket_update_kernel)
+            result = jax.jit(lambda st: agg._finalize_kernel(
+                agg._merge_kernel(st)))
+            for b, want in zip(batches, c["dense"]):
+                took, bstate = bucket(b)
+                assert int(took) == want
+                assert bstate.capacity == agg._BUCKETS
+                if want >= 0:
+                    assert_rows_equal(
+                        result(agg._update_kernel(b)).to_pylist(),
+                        result(bstate).to_pylist())
+            return
+        tpu = query.collect()
+        cpu = c["q"](TpuSession(
+            {**conf, "spark.rapids.sql.enabled": "false"}).from_pydict(
+                c["data"], c["schema"])).collect()
+        assert_rows_equal(cpu, tpu)
+        counted = s.query_metrics_total.get("aggDenseBatches", 0)
+        latched = A._BUCKET_DIRTY_KEYS - dirty_before
+        if c.get("dirty"):
+            # the whole-stage program drops every batch's bucket state;
+            # the loop stops probing at the first dirty batch
+            assert counted == 0 and len(latched) == 1
+        else:
+            assert counted == sum(c["dense"]) and not latched
+    finally:
+        A._BUCKET_DIRTY_KEYS.intersection_update(dirty_before)
+
+
+def _per_row_indexed(jaxpr, cap):
+    """Ops of this jaxpr that scatter, gather or dynamic-slice with an
+    index operand of at least `cap` elements; a count, not a timing.
+    Nested jits and the bodies of loops and conds are walked into."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name.startswith(("scatter", "gather", "dynamic_slice",
+                            "dynamic_update_slice")) \
+                and any(v.aval.size >= cap for v in eqn.invars[1:]
+                        if v.aval.dtype.kind in "iu"):
+            found.append(name)
+        for p in eqn.params.values():
+            for inner in (p if isinstance(p, (tuple, list)) else (p,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found += _per_row_indexed(inner, cap)
+    return found
+
+
+def test_bucket_update_indexes_nothing_per_row():
+    """Q1's aggregate at cap 4,096: the bucket update holds no scatter,
+    gather or dynamic_slice over cap indices, in its loop or outside it;
+    the sort path's update of the same batch holds them (so the walker
+    sees what it is meant to see)."""
+    import jax
+    from benchmarks.tpch import bulk
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.exec.base import ExecContext
+    cap = 4096
+    s = TpuSession({**FLOAT_AGG,
+                    "spark.rapids.sql.reader.batchSizeRows": str(cap)})
+    q1 = bulk.q1(s.from_arrow(bulk.make_lineitem(cap, seed=3)))
+    agg = _find_agg(s.plan(q1.plan))
+    [batch] = agg.children[0].execute(ExecContext(s.conf,
+                                                  runtime=s.runtime))
+    assert batch.capacity == cap
+    bucket = jax.make_jaxpr(agg._bucket_update_kernel)(batch).jaxpr
+    assert any(e.primitive.name == "while" for e in bucket.eqns)
+    assert not _per_row_indexed(bucket, cap)
+    sort = jax.make_jaxpr(agg._update_kernel)(batch).jaxpr
+    per_row = _per_row_indexed(sort, cap)
+    assert "gather" in per_row and \
+        any(n.startswith("scatter") for n in per_row), per_row

@@ -116,10 +116,21 @@ def _recording_jit(records):
     return lambda fun, **kw: Recorder(fun, **kw)
 
 
+def _grouped_minmax(lineitem):
+    """Few groups, min and max over doubles and a date: the bucket
+    update's dense min/max reducers over f32 pairs, which Q1 lacks."""
+    from spark_rapids_tpu.plan.logical import col, functions as F
+    return lineitem.group_by(col("l_returnflag")).agg(
+        F.min(col("l_extendedprice")).alias("min_price"),
+        F.max(col("l_discount")).alias("max_disc"),
+        F.min(col("l_shipdate")).alias("first_ship"))
+
+
 @pytest.fixture(scope="module")
 def smoke_programs(topo):
-    """{query: [programs]} recorded from chip_smoke.py's q6 and join query
-    at SF1 widths, run on the CPU with the engine on its TPU branches."""
+    """{query: [programs]} recorded from chip_smoke.py's q6, q1 and join
+    query (q1 over five batches too, and one grouped min/max) at SF1 widths,
+    run on the CPU with the engine on its TPU branches."""
     import chip_smoke
     from benchmarks.tpch import bulk
     from spark_rapids_tpu.columnar.contiguous import pack_batch
@@ -148,7 +159,12 @@ def smoke_programs(topo):
                                       n_orders=SMOKE_ROWS // 4)
         orders = bulk.make_orders(SMOKE_ROWS // 4, seed=22)
         li, od = session.from_arrow(lineitem), session.from_arrow(orders)
+        li2 = session.from_arrow(lineitem.slice(0, 2 << 20))  # two batches
+        li5 = session.from_arrow(lineitem.slice(0, 5 << 20))
         for name, query in (("q6", lambda: bulk.q6(li)),
+                            ("q1", lambda: bulk.q1(li)),
+                            ("q1x5", lambda: bulk.q1(li5)),
+                            ("minmax", lambda: _grouped_minmax(li2)),
                             ("q3_join", lambda: bulk.q3_shape(li, od))):
             records[name] = []
             mp.setattr(jax, "jit", _recording_jit(records[name]))
@@ -193,6 +209,14 @@ def _largest(programs, name_part):
 @pytest.mark.parametrize("query,kernel,min_rows", [
     # scan -> filter -> aggregate over all six 1M-row batches, one program
     ("q6", "agg.whole_stage", 1 << 20),
+    # the grouped aggregate over the same batches: per batch the bucket
+    # update's `while` of dense passes, f64 sums and int64 counts in its
+    # carry (two string keys; as a `cond` over a dense and a scatter form
+    # XLA:TPU's memory-space assignment segfaulted at 5 and 6 batches)
+    ("q1", "agg.whole_stage_bucket", 1 << 20),
+    ("q1x5", "agg.whole_stage_bucket", 1 << 20),
+    # the same over two batches with min and max of doubles and a date
+    ("minmax", "agg.whole_stage_bucket", 1 << 20),
     # the join's fused window+count kernel over a full probe batch
     ("q3_join", "join.hashjoin_probe", 1 << 20),
     # the 64-bit sort: packed u64 keys, revenue (a double: the f32-pair

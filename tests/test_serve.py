@@ -31,6 +31,7 @@ import pyarrow as pa
 import pytest
 
 from spark_rapids_tpu.engine import TpuSession
+from spark_rapids_tpu.exec import aggregate as AGG
 from spark_rapids_tpu.plan.logical import col, functions as F, lit
 from spark_rapids_tpu.serve.plan_cache import (PlanCache, extract_parameters,
                                                plan_cache_key)
@@ -162,6 +163,11 @@ def test_submit_matches_collect_across_variants():
         variants = [(10.0, 40, 2.0), (55.0, 20, 7.0)]
         for i, (cut, k, scale) in enumerate(variants):
             expected = _q_agg(df, cut, k, scale).to_arrow()
+            # like with like: collect()'s kernel key carries its literals
+            # and probes the bucket update afresh, so submit()'s shared
+            # key does too (the 40-group variant latches it dirty: 20 and
+            # 32 share a bucket)
+            AGG._BUCKET_DIRTY_KEYS.clear()
             fut = s.submit(_q_agg(df, cut, k, scale))
             assert fut.result(300).equals(expected)
             assert fut.plan_cache == ("miss" if i == 0 else "hit")
@@ -190,9 +196,38 @@ def test_variant_resubmission_compiles_nothing_new():
         # and the warm path actually ran through the caches
         assert after["kernel_hits"] + after["stage_hits"] > \
             before["kernel_hits"] + before["stage_hits"]
-        # sanity: the warm results are still right
-        assert r1.equals(_q_agg(df, 66.0, 11, 5.5).to_arrow())
+        # sanity: the warm results are still right.  r1 took the sort
+        # program (the 40-group submission latched the shape's key dirty):
+        # collect() on that path is its like
+        sort_side = _session({"spark.rapids.sql.tpu.agg.bucketGroups":
+                              "false"})
+        assert r1.equals(_q_agg(sort_side.from_arrow(_TABLE),
+                                66.0, 11, 5.5).to_arrow())
         assert r2.equals(_q_rowlocal(df, 30.0, 31.5).to_arrow())
+    finally:
+        s.shutdown_serving()
+
+
+def test_latched_key_answers_differ_from_collect_in_float_order_only():
+    """The cross-path drift, by name: once a 40-group variant has latched
+    the shape's kernel key dirty, submit() answers a 20-group variant
+    through the sort program (a group's doubles summed in row order) while
+    collect(), whose key carries its literals, takes the bucket update's
+    dense form (summed as a tree).  Keys, counts and order are exact; the
+    sums agree to 1e-12 (variableFloatAgg, on in `_session`)."""
+    s = _session()
+    try:
+        df = s.from_arrow(_TABLE)
+        s.submit(_q_agg(df, 10.0, 40, 2.0)).result(300)
+        dense0 = s.query_metrics_total.get("aggDenseBatches", 0)
+        got = s.submit(_q_agg(df, 55.0, 20, 7.0)).result(300)
+        assert s.query_metrics_total.get("aggDenseBatches", 0) == dense0
+        want = _q_agg(df, 55.0, 20, 7.0).to_arrow()
+        assert s.query_metrics_total.get("aggDenseBatches", 0) > dense0
+        assert got.schema == want.schema
+        assert got["b"].equals(want["b"]) and got["n"].equals(want["n"])
+        np.testing.assert_allclose(got["sx"].to_numpy(),
+                                   want["sx"].to_numpy(), rtol=1e-12, atol=0)
     finally:
         s.shutdown_serving()
 
