@@ -1079,9 +1079,15 @@ class TpuHashAggregateExec(TpuExec):
                 cap = b.capacity
                 shape0 = shapes
             batches.append(b)
-            if shapes != shape0 \
-                    or b.schema.names != batches[0].schema.names \
-                    or total_bytes > byte_budget:
+            reason = ("shapes" if shapes != shape0 else
+                      "names" if b.schema.names != batches[0].schema.names
+                      else "bytes" if total_bytes > byte_budget else None)
+            if reason is not None:
+                # a zero-length mark: why this input takes the streaming
+                # loop, and after how many batches the probe knew
+                with named_range("agg_whole_stage_bail", reason=reason,
+                                 batches=len(batches)):
+                    pass
                 return None, (source, batches, src_iter)
         if not batches:
             return None, (source, batches, src_iter)
@@ -1207,6 +1213,7 @@ class TpuHashAggregateExec(TpuExec):
                 n_dense, out = (fnb(pvals, *all_leaves) if pre_params
                                 else fnb(*all_leaves))
             n_dense = int(n_dense)  # host sync: dirty, or one-pass batches
+            self.metrics.add(MN.AGG_HOST_SYNCS, 1)
             if n_dense >= 0:
                 self.metrics.add(MN.AGG_DENSE_BATCHES, n_dense)
                 self.metrics.add(MN.NUM_FUSED_STAGES, 1)
@@ -1228,6 +1235,13 @@ class TpuHashAggregateExec(TpuExec):
         self.metrics.add(MN.NUM_FUSED_STAGES, 1)
         record_output_batch(self.metrics, out, ctx.runtime)
         return out, None
+
+    def _live_rows_host(self, batch) -> int:
+        """`batch.num_rows_host()`, counted in aggHostSyncs where it has
+        to read the device for it."""
+        if batch.known_rows is None:
+            self.metrics.add(MN.AGG_HOST_SYNCS, 1)
+        return batch.num_rows_host()
 
     def _cpu_twin(self):
         """CPU re-execution plan for OOM fallback (exec/retryable.py):
@@ -1295,6 +1309,8 @@ class TpuHashAggregateExec(TpuExec):
                             * self._cost_weight())
                 with self.metrics.timer(MN.CONCAT_TIME):
                     both = concat_batches(parts)
+                # concat_batches reads each compacted part's row count
+                self.metrics.add(MN.AGG_HOST_SYNCS, len(parts))
                 with self.metrics.timer(MN.SEG_AGG_TIME), \
                         named_range("agg_merge", self.metrics,
                                     MN.MERGE_AGG_TIME):
@@ -1365,6 +1381,7 @@ class TpuHashAggregateExec(TpuExec):
                 if bfn is not None:
                     took, bstate = bfn(b)
                     took = int(took)  # host sync: pick the sort-free state
+                    self.metrics.add(MN.AGG_HOST_SYNCS, 1)
                     if took >= 0:
                         self.metrics.add(MN.AGG_DENSE_BATCHES, took)
                         partial = bstate
@@ -1378,7 +1395,7 @@ class TpuHashAggregateExec(TpuExec):
                     partial = update(b, jnp.int64(hot["offset"])) \
                         if needs_off else update(b)
             if needs_off:
-                hot["offset"] += b.num_rows_host()
+                hot["offset"] += self._live_rows_host(b)
             return partial
 
         from ..serve.lifecycle import ctx_checkpoint
@@ -1393,7 +1410,9 @@ class TpuHashAggregateExec(TpuExec):
             # (capacity check is static: dense small batches skip the
             # num_rows_host device sync entirely)
             if batch.capacity >= 8192:
-                batch = batch.maybe_shrink(batch.num_rows_host())
+                with named_range("agg_shrink"):
+                    batch = batch.maybe_shrink(self._live_rows_host(batch))
+            self.metrics.add(MN.AGG_STREAMED_BATCHES, 1)
             with named_range("agg_update", self.metrics, MN.COMPUTE_AGG_TIME):
                 partials = run_retryable(ctx, self.metrics, "aggUpdate",
                                          attempt_update, [batch],

@@ -362,6 +362,17 @@ def monitoring_doc() -> str:
         "row-group chunk, ORC per column, CSV per chunk), on the prefetch "
         "thread when prefetch is on | footer and page parsing, "
         "decompression, H2D enqueue, decode dispatches (timer `scanTime`)",
+        "`srt:agg_shrink` | `exec/aggregate.py`, the streaming loop, one "
+        "a batch of capacity 8,192 or more, before its `srt:agg_update` | "
+        "the read of the batch's live-row count (the wait for the filter "
+        "program; counted in `aggHostSyncs`) and `maybe_shrink`'s eager "
+        "compaction",
+        "`srt:agg_whole_stage_bail` | `exec/aggregate.py "
+        "_try_whole_stage`, zero-length | why the input takes the "
+        "streaming loop (`reason=bytes`: past half of "
+        "`spark.rapids.sql.batchSizeBytes`; `shapes`; `names`) and after "
+        "how many `batches` the probe knew; `aggStreamedBatches` counts "
+        "the batches the loop then takes",
         "`srt:<operator>` | `exec/` | `srt:agg_whole_stage`, "
         "`srt:agg_whole_stage_bucket`, `srt:agg_update`, `srt:agg_merge`, "
         "`srt:whole_stage_<id>`, `srt:join_build`, `srt:join_stream`, "

@@ -286,6 +286,18 @@ AGG_DENSE_BATCHES = register_metric(
     "each bucket's representative, nothing scattered or gathered per row; "
     "a batch of more takes further passes); read from the same device "
     "value as the batch's clean check, never a sync of its own")
+AGG_STREAMED_BATCHES = register_metric(
+    "aggStreamedBatches", COUNTER, ESSENTIAL,
+    "input batches that went through the aggregate's per-batch update "
+    "(the streaming loop an input past half of batchSizeBytes, or of "
+    "unequal batch shapes, takes); 0 where the whole-stage program "
+    "answered; a host integer, never a sync")
+AGG_HOST_SYNCS = register_metric(
+    "aggHostSyncs", COUNTER, ESSENTIAL,
+    "host reads of a device value the aggregate made: a batch's live-row "
+    "count before the shrink, the bucket update's clean check, the row "
+    "count concat_batches reads per part on a fold; a host integer "
+    "counted where the read is made")
 SEG_AGG_TIME = register_metric(
     "segAggTime", TIMER, MODERATE,
     "segmented-aggregation kernel time inside grouped-aggregate "

@@ -156,6 +156,37 @@ def test_a_parquet_query_adds_the_scans_span_and_timer(tmp_path):
     assert 0 < agg[MN.SCAN_TIME] <= sum(e[1] - e[0] for e in decodes) / 1e9
 
 
+def test_a_streamed_aggregate_marks_its_bail_and_spans_each_shrink(tmp_path):
+    """An input past half of batchSizeBytes: the whole-stage probe leaves a
+    zero-length `srt:agg_whole_stage_bail` that says why, and each batch of
+    the streaming loop is shrunk under `srt:agg_shrink`, before and outside
+    its `srt:agg_update`."""
+    session = TpuSession({
+        "spark.rapids.sql.variableFloatAgg.enabled": "true",
+        "spark.rapids.sql.reader.batchSizeRows": "65536",
+        "spark.rapids.sql.batchSizeBytes": "12m"})
+    collects, names = phases(traced(q6(session.from_arrow(lineitem())),
+                                    tmp_path, queries=2))
+    assert "PjitFunction(agg.whole_stage)" not in names
+    batches = -(-ROWS // 65536)
+    for collect, spans in collects:
+        by = {}
+        for e in spans:
+            by.setdefault(e[2], []).append(e)
+        [execute] = by["srt:execute"]
+        [bail] = by["srt:agg_whole_stage_bail"]
+        assert bail[3]["reason"] == "bytes" and int(bail[3]["batches"]) == 3
+        shrinks, updates = by["srt:agg_shrink"], by["srt:agg_update"]
+        assert len(shrinks) == len(updates) == batches
+        assert all(inside(e, execute) for e in [bail] + shrinks + updates)
+        assert bail[1] <= shrinks[0][0]
+        assert all(s[1] <= u[0] for s, u in zip(shrinks, updates))
+    moved = session.last_execution.aggregate()
+    assert moved[MN.AGG_STREAMED_BATCHES] == batches
+    # a live-row read a batch, and the last fold's count per part
+    assert moved[MN.AGG_HOST_SYNCS] == 2 * batches
+
+
 def test_named_range_is_a_span_and_a_timer_and_never_a_sync():
     from spark_rapids_tpu.metrics.registry import DEVICE_SYNCS, Metrics
     m = Metrics()
