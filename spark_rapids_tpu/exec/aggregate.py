@@ -60,6 +60,42 @@ def _flatten_stacked(partials: ColumnarBatch, state_schema) -> ColumnarBatch:
     return ColumnarBatch(cols, partials.sel.reshape(-1), state_schema)
 
 
+def _stack_states(states: Sequence[ColumnarBatch]) -> ColumnarBatch:
+    """Equal-capacity states -> the [k, pcap, ...] form _flatten_stacked
+    takes; string columns are padded to the widest first (a string state
+    is as wide as the batch it was taken from)."""
+    widths = [max(c.max_len for c in cols) if cols[0].dtype.is_string
+              else None for cols in zip(*(s.columns for s in states))]
+    padded = [ColumnarBatch([c if w is None else c.pad_strings_to(w)
+                             for c, w in zip(s.columns, widths)],
+                            s.sel, s.schema) for s in states]
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
+
+
+def _head_rows(batch: ColumnarBatch, cap: int) -> ColumnarBatch:
+    """The first `cap` rows of a batch whose live rows are in front (a
+    merged state's are: `sel = iota < ngroups`)."""
+    cols = [Column(c.data[:cap], c.valid[:cap], c.dtype,
+                   c.lengths[:cap] if c.lengths is not None else None)
+            for c in batch.columns]
+    return ColumnarBatch(cols, batch.sel[:cap], batch.schema)
+
+
+def _thread_params(fn, params):
+    """Parameter-threaded twin of an absorbing program: the bound values
+    of the absorbed chain's plan-cache parameters lead the arguments and
+    install as the active binding while the program traces (see
+    exec/basic.bound_param_builder)."""
+    if not params:
+        return fn
+    slots = [p.slot for p in params]
+
+    def fn_p(pv, *args):
+        with E.bound_params(dict(zip(slots, pv))):
+            return fn(*args)
+    return fn_p
+
+
 def _type_max(dt):
     """Identity element for Min over dtype dt (largest value)."""
     j = dt.jnp_dtype
@@ -1006,6 +1042,37 @@ class TpuHashAggregateExec(TpuExec):
                 tuple(a.output_name for a in self.aggregates),
                 schema_key(self._schema))
 
+    def _absorbed_child(self):
+        """(source, pre_builder, pre_params, pre_key): what a program of
+        this aggregate that absorbs its row-local child runs before the
+        update and over which node's batches; a child that is not
+        row-local is itself the source and nothing is absorbed.  None
+        where the child cannot be absorbed."""
+        from .basic import RowLocalExec
+        child = self.children[0]
+        if not isinstance(child, RowLocalExec):
+            return child, None, [], ()
+        if child._needs_row_offset() or child._needs_input_file():
+            # the fused stage threads a per-batch row offset
+            # (monotonically_increasing_id / rand); absorbing it with
+            # offset 0 would silently repeat per-batch streams.
+            # input_file_name() likewise bakes a per-FILE constant that
+            # one program cannot vary across batches
+            return None
+        pre_params = child.stage_params()
+        if pre_params:
+            # plan-cache parameters in the absorbed chain: value-free
+            # pre-key + the bound values as a leading traced argument
+            # of the absorbing program, so literal-variant
+            # re-submissions replay this compiled program
+            from ..utils.kernel_cache import param_free_keys
+            with param_free_keys():
+                pre_key = child.kernel_key()
+            pre_key += ("params", E.parameter_signature(pre_params))
+        else:
+            pre_key = child.kernel_key()
+        return child.children[0], child.batch_fn, pre_params, pre_key
+
     # ---- whole-stage path --------------------------------------------------
 
     def _try_whole_stage(self, ctx: ExecContext):
@@ -1020,7 +1087,6 @@ class TpuHashAggregateExec(TpuExec):
         qualify (caller falls back to the streaming loop)."""
         from .. import config as C
         from ..utils.kernel_cache import cached_kernel
-        from .basic import RowLocalExec
         # FUSION_ENABLED is the master whole-stage kill switch (plan/
         # fusion.py); WHOLE_STAGE_ENABLED remains the aggregate-specific
         # knob for this absorption path
@@ -1028,34 +1094,10 @@ class TpuHashAggregateExec(TpuExec):
                 or not ctx.conf.get(C.FUSION_ENABLED) \
                 or self._needs_offset():
             return None, None
-        child = self.children[0]
-        if isinstance(child, RowLocalExec):
-            if child._needs_row_offset() or child._needs_input_file():
-                # the fused stage threads a per-batch row offset
-                # (monotonically_increasing_id / rand); vmapping it with
-                # offset 0 would silently repeat per-batch streams.
-                # input_file_name() likewise bakes a per-FILE constant that
-                # one vmapped program cannot vary across batches
-                return None, None
-            pre_builder = child.batch_fn
-            pre_params = child.stage_params()
-            if pre_params:
-                # plan-cache parameters in the absorbed chain: value-free
-                # pre-key + the bound values as a leading traced argument
-                # of the whole-stage program, so literal-variant
-                # re-submissions replay this compiled program
-                from ..utils.kernel_cache import param_free_keys
-                with param_free_keys():
-                    pre_key = child.kernel_key()
-                pre_key += ("params", E.parameter_signature(pre_params))
-            else:
-                pre_key = child.kernel_key()
-            source = child.children[0]
-        else:
-            pre_builder = None
-            pre_params = []
-            pre_key = ()
-            source = child
+        absorbed = self._absorbed_child()
+        if absorbed is None:
+            return None, None
+        source, pre_builder, pre_params, pre_key = absorbed
         # drain INCREMENTALLY: eligibility (leaf shapes, byte budget) is
         # checked per batch so an over-budget input bails to the streaming
         # loop with the tail still unconsumed — the probe must never pin a
@@ -1117,20 +1159,7 @@ class TpuHashAggregateExec(TpuExec):
             return jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *partial_list)
 
-        param_slots = [p.slot for p in pre_params]
         pvals = E.parameter_values(pre_params) if pre_params else None
-
-        def _with_params(whole):
-            """Parameter-threaded twin: the bound values lead the leaf
-            arguments and install as the active binding while the program
-            traces (see exec/basic.bound_param_builder)."""
-            if not pre_params:
-                return whole
-
-            def whole_p(pv, *leaves):
-                with E.bound_params(dict(zip(param_slots, pv))):
-                    return whole(*leaves)
-            return whole_p
 
         def build():
             def whole(*leaves):
@@ -1143,7 +1172,7 @@ class TpuHashAggregateExec(TpuExec):
                 partials = _unrolled(leaves, one)   # leaves [k, pcap, ...]
                 both = _flatten_stacked(partials, state_schema)
                 return finalize(merge(both))
-            return _with_params(whole)
+            return _thread_params(whole, pre_params)
 
         def build_bucket():
             bupdate = self._bucket_update_kernel
@@ -1161,7 +1190,7 @@ class TpuHashAggregateExec(TpuExec):
                 # in one dense pass, or -1 if any was dirty
                 n_dense = jnp.where(jnp.all(took >= 0), jnp.sum(took), -1)
                 return n_dense, finalize(merge(both))
-            return _with_params(whole_bucket)
+            return _thread_params(whole_bucket, pre_params)
 
         # treedef in the key: the per-batch structure is baked into the
         # compiled closure (tree_unflatten over bare leaves), so two
@@ -1265,9 +1294,10 @@ class TpuHashAggregateExec(TpuExec):
         if whole is not None:
             yield whole
             return
-        grouped = bool(self.grouping)
-        base_update = (self._update_kernel if grouped
-                       else self._global_kernel)
+        if not self.grouping:
+            yield self._stream_keyless(ctx, materialized)
+            return
+        base_update = self._update_kernel
         needs_off = self._needs_offset()
         key = self.kernel_key()
         if needs_off:
@@ -1405,10 +1435,12 @@ class TpuHashAggregateExec(TpuExec):
             # partial states are spillable like any owned buffers, so a
             # preemption suspend here parks and resumes bit-for-bit
             ctx_checkpoint(ctx, allow_suspend=True)
-            # the update kernel sorts at batch CAPACITY: a selective
-            # upstream filter leaves mostly-dead batches, so shrink first
-            # (capacity check is static: dense small batches skip the
-            # num_rows_host device sync entirely)
+            # the GROUPED update kernel sorts at batch CAPACITY: a
+            # selective upstream filter leaves mostly-dead batches, so
+            # shrink first (capacity check is static: dense small batches
+            # skip the num_rows_host device sync entirely).  A keyless
+            # aggregate never comes here: its update is masked reductions,
+            # linear in capacity (_stream_keyless)
             if batch.capacity >= 8192:
                 with named_range("agg_shrink"):
                     batch = batch.maybe_shrink(self._live_rows_host(batch))
@@ -1424,17 +1456,131 @@ class TpuHashAggregateExec(TpuExec):
         if pending:
             state = fold(state, pending)
         if state is None:
-            if grouped:
-                return
-            # global agg over empty input still yields one row: run the
-            # kernel on an all-dead batch of the child schema
-            child_schema = self.children[0].schema
-            data = {f.name: [] for f in child_schema}
-            dead = ColumnarBatch.from_pydict(data, child_schema)
-            state = update(dead, jnp.int64(0)) if needs_off else update(dead)
+            return
         out = finalize(state)
         record_output_batch(self.metrics, out, ctx.runtime)
         yield out
+
+    def _stream_keyless(self, ctx: ExecContext, materialized):
+        """The streaming loop of an aggregate with no grouping keys: ONE
+        program per input batch, `carry' = merge(carry, update(pre(batch)))`
+        over a 1-row running state that stays on the device, and no host
+        read before the output is collected.  `_global_kernel` is masked
+        reductions, linear in capacity, so nothing is gained by shrinking
+        a mostly-dead batch first, and the states are 1 row each, so
+        nothing by folding them `mergeFanIn` at a time: the grouped loop's
+        live-row read, shrink and concat cost this shape 250 times its
+        device work (PERF.md, PR 28).
+
+        The carry is `(state,)`, or `(state, row offset)` where First/Last
+        or a row-offset expression needs the live rows before a batch:
+        the offset advances on the device by each batch's live rows."""
+        import itertools
+        from ..mem.retry import split_batch_rows
+        from ..serve.lifecycle import ctx_checkpoint
+        from ..utils.kernel_cache import cached_kernel, record_dispatch
+        from .retryable import run_retryable
+        needs_off = self._needs_offset()
+        if materialized is not None:
+            # the whole-stage probe drained (part of) the source and bailed:
+            # the step absorbs the row-local child as that program would
+            # have, so filter, projection and reduction are one launch
+            source, pre_builder, pre_params, pre_key = self._absorbed_child()
+            _, drained, rest = materialized
+            input_iter = itertools.chain(drained, rest)
+        else:
+            source, pre_builder, pre_params, pre_key = (
+                self.children[0], None, [], ())
+            input_iter = source.execute(ctx)
+        update, merge = self._global_kernel, self._merge_kernel
+        state_schema = self._state_schema
+        cap = 8  # _global_kernel's state capacity
+
+        def build_init():
+            def init():
+                dead = ColumnarBatch(
+                    [Column.all_null(f.dtype, cap) for f in state_schema],
+                    jnp.zeros(cap, jnp.bool_), state_schema)
+                return (dead, jnp.int64(0)) if needs_off else (dead,)
+            return init
+
+        def build_step():
+            pre = pre_builder() if pre_builder is not None else None
+
+            def step(carry, batch):
+                if pre is not None:
+                    batch = pre(batch)
+                if needs_off:
+                    state, off = carry
+                    partial = E.eval_with_row_offset(update, batch, off)
+                    off = off + jnp.sum(batch.sel.astype(jnp.int64))
+                else:
+                    (state,), partial = carry, update(batch)
+                both = _flatten_stacked(_stack_states([state, partial]),
+                                        state_schema)
+                state = _head_rows(merge(both), cap)
+                return (state, off) if needs_off else (state,)
+            return _thread_params(step, pre_params)
+
+        key = self.kernel_key()
+        pvals = (E.parameter_values(pre_params),) if pre_params else ()
+        # the carry is donated to the step (never the batch: the scan
+        # cache's leaves are pinned): it is this loop's own, made by init
+        # or by the step before and referenced by nothing else, and a
+        # launch on a v5e costs 0.13 ms of host time per output buffer it
+        # has to allocate, 0.24 of a step's 0.48 ms (PERF.md, PR 28)
+        from .. import config as C
+        donate = ({"donate_argnums": (len(pvals),)}
+                  if ctx.conf.get(C.DONATION_ENABLED) else {})
+        init = cached_kernel(("stream_init", needs_off) + key, build_init)
+        step = cached_kernel(("stream_step", needs_off, pre_key) + key,
+                             build_step, **donate)
+        finalize = cached_kernel(key + ("finalize",),
+                                 lambda: self._finalize_kernel)
+        hot = {"carry": init()}
+        # distinct dedup happens inside ONE update call: a row-range split
+        # would double-count values straddling the halves (retry-only)
+        update_split = (None if self._distinct_child() is not None
+                        else split_batch_rows)
+
+        def attempt_step(b):
+            """Retryable: only the reservation raises what the retry
+            ladder catches, and it does before the step is issued (and
+            the carry donated), so a retried batch and the pieces of a
+            split one, IN ORDER, merge once each."""
+            nbytes = b.device_size_bytes()
+            if ctx.runtime is not None:
+                ctx.runtime.reserve(nbytes, site="agg.update")
+            record_cost(self.metrics, hbm_read=nbytes,
+                        flops=(b.known_rows if b.known_rows is not None
+                               else b.capacity) * self._cost_weight())
+            record_dispatch()
+            hot["carry"] = step(*pvals, hot["carry"], b)
+
+        def run(b):
+            with named_range("agg_update", self.metrics, MN.COMPUTE_AGG_TIME):
+                run_retryable(ctx, self.metrics, "aggUpdate", attempt_step,
+                              [b], split=update_split)
+
+        # present and 0: this loop reads nothing back
+        self.metrics.add(MN.AGG_HOST_SYNCS, 0)
+        streamed = 0
+        for batch in input_iter:
+            # stage-boundary lifecycle checkpoint (serve/lifecycle.py), as
+            # in the grouped loop: no reservation is mid-flight here
+            ctx_checkpoint(ctx, allow_suspend=True)
+            self.metrics.add(MN.AGG_STREAMED_BATCHES, 1)
+            self.metrics.add(MN.AGG_SYNC_FREE_BATCHES, 1)
+            run(batch)
+            streamed += 1
+        if not streamed:
+            # global agg over empty input still yields one row: the step
+            # over an all-dead batch of its input's schema
+            data = {f.name: [] for f in source.schema}
+            run(ColumnarBatch.from_pydict(data, source.schema))
+        out = finalize(hot["carry"][0])
+        record_output_batch(self.metrics, out, ctx.runtime)
+        return out
 
 
 def _scalar_col(value, valid, dtype, cap):

@@ -362,11 +362,14 @@ def monitoring_doc() -> str:
         "row-group chunk, ORC per column, CSV per chunk), on the prefetch "
         "thread when prefetch is on | footer and page parsing, "
         "decompression, H2D enqueue, decode dispatches (timer `scanTime`)",
-        "`srt:agg_shrink` | `exec/aggregate.py`, the streaming loop, one "
-        "a batch of capacity 8,192 or more, before its `srt:agg_update` | "
-        "the read of the batch's live-row count (the wait for the filter "
-        "program; counted in `aggHostSyncs`) and `maybe_shrink`'s eager "
-        "compaction",
+        "`srt:agg_shrink` | `exec/aggregate.py`, the GROUPED streaming "
+        "loop only (its update sorts at capacity), one a batch of capacity "
+        "8,192 or more, before its `srt:agg_update` | the read of the "
+        "batch's live-row count (the wait for the program before it; "
+        "counted in `aggHostSyncs`) and `maybe_shrink`'s eager compaction; "
+        "an aggregate with no grouping keys has none: its loop is one "
+        "`srt:agg_update` a batch around one `jit_agg.stream_step` "
+        "(`aggSyncFreeBatches`)",
         "`srt:agg_whole_stage_bail` | `exec/aggregate.py "
         "_try_whole_stage`, zero-length | why the input takes the "
         "streaming loop (`reason=bytes`: past half of "

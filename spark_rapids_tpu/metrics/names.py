@@ -288,16 +288,26 @@ AGG_DENSE_BATCHES = register_metric(
     "value as the batch's clean check, never a sync of its own")
 AGG_STREAMED_BATCHES = register_metric(
     "aggStreamedBatches", COUNTER, ESSENTIAL,
-    "input batches that went through the aggregate's per-batch update "
-    "(the streaming loop an input past half of batchSizeBytes, or of "
-    "unequal batch shapes, takes); 0 where the whole-stage program "
-    "answered; a host integer, never a sync")
+    "input batches that went through the aggregate's streaming loop (what "
+    "an input past half of batchSizeBytes, or of unequal batch shapes, "
+    "takes): the grouped loop's per-batch update, or the keyless loop's "
+    "step program; 0 where the whole-stage program answered; a host "
+    "integer, never a sync")
+AGG_SYNC_FREE_BATCHES = register_metric(
+    "aggSyncFreeBatches", COUNTER, ESSENTIAL,
+    "input batches the streaming loop of an aggregate with no grouping "
+    "keys took through its step program (filter, update and merge into "
+    "the running 1-row state in one launch) with no host read, no shrink "
+    "and no concat; equals aggStreamedBatches for such a query, 0 for a "
+    "grouped one; a host integer")
 AGG_HOST_SYNCS = register_metric(
     "aggHostSyncs", COUNTER, ESSENTIAL,
-    "host reads of a device value the aggregate made: a batch's live-row "
-    "count before the shrink, the bucket update's clean check, the row "
-    "count concat_batches reads per part on a fold; a host integer "
-    "counted where the read is made")
+    "host reads of a device value the aggregate made: the bucket "
+    "update's clean check and, in the GROUPED streaming loop only, a "
+    "batch's live-row count before the shrink and the row count "
+    "concat_batches reads per part on a fold; present and 0 for a "
+    "keyless aggregate through the streaming loop, which reads nothing; "
+    "a host integer counted where the read is made")
 SEG_AGG_TIME = register_metric(
     "segAggTime", TIMER, MODERATE,
     "segmented-aggregation kernel time inside grouped-aggregate "

@@ -129,7 +129,8 @@ def _grouped_minmax(lineitem):
 @pytest.fixture(scope="module")
 def smoke_programs(topo):
     """{query: [programs]} recorded from chip_smoke.py's q6, q1 and join
-    query (q1 over five batches too, and one grouped min/max) at SF1 widths,
+    query (q1 over five batches too, q6 through the streaming loop, and one
+    grouped min/max) at SF1 widths,
     run on the CPU with the engine on its TPU branches."""
     import chip_smoke
     from benchmarks.tpch import bulk
@@ -169,6 +170,14 @@ def smoke_programs(topo):
             records[name] = []
             mp.setattr(jax, "jit", _recording_jit(records[name]))
             assert query().collect()
+        # q6 again past the whole-stage budget (half of batchSizeBytes:
+        # 64 MiB against 38 MB a batch), as the SF10 table is at the
+        # default: the keyless streaming loop's step program
+        streaming = TpuSession({**chip_smoke.base_conf(),
+                                "spark.rapids.sql.batchSizeBytes": "128m"})
+        records["q6_stream"] = []
+        mp.setattr(jax, "jit", _recording_jit(records["q6_stream"]))
+        assert bulk.q6(streaming.from_arrow(lineitem)).collect()
         # the contiguous pack (shuffle/spill/broadcast unit) of one
         # reader batch of orders: ints, plus a double column for the
         # f32-pair branch
@@ -209,6 +218,9 @@ def _largest(programs, name_part):
 @pytest.mark.parametrize("query,kernel,min_rows", [
     # scan -> filter -> aggregate over all six 1M-row batches, one program
     ("q6", "agg.whole_stage", 1 << 20),
+    # the same query as the streaming loop answers it: filter, masked
+    # reductions and the merge into the running 1-row state, one batch
+    ("q6_stream", "agg.stream_step", 1 << 20),
     # the grouped aggregate over the same batches: per batch the bucket
     # update's `while` of dense passes, f64 sums and int64 counts in its
     # carry (two string keys; as a `cond` over a dense and a scatter form

@@ -156,18 +156,26 @@ def test_a_parquet_query_adds_the_scans_span_and_timer(tmp_path):
     assert 0 < agg[MN.SCAN_TIME] <= sum(e[1] - e[0] for e in decodes) / 1e9
 
 
-def test_a_streamed_aggregate_marks_its_bail_and_spans_each_shrink(tmp_path):
+@pytest.mark.parametrize("grouped", [False, True], ids=["keyless", "grouped"])
+def test_a_streamed_aggregate_marks_its_bail_and_spans_each_batch(tmp_path,
+                                                                  grouped):
     """An input past half of batchSizeBytes: the whole-stage probe leaves a
-    zero-length `srt:agg_whole_stage_bail` that says why, and each batch of
-    the streaming loop is shrunk under `srt:agg_shrink`, before and outside
-    its `srt:agg_update`."""
+    zero-length `srt:agg_whole_stage_bail` that says why.  The GROUPED
+    streaming loop shrinks each batch under `srt:agg_shrink`, before and
+    outside its `srt:agg_update`; the loop of an aggregate with no grouping
+    keys has no shrink to span: one `srt:agg_update` a batch around one
+    `agg.stream_step`, and no host read."""
     session = TpuSession({
         "spark.rapids.sql.variableFloatAgg.enabled": "true",
         "spark.rapids.sql.reader.batchSizeRows": "65536",
         "spark.rapids.sql.batchSizeBytes": "12m"})
-    collects, names = phases(traced(q6(session.from_arrow(lineitem())),
-                                    tmp_path, queries=2))
+    frame = session.from_arrow(lineitem())
+    df = (frame.filter(col("l_quantity") < 24.0).group_by(col("l_discount"))
+          .agg(F.sum(col("l_extendedprice") * col("l_shipdate")).alias("v"))
+          if grouped else q6(frame))
+    collects, names = phases(traced(df, tmp_path, queries=2))
     assert "PjitFunction(agg.whole_stage)" not in names
+    assert ("PjitFunction(agg.stream_step)" in names) == (not grouped)
     batches = -(-ROWS // 65536)
     for collect, spans in collects:
         by = {}
@@ -176,15 +184,22 @@ def test_a_streamed_aggregate_marks_its_bail_and_spans_each_shrink(tmp_path):
         [execute] = by["srt:execute"]
         [bail] = by["srt:agg_whole_stage_bail"]
         assert bail[3]["reason"] == "bytes" and int(bail[3]["batches"]) == 3
-        shrinks, updates = by["srt:agg_shrink"], by["srt:agg_update"]
-        assert len(shrinks) == len(updates) == batches
+        shrinks, updates = by.get("srt:agg_shrink", []), by["srt:agg_update"]
+        assert len(updates) == batches
+        assert len(shrinks) == (batches if grouped else 0)
         assert all(inside(e, execute) for e in [bail] + shrinks + updates)
-        assert bail[1] <= shrinks[0][0]
+        assert bail[1] <= (shrinks or updates)[0][0]
         assert all(s[1] <= u[0] for s, u in zip(shrinks, updates))
     moved = session.last_execution.aggregate()
     assert moved[MN.AGG_STREAMED_BATCHES] == batches
-    # a live-row read a batch, and the last fold's count per part
-    assert moved[MN.AGG_HOST_SYNCS] == 2 * batches
+    if grouped:
+        # a live-row read and a bucket check a batch, and the last fold's
+        # count per part
+        assert moved[MN.AGG_HOST_SYNCS] == 3 * batches
+        assert moved.get(MN.AGG_SYNC_FREE_BATCHES, 0) == 0
+    else:
+        assert moved[MN.AGG_HOST_SYNCS] == 0
+        assert moved[MN.AGG_SYNC_FREE_BATCHES] == batches
 
 
 def test_named_range_is_a_span_and_a_timer_and_never_a_sync():
