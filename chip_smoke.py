@@ -201,9 +201,6 @@ def one_chip_phase(args, devices, watch) -> bool:
     ok &= check("numHbmDetectFallbacks",
                 ENGINE_COUNTERS.get("numHbmDetectFallbacks") == 0,
                 value=ENGINE_COUNTERS.get("numHbmDetectFallbacks"))
-    # no counter for Pallas: with the flag on a kernel that fails to lower
-    # raises (nothing falls back), and the main path leaves the flag off
-    ok &= check("pallas_off", not dev.conf.get(C.PALLAS_ENABLED))
     stats = devices[0].memory_stats() or {}
     limit = int(stats.get("bytes_limit", 0))
     pool = dev.runtime.pool_limit

@@ -245,17 +245,13 @@ XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \
     -p no:cacheprovider
 echo "== mesh exchange tier took $((SECONDS - T_MESH))s =="
 
-echo "== pallas/donation tier =="
-# on-chip kernels + buffer donation (ISSUE 11): interpret-mode pallas
-# kernel tests (fused segmented aggregation, tiled bitonic sort, the
-# carry-pattern cumsum), the fused-dispatcher parity checks, the
-# packed-key argsort vs lexsort permutation equality, and the donation
-# parity sweep (donation ON vs OFF bit-for-bit across every dtype,
-# retry/checkpoint exclusion, multi-consumer pins)
-T_PAL=$SECONDS
-python -m pytest tests/test_pallas.py tests/test_donation.py -q \
-    -m "not slow" -p no:cacheprovider
-echo "== pallas/donation tier took $((SECONDS - T_PAL))s =="
+echo "== donation tier =="
+# buffer donation: the parity sweep (donation ON vs OFF bit-for-bit
+# across every dtype, retry/checkpoint exclusion, multi-consumer pins)
+T_DON=$SECONDS
+python -m pytest tests/test_donation.py -q -m "not slow" \
+    -p no:cacheprovider
+echo "== donation tier took $((SECONDS - T_DON))s =="
 
 echo "== tests (fast tier) =="
 T_TESTS=$SECONDS

@@ -1,6 +1,6 @@
 """spark_rapids_tpu — a TPU-native columnar SQL acceleration framework.
 
-A from-scratch JAX/XLA/Pallas implementation of the capability surface of the
+A from-scratch JAX/XLA implementation of the capability surface of the
 RAPIDS Accelerator for Apache Spark (plan rewrite -> columnar device operators
 -> tiered device memory -> columnar file I/O -> device-resident shuffle),
 designed for TPU: static-shape bucketed batches, whole-pipeline jit

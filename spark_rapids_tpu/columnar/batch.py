@@ -140,19 +140,13 @@ class ColumnarBatch:
     def compact(self) -> "ColumnarBatch":
         """Gather live rows to the front (stable).  Capacity unchanged.
 
-        The permutation is a 1-bit packed-key sort (utils/packed_sort):
-        jnp.argsort is a VARIADIC sort HLO (operand + iota) that costs
-        ~6x a single-operand sort on the CPU/TPU sort path, and compact
-        runs per batch in every concat/coalesce."""
-        from ..utils import packed_sort as PS
+        The permutation is a 1-bit stable sort (utils/packed_sort): live
+        rows keep relative order, dead rows go to the back; compact runs
+        per batch in every concat/coalesce."""
+        from ..utils.packed_sort import stable_argsort
         cap = self.capacity
         iota = jnp.arange(cap, dtype=jnp.int32)
-        if PS.packed_enabled() and cap & (cap - 1) == 0:
-            order = PS.packed_argsort([((~self.sel).astype(jnp.uint64), 1)],
-                                      cap)
-        else:
-            # stable: live rows keep relative order, dead rows at the back
-            order = jnp.argsort(jnp.where(self.sel, iota, cap + iota))
+        order = stable_argsort([((~self.sel).astype(jnp.uint64), 1)], cap)
         n = self.num_rows()
         new_sel = iota < n
         return self.take(order, sel=new_sel)

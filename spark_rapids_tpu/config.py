@@ -258,19 +258,6 @@ DONATION_ENABLED = _conf(
     "of allocating a fresh copy per column per batch.  Results are "
     "byte-identical either way; false restores the copying behavior "
     "(numDonatedBuffers counts what warm runs saved).", _to_bool)
-SORT_PACKED_ENABLED = _conf(
-    "spark.rapids.sql.tpu.sort.packed.enabled", True,
-    "One-shot packed-key sort: fuse the order-preserving integer sort "
-    "keys (exec/sort.py encodings) into as few 64-bit words as their "
-    "static bit widths allow, embed the row id in the low bits, and "
-    "order rows with SINGLE-operand jax.lax.sort passes (one pass when "
-    "key+rowid bits fit 64, else a stable LSD radix over 64-bit chunks) "
-    "instead of the N-pass variadic lexsort.  Grouped aggregation's "
-    "(h1, h2) hash sort takes the same path.  The permutation is "
-    "bit-identical to lexsort (ties break by row id = stable); columns "
-    "whose keys are not order-preserving integers on this backend "
-    "(float sort keys on the emulated-f64 TPU backend) fall back to "
-    "lexsort.  false restores lexsort everywhere.", _to_bool)
 FUSION_MAX_OPS = _conf(
     "spark.rapids.sql.tpu.fusion.maxOpsPerStage", 16,
     "Upper bound on row-local operators fused into one whole-stage "
@@ -305,15 +292,6 @@ MESH_DEVICES = _conf(
     "(exec/distributed.py); 0/1 keeps single-chip execution.  Must be a "
     "power of two and <= the device count (fewer devices is an error, "
     "never a quiet single-chip run).", int)
-PALLAS_ENABLED = _conf(
-    "spark.rapids.sql.tpu.pallas.enabled", False,
-    "Use hand-written pallas kernels where available (currently the "
-    "prefix-sum inside segmented aggregation: one sequential-grid VMEM "
-    "pass with an SMEM carry instead of XLA's log-depth scan).  TPU "
-    "backend only; the CPU backend always takes the XLA lowering.  A "
-    "kernel that fails to lower raises rather than quietly running XLA: "
-    "the installed Pallas TPU lowering refuses all three kernels "
-    "(cumsum / dynamic_slice / rev), so leave this off.", _to_bool)
 MESH_COORDINATOR = _conf(
     "spark.rapids.sql.tpu.mesh.coordinator", "",
     "host:port of the jax.distributed coordinator for MULTI-HOST meshes "

@@ -146,42 +146,25 @@ def group_rows(key_cols: Sequence[Column], live, value_cols=None):
     gid_sorted[i]: group id of sorted position i (garbage for dead rows).
     `value_cols`: optional minor sort keys — equal values land adjacent
     WITHIN each group (the distinct-aggregate dedup needs this)."""
-    from ..utils import packed_sort as PS
+    from ..utils.packed_sort import stable_argsort
     cap = live.shape[0]
-    packed = PS.packed_enabled() and cap & (cap - 1) == 0
     if not key_cols and not value_cols:
         # one group — but the contract (dead rows LAST) must still hold:
         # merge states interleave live/dead rows, and the searchsorted
         # segmented reducers require gid sorted after the dead->cap-1 remap
-        if packed:
-            # single-operand packed sort (lexsort is variadic even for
-            # one key); identical stable permutation
-            order = PS.packed_argsort([((~live).astype(jnp.uint64), 1)],
-                                      cap)
-        else:
-            order = jnp.lexsort(((~live).astype(jnp.int8),)) \
-                .astype(jnp.int32)
+        order = stable_argsort([((~live).astype(jnp.uint64), 1)], cap)
         gid = jnp.zeros(cap, dtype=jnp.int32)
         live_s = jnp.take(live, order)
         boundary = jnp.zeros(cap, dtype=jnp.bool_).at[0].set(live_s[0])
         return order, gid, boundary, jnp.minimum(jnp.sum(live), 1)
     h1, h2 = hash_columns_double(key_cols, live) if key_cols else (
         jnp.zeros(cap, jnp.uint64), jnp.zeros(cap, jnp.uint64))
-    # stable sort: primary h1, secondary h2, tertiary original index —
-    # packed path runs it as an LSD radix of single-operand sorts (the
-    # variadic lexsort costs ~6x per pass on the CPU sort HLO; identical
-    # permutation either way)
+    # stable sort: primary h1, secondary h2, then the value hashes,
+    # tertiary original index
+    comps = [(h1, 64), (h2, 64)]
     if value_cols:
-        vh1, vh2 = hash_columns_double(value_cols, live)
-        if packed:
-            order = PS.packed_argsort(
-                [(h1, 64), (h2, 64), (vh1, 64), (vh2, 64)], cap)
-        else:
-            order = jnp.lexsort((vh2, vh1, h2, h1)).astype(jnp.int32)
-    elif packed:
-        order = PS.packed_argsort([(h1, 64), (h2, 64)], cap)
-    else:
-        order = jnp.lexsort((h2, h1)).astype(jnp.int32)
+        comps += [(vh, 64) for vh in hash_columns_double(value_cols, live)]
+    order = stable_argsort(comps, cap)
     if not key_cols:
         live_s = jnp.take(live, order)
         gid = jnp.zeros(cap, dtype=jnp.int32)
@@ -245,123 +228,42 @@ def _shift1_rows(m):
 # cancellation.  min/max have no invertible prefix form and keep
 # segment_min/max.
 
-_PALLAS_CUMSUM = [False]  # flipped by the conf via set_pallas_cumsum
-# test hook: route the fused segmented kernel through pallas INTERPRET
-# mode on the CPU backend so the full dispatcher (not just the kernel)
-# is exercised by tests/test_pallas.py
-_PALLAS_SEG_INTERPRET = [False]
-
-
-def set_pallas_cumsum(enabled: bool) -> None:
-    _PALLAS_CUMSUM[0] = bool(enabled)  # tpulint: disable=TPU009 per-session conf latch: atomic boolean store, same-value writers under one session conf
-
-
-def _masked_cumsum(v):
-    # pallas path: TPU backend only (CPU lacks non-interpret pallas) and
-    # 32-bit dtypes only (XLA:TPU emulates 64-bit types, which the kernel
-    # does not take); everything else takes XLA's cumsum.  A kernel that
-    # fails to lower RAISES: with the flag on, "pallas" never quietly
-    # means the XLA lowering.
-    if _PALLAS_CUMSUM[0] and v.dtype.itemsize < 8 \
-            and jax.default_backend() == "tpu":
-        from ..ops.pallas_kernels import cumsum_1d
-        return cumsum_1d(v)
-    return jnp.cumsum(v)
-
-
-def _pallas_seg_mode():
-    """Which fused-kernel mode the dispatcher may use: 'tpu' (compiled,
-    BACKEND-gated — BENCH_PALLAS showed the pallas formulation slower
-    than XLA on the CPU backend, so the flag alone is not enough),
-    'interpret' (test hook), or None (XLA per-request reducers)."""
-    if _PALLAS_SEG_INTERPRET[0]:
-        return "interpret"
-    if _PALLAS_CUMSUM[0] and jax.default_backend() == "tpu":
-        return "tpu"
-    return None
-
-
 def _seg_multi(reqs, gid, cap):
-    """All requested segmented reductions over sorted `gid` in as few
-    HBM passes as the backend allows.
+    """All requested segmented reductions over sorted `gid`.
 
-    `reqs`: list of (op, vals, contribute, fill[, is_count]) with op in
+    `reqs`: list of (op, vals, contribute, fill) with op in
     'sum'|'min'|'max' — contribute masks rows out (sum: add 0; min/max:
-    compare fill), exactly the legacy _seg_sum/_seg_min/_seg_max
-    contracts.  Returns one [cap] array per request.
-
-    Fused path (TPU backend + pallas.enabled, or the interpret test
-    hook): ONE pallas pass (ops/pallas_kernels.seg_agg_1d) computes the
-    running segmented aggregate of every request at once, and a SHARED
-    searchsorted pair gathers each segment's last-row value — instead of
-    one scatter/prefix pass per aggregate.  A kernel that fails to lower
-    raises (no quiet XLA substitute).  64-bit requests stay on the XLA
-    reducers on the TPU backend (XLA:TPU emulates them), except
-    counts (`is_count`: 0/1 values) which run in int32 and widen after.
-    XLA path: the prior per-request formulations verbatim — integer sums
+    compare fill).  Returns one [cap] array per request: integer sums
     via prefix-diff, float sums via scatter segment_sum (a restart-free
     prefix would cancel catastrophically), min/max via segment_min/max —
     sharing one searchsorted pair across every request."""
     n = gid.shape[0]
-    results = [None] * len(reqs)
-    mode = _pallas_seg_mode()
-    # shared segment bounds (one searchsorted pair for ALL requests; the
-    # legacy path recomputed them per _seg_sum call)
+    results = []
     seg = jnp.arange(cap, dtype=gid.dtype)
     start = jnp.searchsorted(gid, seg, side="left")
     end = jnp.searchsorted(gid, seg, side="right")
     end_ix = jnp.clip(end - 1, 0, n - 1)
     nonempty = end > start
-
-    fused: list = []  # (req index, kernel value array, out cast dtype)
-    if mode is not None:
-        for i, req in enumerate(reqs):
-            op, vals, contribute, fill = req[0], req[1], req[2], req[3]
-            is_count = bool(req[4]) if len(req) > 4 else False
-            dt = vals.dtype
-            if mode == "tpu" and dt.itemsize >= 8:
-                if not (is_count and op == "sum"):
-                    continue  # emulated 64-bit: XLA reducer below
-                vals, dt = vals.astype(jnp.int32), jnp.dtype(jnp.int32)
-            if op == "sum":
-                v = jnp.where(contribute, vals, jnp.zeros((), dt))
-            else:
-                v = jnp.where(contribute, vals, fill)
-            fused.append((i, v, reqs[i][1].dtype))
-    if fused:
-        from ..ops.pallas_kernels import seg_agg_1d
-        running = seg_agg_1d(gid, [v for _, v, _ in fused],
-                             [reqs[i][0] for i, _, _ in fused],
-                             interpret=(mode == "interpret"))
-        for (i, _v, out_dt), run in zip(fused, running):
-            op, fill = reqs[i][0], reqs[i][3]
-            ident = (jnp.zeros((), run.dtype) if op == "sum"
-                     else jnp.asarray(fill).astype(run.dtype))
-            out = jnp.where(nonempty, run[end_ix], ident)
-            results[i] = out.astype(out_dt)
-    for i, req in enumerate(reqs):
-        if results[i] is not None:
-            continue
-        op, vals, contribute, fill = req[0], req[1], req[2], req[3]
+    for op, vals, contribute, fill in reqs:
         if op == "sum":
             v = jnp.where(contribute, vals, jnp.zeros((), vals.dtype))
             if jnp.issubdtype(vals.dtype, jnp.floating):
-                results[i] = jax.ops.segment_sum(
-                    v, gid, num_segments=cap, indices_are_sorted=True)
+                results.append(jax.ops.segment_sum(
+                    v, gid, num_segments=cap, indices_are_sorted=True))
                 continue
-            c = _masked_cumsum(v)
+            c = jnp.cumsum(v)
             zero = jnp.zeros((), c.dtype)
             total = jnp.where(end > 0, c[end_ix], zero)
             prev = jnp.where(start > 0, c[jnp.clip(start - 1, 0, n - 1)],
                              zero)
-            results[i] = jnp.where(nonempty, total - prev,
-                                   zero).astype(vals.dtype)
+            results.append(jnp.where(nonempty, total - prev,
+                                     zero).astype(vals.dtype))
         else:
             v = jnp.where(contribute, vals, fill)
             reducer = (jax.ops.segment_min if op == "min"
                        else jax.ops.segment_max)
-            results[i] = reducer(v, gid, num_segments=cap,
-                                 indices_are_sorted=True)
+            results.append(reducer(v, gid, num_segments=cap,
+                                   indices_are_sorted=True))
     return results
 
 
@@ -410,8 +312,7 @@ def _update_one(agg: AggregateExpression, col, gid, live_s, cap,
             contribute = live_s & col.valid
         if agg.distinct and dedup is not None:
             contribute = contribute & dedup
-        cnt = _seg_multi([("sum", contribute.astype(jnp.int64), live_s,
-                           0, True)], gid, cap)[0]
+        cnt = _seg_sum(contribute.astype(jnp.int64), gid, live_s, cap)
         return [Column(cnt, jnp.ones(cap, jnp.bool_), LongType)]
     valid = col.valid
     contribute = live_s & valid
@@ -423,7 +324,7 @@ def _update_one(agg: AggregateExpression, col, gid, live_s, cap,
         # one fused segmented pass for the value sum AND its count
         s, nvalid = _seg_multi(
             [("sum", v, contribute, 0),
-             ("sum", contribute.astype(jnp.int64), live_s, 0, True)],
+             ("sum", contribute.astype(jnp.int64), live_s, 0)],
             gid, cap)
         sum_col = Column(s, nvalid > 0, out_t).mask_invalid()
         if f == "Sum":
@@ -487,9 +388,8 @@ def _minmax(f, dtype, vals, gid, contribute, cap):
             has_nan_i, nvalid, n_non_nan, r = _seg_multi(
                 [("max", (contribute & isnan).astype(jnp.int32), ones,
                   jnp.int32(0)),
-                 ("sum", contribute.astype(jnp.int64), ones, 0, True),
-                 ("sum", (contribute & ~isnan).astype(jnp.int32), ones,
-                  0, True),
+                 ("sum", contribute.astype(jnp.int64), ones, 0),
+                 ("sum", (contribute & ~isnan).astype(jnp.int32), ones, 0),
                  ("min", jnp.where(isnan, jnp.inf, v), contribute,
                   jnp.float64(np.inf))], gid, cap)
             # NaN only wins min when the group has NO non-NaN values
@@ -500,7 +400,7 @@ def _minmax(f, dtype, vals, gid, contribute, cap):
             has_nan_i, nvalid, r = _seg_multi(
                 [("max", (contribute & isnan).astype(jnp.int32), ones,
                   jnp.int32(0)),
-                 ("sum", contribute.astype(jnp.int64), ones, 0, True),
+                 ("sum", contribute.astype(jnp.int64), ones, 0),
                  ("max", jnp.where(isnan, -jnp.inf, v), contribute,
                   jnp.float64(-np.inf))], gid, cap)
             r = jnp.where(has_nan_i > 0, jnp.nan, r)  # NaN is greatest
@@ -509,11 +409,11 @@ def _minmax(f, dtype, vals, gid, contribute, cap):
     v = vals.astype(jnp.int64)
     if f == "Min":
         nvalid, r = _seg_multi(
-            [("sum", contribute.astype(jnp.int64), ones, 0, True),
+            [("sum", contribute.astype(jnp.int64), ones, 0),
              ("min", v, contribute, jnp.int64(_I64_MAX))], gid, cap)
     else:
         nvalid, r = _seg_multi(
-            [("sum", contribute.astype(jnp.int64), ones, 0, True),
+            [("sum", contribute.astype(jnp.int64), ones, 0),
              ("max", v, contribute, jnp.int64(_I64_MIN))], gid, cap)
     return Column(r.astype(dtype.jnp_dtype), nvalid > 0, dtype) \
         .mask_invalid()
@@ -857,8 +757,8 @@ class TpuHashAggregateExec(TpuExec):
                 contribute = live_s & scol.valid
                 s, nvalid = _seg_multi(
                     [("sum", scol.data, contribute, 0),
-                     ("sum", contribute.astype(jnp.int64), live_s, 0,
-                      True)], gid, cap)
+                     ("sum", contribute.astype(jnp.int64), live_s, 0)],
+                    gid, cap)
                 out_cols.append(Column(s, nvalid > 0, cols[0].dtype)
                                 .mask_invalid())
             elif f == "Average":
@@ -1028,14 +928,7 @@ class TpuHashAggregateExec(TpuExec):
 
     def kernel_key(self) -> tuple:
         from ..utils.kernel_cache import expr_key, schema_key
-        from ..utils import packed_sort as _PS
         return ("TpuHashAggregateExec",
-                # the pallas/packed flags change the traced program (the
-                # packed kill switch must also bust cached kernels —
-                # "false restores lexsort" is a per-process contract)
-                ("pallas" if _PALLAS_CUMSUM[0] else "xla"),
-                (_pallas_seg_mode() or "none"),
-                ("packed" if _PS.packed_enabled() else "lex"),
                 tuple(expr_key(g) for g in self.grouping),
                 tuple(self.group_names),
                 tuple(expr_key(a) for a in self.aggregates),
@@ -1289,7 +1182,6 @@ class TpuHashAggregateExec(TpuExec):
     def _execute_device(self, ctx: ExecContext):
         from ..utils.kernel_cache import cached_kernel
         from .. import config as C
-        set_pallas_cumsum(ctx.conf.get(C.PALLAS_ENABLED))
         whole, materialized = self._try_whole_stage(ctx)
         if whole is not None:
             yield whole

@@ -91,17 +91,9 @@ class ExecContext:
                  num_partitions: int = 1, runtime=None, cluster=None,
                  journal=None, query_execution=None):
         self.conf = conf or TpuConf()
-        # latch the packed-sort kill switch for every device path this
-        # query touches (sort, grouping, compact, join build, partition
-        # split) — the flag only selects between two formulations that
-        # produce IDENTICAL permutations, so a concurrent query with a
-        # different conf can at worst run the other (equally correct)
-        # kernel, mirroring the pallas flag's semantics
+        # roofline cost-accounting latch: observability-only, so
+        # cross-query interleaving is safe
         from .. import config as _C
-        from ..utils import packed_sort as _PS
-        _PS.set_packed_enabled(self.conf.get(_C.SORT_PACKED_ENABLED))
-        # roofline cost-accounting latch: same semantics as the packed
-        # flag — observability-only, so cross-query interleaving is safe
         from ..metrics.roofline import set_cost_accounting
         set_cost_accounting(self.conf.get(_C.ROOFLINE_COST_ENABLED))
         self.partition_id = partition_id

@@ -136,8 +136,6 @@ class TpuDistributedAggregateExec(TpuHashAggregateExec):
 
     def execute(self, ctx: ExecContext):
         from .. import config as C
-        from .aggregate import set_pallas_cumsum
-        set_pallas_cumsum(ctx.conf.get(C.PALLAS_ENABLED))
         n = self.mesh.shape[DATA_AXIS]
         chunk_rows = max(int(ctx.conf.get(C.MESH_INPUT_CHUNK_ROWS)), n)
         chunks = _sharded_chunks(self.children[0], ctx, self.mesh, n,

@@ -138,13 +138,9 @@ class TpuHashJoinExec(TpuExec):
 
     def kernel_key(self) -> tuple:
         from ..utils.kernel_cache import expr_key, schema_key
-        from ..utils import packed_sort as PS
         # schemas matter: the gather kernel closes over self._schema, and
         # two joins with identical key exprs can differ in payload columns
         return ("TpuHashJoinExec", self.join_type,
-                # the packed-sort flag changes the build kernel's traced
-                # program (kill-switch contract: false == lexsort family)
-                ("packed" if PS.packed_enabled() else "lex"),
                 tuple(expr_key(e) for e in self.left_keys),
                 tuple(expr_key(e) for e in self.right_keys),
                 expr_key(self.condition) if self.condition is not None
@@ -160,14 +156,8 @@ class TpuHashJoinExec(TpuExec):
         """Sort the build batch by key hash; dead rows last."""
         keys = [e.eval(rbatch) for e in self.right_keys]
         h1, _h2 = hash_columns_double(keys, rbatch.sel)
-        from ..utils import packed_sort as PS
-        cap = rbatch.capacity
-        if PS.packed_enabled() and cap & (cap - 1) == 0:
-            # single-operand packed sort passes (same stable order;
-            # variadic argsort pays the multi-operand comparator)
-            order = PS.packed_argsort([(h1, 64)], cap)
-        else:
-            order = jnp.argsort(h1, stable=True).astype(jnp.int32)
+        from ..utils.packed_sort import stable_argsort
+        order = stable_argsort([(h1, 64)], rbatch.capacity)
         sorted_batch = rbatch.take(order)
         skeys = [k.take(order) for k in keys]
         return sorted_batch, skeys, jnp.take(h1, order)

@@ -25,7 +25,7 @@ from ..ops.windows import (UNBOUNDED, WindowFunc, eval_window_func,
 from ..types import Schema, StructField
 from .base import (CpuExec, ExecContext, ExecNode, TpuExec,
                    record_output_batch)
-from .sort import sort_order
+from ..ops.sort_keys import sort_order
 from ..metrics import names as MN
 
 
@@ -84,11 +84,7 @@ class TpuWindowExec(TpuExec):
 
     def kernel_key(self):
         from ..utils.kernel_cache import expr_key
-        from ..utils import packed_sort as PS
         return ("TpuWindowExec",
-                # sort_order inside the window kernel follows the
-                # packed-sort flag; key it so the kill switch holds
-                ("packed" if PS.packed_enabled() else "lex"),
                 tuple(expr_key(e) for e in self.part_exprs),
                 tuple(expr_key(e) for e in self.order_exprs),
                 tuple(self.ascending), tuple(self.nulls_first),

@@ -628,7 +628,7 @@ def _bss_decode(payload: bytes, n_values: int, phys: str, cap: int):
     layout plus a little-endian byte combine.  float32 bitcasts on
     device; float64 combines on host (f64<->int bitcasts are
     unimplemented on the emulated-f64 chip — the same carve-out as the
-    sort keys, exec/sort.py:float_sort_keys)."""
+    sort keys, ops/sort_keys.py:float_sort_keys)."""
     import jax
     import jax.numpy as jnp
 

@@ -201,7 +201,7 @@ def monitoring_doc() -> str:
         "  link that device-side decode or device-resident shuffle",
         "  would keep in HBM.  `hbm`-bound nodes with LOW utilization",
         "  are kernel-bound: the kernel runs far off the memory",
-        "  roofline (the Pallas/fusion backlog); with HIGH utilization",
+        "  roofline (the kernel/fusion backlog); with HIGH utilization",
         "  they are genuinely bandwidth-bound — the good end state.",
         "",
         "Surfaces: `explain_with_metrics()` appends",

@@ -273,7 +273,7 @@ NUM_DONATED_BUFFERS = register_metric(
     "input batch is pinned (scan cache, spillable registration, retry "
     "checkpoint)")
 
-# --- on-chip kernels (exec/sort.py packed keys, aggregate seg-agg) -----------
+# --- operator kernels (packed-key sort, aggregate bucket update) ------------
 NUM_PACKED_SORTS = register_metric(
     "numPackedSorts", COUNTER, ESSENTIAL,
     "sort dispatches that took the packed-key path (sort keys fused "

@@ -70,7 +70,7 @@ _COST_ACCOUNTING = [True]
 
 
 def set_cost_accounting(on: bool) -> None:
-    _COST_ACCOUNTING[0] = bool(on)  # tpulint: disable=TPU009 per-session conf latch like packed_sort's: an atomic boolean store, observability-only — a racing query at worst records/skips its own declarations
+    _COST_ACCOUNTING[0] = bool(on)  # tpulint: disable=TPU009 per-session conf latch: an atomic boolean store, observability-only — a racing query at worst records/skips its own declarations
 
 
 def cost_accounting_enabled() -> bool:

@@ -438,7 +438,7 @@ def _min_max(func: WindowFunc, c: Column, a, b, iota, seg_start,
     is_min = func.kind == "Min"
     if c.dtype.is_string:
         return _min_max_string(func, c, a, b, iota, seg_first, seg_last)
-    from ..exec.sort import column_sort_keys
+    from .sort_keys import column_sort_keys
     # encode to order-preserving int64 keys so one scan handles floats with
     # Spark NaN/-0.0 semantics too
     keys = column_sort_keys(c, ascending=True)
@@ -475,7 +475,7 @@ def _min_max(func: WindowFunc, c: Column, a, b, iota, seg_start,
 
 
 def _decode_sort_key(k, dtype: DataType):
-    """Invert exec.sort.column_sort_keys for single-key integer dtypes
+    """Invert sort_keys.column_sort_keys for single-key integer dtypes
     (floats take the pair-scan path in _min_max_float)."""
     assert not dtype.is_floating
     if dtype.name == "boolean":

@@ -77,18 +77,10 @@ def exchange_compact(batch: ColumnarBatch, bucket, quota: int,
     live = batch.sel
     dest = jnp.where(live, bucket.astype(jnp.int32), n)
     # group rows by destination (stable: preserves row order within a
-    # dest).  Packed single-operand sort when the capacity allows it:
-    # jnp.argsort is a VARIADIC sort HLO (operand + iota) costing ~6x a
-    # single-operand sort on the CPU/TPU sort path (utils/packed_sort,
-    # PR-11 measurement), and this sort runs inside EVERY quota-block
-    # exchange dispatch — the permutation is bit-identical either way
-    from ..utils import packed_sort as PS
-    if PS.packed_enabled() and cap & (cap - 1) == 0:
-        order = PS.packed_argsort(
-            [(dest.astype(jnp.uint64), max(1, int(n).bit_length() + 1))],
-            cap)
-    else:
-        order = jnp.argsort(dest, stable=True).astype(jnp.int32)
+    # dest); this sort runs inside EVERY quota-block exchange dispatch
+    from ..utils.packed_sort import stable_argsort
+    order = stable_argsort(
+        [(dest.astype(jnp.uint64), max(1, int(n).bit_length() + 1))], cap)
     dsorted = jnp.take(dest, order)
     start_of = jnp.searchsorted(dsorted, jnp.arange(n, dtype=jnp.int32)
                                 ).astype(jnp.int32)
@@ -777,7 +769,7 @@ def distributed_sort_step(sort_exprs, ascending, nulls_first, mesh: Mesh,
     d's live rows are all <= device d+1's under the sort order, and locally
     sorted — so shard order IS global order.
     """
-    from ..exec.sort import sort_order
+    from ..ops.sort_keys import sort_order
     n = mesh.shape[axis]
     first = sort_exprs[0]
 

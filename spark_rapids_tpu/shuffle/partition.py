@@ -20,7 +20,8 @@ import numpy as np
 
 from ..columnar import Column, ColumnarBatch, bucket_rows
 from ..ops.hashing import spark_hash_columns
-from ..exec.sort import column_sort_keys
+from ..ops.sort_keys import column_sort_keys, sort_order
+from ..utils.packed_sort import stable_argsort
 
 
 # ---- partition id kernels (traced) -----------------------------------------
@@ -96,7 +97,6 @@ def sample_range_bounds(batches: Sequence[ColumnarBatch], sort_exprs,
     (reference: GpuRangePartitioner.sketch/determineBounds,
     GpuRangePartitioner.scala:42-216 + SamplingUtils.scala).  Returns a
     small device batch of bound rows, or None when there is no data."""
-    from ..exec.sort import sort_order
     from ..types import Schema, StructField
 
     key_schema = Schema([StructField(f"k{i}", e.dtype)
@@ -152,8 +152,8 @@ def split_by_partition(batch: ColumnarBatch, pids: jnp.ndarray, n: int,
     cap = batch.capacity
     live = batch.sel
     key = jnp.where(live, pids.astype(jnp.int64), jnp.int64(n))
-    from ..exec.sort import _packed_or_argsort
-    order = _packed_or_argsort(key, max(1, int(n).bit_length()), cap)
+    order = stable_argsort(
+        [(key.astype(jnp.uint64), max(1, int(n).bit_length()))], cap)
     sorted_batch = batch.take(order)
     counts = np.asarray(jnp.bincount(
         jnp.where(live, pids, jnp.int32(n)), length=n + 1))[:n]

@@ -708,3 +708,133 @@ def test_bucket_update_indexes_nothing_per_row():
     per_row = _per_row_indexed(sort, cap)
     assert "gather" in per_row and \
         any(n.startswith("scatter") for n in per_row), per_row
+
+
+# --------------------------------------------------------------------------
+# segment reducers (sorted ids, masked): _seg_multi and its _seg_sum face
+# --------------------------------------------------------------------------
+
+def test_seg_sum_float_keeps_scatter_semantics():
+    """Float sums must survive huge-magnitude neighbors (prefix-diff would
+    absorb small segments after a 1e300 running total — the reason floats
+    keep scatter, exec/aggregate.py _seg_sum)."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.exec.aggregate import _seg_sum
+    cap = 1024
+    gid = np.zeros(cap, np.int32)
+    gid[2:] = np.arange(2, cap)  # seg 0: rows 0-1, then singletons
+    vals = np.full(cap, 123.5)
+    vals[0] = 1e300
+    contribute = np.ones(cap, bool)
+    got = np.asarray(_seg_sum(jnp.asarray(vals), jnp.asarray(gid),
+                              jnp.asarray(contribute), cap))
+    assert got[0] == 1e300 + 123.5
+    assert got[5] == 123.5  # NOT absorbed to 0.0
+
+
+def test_seg_sum_gather_matches_scatter():
+    """The searchsorted/prefix-sum segmented sum must equal XLA's
+    scatter-based segment_sum on sorted ids, including empty segments,
+    masked rows, and the dead-rows-at-cap-1 convention."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.exec.aggregate import _seg_sum
+    rng = np.random.RandomState(9)
+    cap = 2048
+    n_live = 1500
+    gid = np.sort(rng.randint(0, 40, n_live))
+    gid = np.concatenate([gid, np.full(cap - n_live, cap - 1)])
+    vals = rng.randint(-100, 100, cap).astype(np.int64)
+    contribute = rng.rand(cap) < 0.8
+    contribute[n_live:] = False
+    got = np.asarray(_seg_sum(jnp.asarray(vals), jnp.asarray(gid),
+                              jnp.asarray(contribute), cap))
+    v = np.where(contribute, vals, 0)
+    want = np.asarray(jax.ops.segment_sum(
+        jnp.asarray(v), jnp.asarray(gid), num_segments=cap,
+        indices_are_sorted=True))
+    assert (got == want).all()
+
+
+def test_seg_sum_int_overflow_wraps_like_scatter():
+    """int64 prefix-diff wraps identically to per-segment accumulation
+    (modular addition is associative)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.exec.aggregate import _seg_sum
+    cap = 1024
+    gid = np.sort(np.arange(cap) % 7).astype(np.int32)
+    vals = np.full(cap, 2**61, np.int64)
+    contribute = np.ones(cap, bool)
+    got = np.asarray(_seg_sum(jnp.asarray(vals), jnp.asarray(gid),
+                              jnp.asarray(contribute), cap))
+    want = np.asarray(jax.ops.segment_sum(
+        jnp.asarray(vals), jnp.asarray(gid), num_segments=cap,
+        indices_are_sorted=True))
+    assert (got == want).all()
+
+
+def test_seg_sum_fewer_segments_than_rows():
+    """cap (segment count) smaller than the row count — the global
+    kernel's 1-segment whole-batch reduction shape (regression: prefix
+    indices were clipped to cap-1 instead of rows-1)."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.exec.aggregate import _seg_sum
+    rows = 1024
+    gid = np.zeros(rows, np.int32)
+    vals = np.arange(rows, dtype=np.int64)
+    contribute = (np.arange(rows) % 3) == 0
+    got = np.asarray(_seg_sum(jnp.asarray(vals), jnp.asarray(gid),
+                              jnp.asarray(contribute), 1))
+    want = int(vals[contribute].sum())
+    assert got.tolist() == [want]
+
+
+@pytest.mark.parametrize("dtype", ["int32", "int64", "float32", "float64"])
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+def test_seg_multi_against_numpy(op, dtype):
+    """Each reduction against a plain loop: a segment of thousands of
+    rows, an empty segment, masked-out rows, the dead rows at gid cap-1,
+    and all three requested in one call sharing the segment bounds."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.exec.aggregate import _seg_multi
+    rng = np.random.RandomState(17)
+    cap, n_live = 8192, 7000
+    # segment 0 spans 5,000 rows, segment 1 is empty, 2..40 share the rest
+    gid = np.concatenate([np.zeros(5000, np.int32),
+                          np.sort(rng.randint(2, 41, n_live - 5000)),
+                          np.full(cap - n_live, cap - 1)]).astype(np.int32)
+    dt = np.dtype(dtype)
+    # integer-valued, so a float sum is exact in any order
+    vals = rng.randint(-1000, 1000, cap).astype(dt)
+    contribute = rng.rand(cap) < 0.7
+    contribute[n_live:] = False
+    contribute[gid == 7] = False   # a segment with every row masked out
+    if dt.kind == "f":
+        lo, hi = dt.type(-np.inf), dt.type(np.inf)
+    else:
+        lo, hi = np.iinfo(dt).min, np.iinfo(dt).max
+    # min/max: a masked row compares as `fill`; an empty segment holds
+    # the reducer's identity
+    fill = {"sum": 0, "min": hi, "max": lo}[op]
+    want = np.full(cap, fill, dt)
+    for g, v, c in zip(gid, vals, contribute):
+        if not c:
+            continue
+        if op == "sum":
+            want[g] += v
+        else:
+            want[g] = min(want[g], v) if op == "min" else max(want[g], v)
+    reqs = [(o, jnp.asarray(vals), jnp.asarray(contribute),
+             jnp.asarray({"sum": 0, "min": hi, "max": lo}[o], dt))
+            for o in ("sum", "min", "max")]
+    got = _seg_multi(reqs, jnp.asarray(gid), cap)
+    got = np.asarray(got[("sum", "min", "max").index(op)])
+    assert got.dtype == dt
+    np.testing.assert_array_equal(got, want)
+    assert want[1] == fill and want[7] == fill and abs(want[0]) > 0

@@ -254,42 +254,3 @@ def test_f64_bitcast_is_what_the_tpu_branches_avoid(one_chip,
     with pytest.raises(Exception, match="X64"):
         jax.jit(lambda v: jax.lax.bitcast_convert_type(v, jnp.int64)) \
             .lower(x).compile()
-
-
-def _pallas_cumsum(sharding):
-    from spark_rapids_tpu.ops.pallas_kernels import cumsum_1d
-    x = jax.ShapeDtypeStruct((1 << 20,), jnp.int32, sharding=sharding)
-    return jax.jit(cumsum_1d).lower(x)
-
-
-def _pallas_seg_agg(sharding):
-    from spark_rapids_tpu.ops.pallas_kernels import seg_agg_1d
-    gid = jax.ShapeDtypeStruct((1 << 20,), jnp.int32, sharding=sharding)
-    val = jax.ShapeDtypeStruct((1 << 20,), jnp.float32, sharding=sharding)
-    return jax.jit(lambda g, v: seg_agg_1d(g, [v], ["sum"])).lower(gid, val)
-
-
-def _pallas_bitonic(sharding):
-    from spark_rapids_tpu.ops.pallas_kernels import bitonic_sort_u64
-    keys = jax.ShapeDtypeStruct((1 << 20,), jnp.uint64, sharding=sharding)
-    return jax.jit(bitonic_sort_u64).lower(keys)
-
-
-@pytest.mark.parametrize("lower", [
-    pytest.param(_pallas_cumsum, id="cumsum_1d", marks=pytest.mark.xfail(
-        strict=True, reason="Unimplemented primitive in Pallas TPU lowering "
-        "for KernelType.TC: cumsum")),
-    pytest.param(_pallas_seg_agg, id="seg_agg_1d", marks=pytest.mark.xfail(
-        strict=True, reason="Unimplemented primitive in Pallas TPU lowering "
-        "for KernelType.TC: dynamic_slice")),
-    pytest.param(_pallas_bitonic, id="bitonic_sort_u64",
-                 marks=pytest.mark.xfail(
-                     strict=True, reason="Unimplemented primitive in Pallas "
-                     "TPU lowering for KernelType.TC: rev")),
-])
-def test_pallas_kernel_lowers_for_v5e(one_chip, no_persistent_cache, lower):
-    """The three Pallas kernels have only ever run with interpret=True: the
-    installed Pallas TPU lowering refuses each before Mosaic is reached.
-    Strict xfail: the day one lowers, the mark has to come off (and
-    spark.rapids.sql.tpu.pallas.enabled means something on a chip)."""
-    lower(one_chip).compile()

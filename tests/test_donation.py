@@ -16,8 +16,7 @@ owns".  Coverage:
   * donation through the exchange-bucketing fused program and the
     aggregate whole-stage absorption.
 
-Runs in the `pallas` ci.sh tier next to the interpret-mode kernel tests
-(the donation parity sweep half of that tier).
+Runs in the `donation` ci.sh tier.
 """
 from __future__ import annotations
 
@@ -35,7 +34,7 @@ from spark_rapids_tpu.utils import faults
 from compare import assert_rows_equal
 from data_gen import gen_table
 
-pytestmark = pytest.mark.pallas
+pytestmark = pytest.mark.donation
 
 # donation needs the memory-scan cache OFF to fire on in-memory scans
 # (cached batches are pinned — re-served to later queries by design)

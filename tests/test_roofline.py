@@ -520,21 +520,25 @@ def test_session_observability_carries_slo_block():
 # --------------------------------------------------------------------------
 
 def test_profiler_overhead_under_generous_ceiling():
-    def measure(extra):
-        s = _session(extra)
-        df = s.from_arrow(_TABLE)
+    def warmed(extra):
+        df = _session(extra).from_arrow(_TABLE)
         _q1(df).collect()  # warm: compiles + scan cache
-        runs = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            _q1(df).collect()
-            runs.append(time.perf_counter() - t0)
-        return min(runs)
+        return df
 
-    off = measure({"spark.rapids.sql.tpu.roofline.costAccounting"
-                   ".enabled": "false",
-                   "spark.rapids.sql.tpu.roofline.enabled": "false"})
-    on = measure({})
+    def timed(df):
+        t0 = time.perf_counter()
+        _q1(df).collect()
+        return time.perf_counter() - t0
+
+    df_off = warmed({"spark.rapids.sql.tpu.roofline.costAccounting"
+                     ".enabled": "false",
+                     "spark.rapids.sql.tpu.roofline.enabled": "false"})
+    df_on = warmed({})
+    # alternate the two sides and keep each side's quietest run: a burst
+    # of load on the host (six workers share it) then falls on both, not
+    # on whichever side happened to be measured during it
+    pairs = [(timed(df_off), timed(df_on)) for _ in range(10)]
+    off, on = (min(side) for side in zip(*pairs))
     overhead = (on - off) / off if off > 0 else 0.0
     # target <2% (BENCH_PROFILE.json records the honest number; this
     # assertion uses a generous ceiling so shared-host jitter cannot
