@@ -156,7 +156,7 @@ class TpuDistributedAggregateExec(TpuHashAggregateExec):
 
 class TpuDistributedJoinExec(TpuHashJoinExec):
     """SPMD hash join: both sides hash-partitioned by join key over the mesh
-    in one all-to-all, local sort+searchsorted join per device."""
+    in one all-to-all, local sort+merge join per device."""
 
     def __init__(self, left, right, join_type, left_keys, right_keys,
                  condition, out_schema, using_drop, mesh,

@@ -279,6 +279,13 @@ NUM_PACKED_SORTS = register_metric(
     "sort dispatches that took the packed-key path (sort keys fused "
     "into 64-bit words + embedded row ids, single-operand sort passes) "
     "instead of the N-pass variadic lexsort")
+JOIN_MERGED_WINDOW_BATCHES = register_metric(
+    "joinMergedWindowBatches", COUNTER, ESSENTIAL,
+    "stream batches (mesh: stream chunks) whose candidate windows in the "
+    "hash-sorted build side came from one merge of both sides' hashes "
+    "(utils/packed_sort.merge_windows: three single-operand sorts and two "
+    "prefix scans, no gather) and not from a binary search per row; a "
+    "host integer, never a sync")
 AGG_DENSE_BATCHES = register_metric(
     "aggDenseBatches", COUNTER, ESSENTIAL,
     "input batches whose grouped-aggregate bucket update finished in one "

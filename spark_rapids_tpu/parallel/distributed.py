@@ -33,6 +33,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
 from ..columnar import Column, ColumnarBatch
+from ..metrics import names as MN
 from ..ops.hashing import hash_columns_double
 from ..utils import pow2_bucket
 from .mesh import DATA_AXIS
@@ -496,7 +497,7 @@ def distributed_join_step(join, mesh: Mesh, max_dup: int, out_cap: int,
                           axis: str = DATA_AXIS,
                           use_allgather: bool = False):
     """SPMD hash join: hash-partition both sides by join key, local
-    sort+searchsorted join per device (the reference pairs
+    sort+merge join per device (the reference pairs
     GpuShuffleExchangeExec with GpuShuffledHashJoinExec the same way;
     GpuShuffledHashJoinExec.scala:83-87).
 
@@ -588,6 +589,7 @@ def run_distributed_join(join, mesh: Mesh, left: ColumnarBatch,
             out_cap = out_cap * 2
             retry = True
         if not retry:
+            join.metrics.add(MN.JOIN_MERGED_WINDOW_BATCHES, 1)
             return out
 
 
@@ -718,6 +720,7 @@ def run_distributed_join_streaming(join, mesh: Mesh, left_chunks,
                 retry = True
             if not retry:
                 break
+        join.metrics.add(MN.JOIN_MERGED_WINDOW_BATCHES, 1)
         yield out
 
 

@@ -1,5 +1,7 @@
 """Stable argsort by integer key components: the one way this package
-sorts.
+sorts; and `merge_windows`, the one way it ranks one batch's hashes among
+another's sorted hashes (the join's candidate windows), made of the same
+single-operand sort.
 
 XLA:CPU (and the TPU sort HLO) pay a steep premium for VARIADIC sorts:
 on the build host a single-operand 2M-row u64 sort runs ~180 ms while
@@ -8,6 +10,14 @@ the same rows through a 2-operand key/value sort cost ~1060 ms and a
 defeats the specialized single-key path.  `jnp.lexsort`/`jnp.argsort`
 are ALWAYS variadic (they append an iota operand), and on the v5e a
 variadic sort with an f64 comparator compiled for nine minutes (PR 22).
+On that chip (`_chip/window_cost.py`, PR 30) a single-operand sort of 2M
+uint64 words takes 3.8 ms (1M: 2.1 ms, 2M uint32: 2.2 ms), a prefix scan
+of 2M int32 1.0 to 1.4 ms, a 1M-row gather through a random index 8.3 ms
+a uint32 and 17.4 ms a uint64, a 1M-row scatter through a permutation 5.6
+ms: a sort costs less than half a gather, so whatever can be phrased as
+sort-and-scan is, and a chain of dependent gathers (a binary search: 21
+steps of them over 1M rows, 1.05 s for both sides of a window) is the
+form to avoid.
 
 `stable_argsort` is the entry.  For a power-of-two capacity (every
 capacity `columnar/batch.py bucket_rows` makes) it sorts with
@@ -133,3 +143,56 @@ def stable_argsort(components: Sequence[Tuple[jnp.ndarray, int]],
         step = (s & mask_r).astype(jnp.int32)
         perm = step if perm is None else jnp.take(perm, step)
     return perm
+
+
+def merge_windows(h_sorted, h_query, live):
+    """Candidate windows of `h_query` in `h_sorted` from ONE merge:
+    -> (lo, hi, max_width), int32 `[lo[i], hi[i])` holding every j with
+    `h_sorted[j] == h_query[i]`, and the widest window of a `live` query.
+
+    `h_sorted` is ascending uint64 (cap_b), `h_query` any uint64 (cap_l).
+    Both concatenate, build first, to `2^r` elements; one single-operand
+    sort orders the words `top (64 - r) hash bits | r-bit position id`,
+    so inside a run of equal prefixes the build elements come first.  In
+    that order `hi` of a query is the running count of build elements and
+    `lo` that count at the start of its run (both monotone: two prefix
+    scans).  Two more single-operand sorts, of `query index | lo` and
+    `query index | hi`, bring both back to the queries' own order: three
+    sorts and two scans whatever cap_b is, and no gather, where a binary
+    search chains `2 log2(cap_b)` dependent gathers a side (the header
+    has what each costs on the chip).
+
+    The window is over the kept PREFIX, a superset of the equal-hash
+    window (equal to it unless two different hashes share their top
+    `64 - r` bits); callers verify candidates by key, so a wider window
+    is a cost and never a wrongness."""
+    cap_b, cap_l = h_sorted.shape[0], h_query.shape[0]
+    total = cap_b + cap_l
+    r = max(1, (total - 1).bit_length())
+    n = 1 << r
+    mask_r = _mask(r)
+    ids = jnp.arange(n, dtype=jnp.uint64)
+    # padding sorts last: the all-ones prefix under the highest ids
+    h = jnp.concatenate([h_sorted, h_query,
+                         jnp.full(n - total, _mask(64), jnp.uint64)])
+    s = jax.lax.sort((h & ~mask_r) | ids, dimension=0, is_stable=False)
+    sid = s & mask_r
+    prefix = s >> _u64(r)
+    is_build = (sid < _u64(cap_b)).astype(jnp.int32)
+    hi_all = jnp.cumsum(is_build)
+    run_start = jnp.concatenate(
+        [jnp.ones(1, jnp.bool_), prefix[1:] != prefix[:-1]])
+    lo_all = jax.lax.cummax(jnp.where(run_start, hi_all - is_build, 0))
+    # back to query order: a query's word is its own index above the
+    # value (at most cap_b < 2^r), everything else sorts behind them
+    is_query = (sid >= _u64(cap_b)) & (sid < _u64(total))
+    slot = (sid - _u64(cap_b)) << _u64(r)
+
+    def in_query_order(vals):
+        words = jnp.where(is_query, slot | vals.astype(jnp.uint64),
+                          _mask(64))
+        back = jax.lax.sort(words, dimension=0, is_stable=False)[:cap_l]
+        return (back & mask_r).astype(jnp.int32)
+
+    lo, hi = in_query_order(lo_all), in_query_order(hi_all)
+    return lo, hi, jnp.max(jnp.where(live, hi - lo, 0))
