@@ -15,11 +15,14 @@ from __future__ import annotations
 import threading
 from typing import Iterator, Optional
 
+import numpy as np
+
 from ..columnar import ColumnarBatch, concat_batches
 from ..mem.buffer import SpillPriorities, batch_to_host, host_to_batch
 from .base import CpuExec, ExecContext, ExecNode, TpuExec, record_cost
 from .join import TpuHashJoinExec
 from ..metrics import names as MN
+from ..utils.tracing import named_range
 
 
 class TpuBroadcastExchangeExec(TpuExec):
@@ -41,17 +44,23 @@ class TpuBroadcastExchangeExec(TpuExec):
     def _collect(self, ctx: ExecContext):
         """The async driver job of the reference (collect + serialize),
         run once (GpuBroadcastExchangeExec.scala:215-391)."""
-        with self.metrics.timer(MN.COLLECT_TIME):
-            batches = list(self.children[0].execute(ctx))
-        with self.metrics.timer(MN.BUILD_TIME):
-            if batches:
-                batch = batches[0] if len(batches) == 1 \
-                    else concat_batches(batches)
-            else:
-                from .join import _empty_batch
-                batch = _empty_batch(self.schema)
-            leaves, meta = batch_to_host(batch)
+        with named_range("broadcast_collect"):
+            with self.metrics.timer(MN.COLLECT_TIME):
+                batches = list(self.children[0].execute(ctx))
+            with self.metrics.timer(MN.BUILD_TIME):
+                if batches:
+                    batch = batches[0] if len(batches) == 1 \
+                        else concat_batches(batches)
+                else:
+                    from .join import _empty_batch
+                    batch = _empty_batch(self.schema)
+                leaves, meta = batch_to_host(batch)
         self.metrics.add(MN.DATA_SIZE, meta.size_bytes)
+        # what the exchange ships, both ways, and how much of it is alive:
+        # the batch travels at its capacity; the selection is the last
+        # leaf and is on the host already
+        self.metrics.add(MN.BROADCAST_BYTES, meta.size_bytes)
+        self.metrics.add(MN.BROADCAST_ROWS, int(np.count_nonzero(leaves[-1])))
         # roofline: the broadcast payload left the device (d2h) and is
         # re-published to every executor over the wire
         record_cost(self.metrics, d2h=meta.size_bytes,
@@ -74,17 +83,18 @@ class TpuBroadcastExchangeExec(TpuExec):
             if self._host_form is None:
                 self._host_form = self._collect(ctx)
             leaves, meta = self._host_form
-            if ctx.runtime is not None:
-                if self._buffer_id is not None:
-                    try:
-                        return ctx.runtime.get_batch(self._buffer_id)
-                    except KeyError:
-                        self._buffer_id = None
+            runtime = ctx.runtime
+            if runtime is not None and self._buffer_id is not None:
+                try:
+                    return runtime.get_batch(self._buffer_id)
+                except KeyError:
+                    self._buffer_id = None
+            with named_range("broadcast_upload", bytes=meta.size_bytes):
                 batch = host_to_batch(leaves, meta)
-                self._buffer_id = ctx.runtime.add_batch(
+            if runtime is not None:
+                self._buffer_id = runtime.add_batch(
                     batch, SpillPriorities.ACTIVE_ON_DECK_PRIORITY)
-                return batch
-            return host_to_batch(leaves, meta)
+            return batch
 
     def execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         yield self.broadcast_batch(ctx)

@@ -368,11 +368,18 @@ class TpuHashJoinExec(TpuExec):
                 else concat_batches(rbatches)
             # filtered build sides ride their input capacity otherwise —
             # the build sort and every probe window pay for dead rows
-            rbatch = rbatch.maybe_shrink(rbatch.num_rows_host())
+            rbatch = rbatch.maybe_shrink(self._live_rows_host(rbatch))
         else:
             rbatch = _empty_batch(self.children[1].schema)
         yield from self._join_stream(rbatch, self.children[0].execute(ctx),
                                      ctx)
+
+    def _live_rows_host(self, batch: ColumnarBatch) -> int:
+        """`batch.num_rows_host()`, counted in joinHostSyncs where it has
+        to read the device for it."""
+        if batch.known_rows is None:
+            self.metrics.add(MN.JOIN_HOST_SYNCS, 1)
+        return batch.num_rows_host()
 
     def _join_stream(self, rbatch: ColumnarBatch, lbatches, ctx=None):
         """Build once from `rbatch`, stream left batches through the probe
@@ -434,6 +441,7 @@ class TpuHashJoinExec(TpuExec):
             lo, hi, counts, starts, scalars_t = probe_fn(
                 lb, build, bkeys, h1s)
             self.metrics.add(MN.JOIN_MERGED_WINDOW_BATCHES, 1)
+            self.metrics.add(MN.JOIN_HOST_SYNCS, 1)
             md, total = (int(x) for x in np.asarray(scalars_t))
             max_dup = _pow2_bucket(md)
             self._dup_guess = max_dup
@@ -446,6 +454,7 @@ class TpuHashJoinExec(TpuExec):
                                               max_dup))
                 counts, starts, total_t = count_fn(lb, build,
                                                    bkeys, lo, hi)
+                self.metrics.add(MN.JOIN_HOST_SYNCS, 1)
                 total = int(total_t)
             else:
                 max_dup = guess  # counts were computed at the guess
@@ -493,7 +502,7 @@ class TpuHashJoinExec(TpuExec):
                 b_hit_accum = jnp.zeros(build.capacity, jnp.bool_)
             with named_range("join_full_tail", self.metrics, MN.JOIN_TIME):
                 tail = self._full_remainder(build, b_hit_accum)
-            n = tail.num_rows_host()
+            n = self._live_rows_host(tail)
             if n:
                 self.metrics.add(MN.NUM_OUTPUT_BATCHES, 1)
                 self.metrics.add(MN.NUM_OUTPUT_ROWS, n)
@@ -545,7 +554,7 @@ class TpuShuffledHashJoinExec(TpuHashJoinExec):
                     continue
                 tail = self._full_remainder(
                     rbatch, jnp.zeros(rbatch.capacity, jnp.bool_))
-                n = tail.num_rows_host()
+                n = self._live_rows_host(tail)
                 if n:
                     produced = True
                     self.metrics.add(MN.NUM_OUTPUT_BATCHES, 1)

@@ -376,6 +376,16 @@ def monitoring_doc() -> str:
         "`spark.rapids.sql.batchSizeBytes`; `shapes`; `names`) and after "
         "how many `batches` the probe knew; `aggStreamedBatches` counts "
         "the batches the loop then takes",
+        "`srt:broadcast_collect` | `exec/broadcast.py "
+        "TpuBroadcastExchangeExec._collect`, once an exchange (the plan "
+        "is made anew for every query, so once a query and build side) | "
+        "the build side's child executed, its batches concatenated, "
+        "`batch_to_host`: the wait for the device and the copy of the "
+        "batch AT ITS CAPACITY to the host (timers `collectTime`, "
+        "`buildTime`; counters `broadcastBytes`, `broadcastRows`)",
+        "`srt:broadcast_upload` | `TpuBroadcastExchangeExec"
+        ".broadcast_batch` | `host_to_batch`: the host form back to the "
+        "device for the join (`bytes=` in the annotation)",
         "`srt:<operator>` | `exec/` | `srt:agg_whole_stage`, "
         "`srt:agg_whole_stage_bucket`, `srt:agg_update`, `srt:agg_merge`, "
         "`srt:whole_stage_<id>`, `srt:join_build`, `srt:join_stream`, "

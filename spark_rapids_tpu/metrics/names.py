@@ -286,6 +286,26 @@ JOIN_MERGED_WINDOW_BATCHES = register_metric(
     "(utils/packed_sort.merge_windows: three single-operand sorts and two "
     "prefix scans, no gather) and not from a binary search per row; a "
     "host integer, never a sync")
+JOIN_HOST_SYNCS = register_metric(
+    "joinHostSyncs", COUNTER, ESSENTIAL,
+    "host reads of a device value the join made (exec/join.py): the "
+    "probe's two scalars a stream batch (the duplication bucket and the "
+    "output row count), a recount's total where the bucket grew, the "
+    "build side's live-row count before its shrink, a full join's tail "
+    "count; the join's twin of aggHostSyncs; a host integer counted where "
+    "the read is made")
+BROADCAST_BYTES = register_metric(
+    "broadcastBytes", COUNTER, ESSENTIAL,
+    "bytes of the host form a broadcast exchange collected "
+    "(TpuBroadcastExchangeExec._collect: the build side's batch at its "
+    "CAPACITY, data, validity and selection, device to host) and uploads "
+    "again for the join; what dataSize gets from the exchange; a host "
+    "integer")
+BROADCAST_ROWS = register_metric(
+    "broadcastRows", COUNTER, ESSENTIAL,
+    "live rows of the host form a broadcast exchange collected, counted "
+    "on the host from the selection it already holds (no device read): "
+    "against broadcastBytes, how much of what was shipped was alive")
 AGG_DENSE_BATCHES = register_metric(
     "aggDenseBatches", COUNTER, ESSENTIAL,
     "input batches whose grouped-aggregate bucket update finished in one "

@@ -202,6 +202,53 @@ def test_a_streamed_aggregate_marks_its_bail_and_spans_each_batch(tmp_path,
         assert moved[MN.AGG_SYNC_FREE_BATCHES] == batches
 
 
+@pytest.mark.parametrize("builds", [1, 2], ids=["one_build", "two_builds"])
+def test_a_broadcast_join_spans_its_collect_and_its_upload(tmp_path, builds):
+    """A build side under `spark.sql.autoBroadcastJoinThreshold`: its
+    exchange collects the child to the host under `srt:broadcast_collect`
+    and hands it back to the device under `srt:broadcast_upload`, once a
+    build side and query (every `collect()` plans anew), both inside
+    `srt:execute` and before the join's own `srt:join_build`."""
+    rng = np.random.default_rng(31)
+    session = TpuSession({})
+    n = 20_000
+    facts = session.from_arrow(pa.table({
+        "a": rng.integers(0, 40, n).astype(np.int64),
+        "b": rng.integers(0, 30, n).astype(np.int64),
+        "v": rng.integers(0, 1000, n).astype(np.int64)}))
+    dims = [session.from_arrow(pa.table({
+        key: np.arange(50, dtype=np.int64),
+        key + "_w": np.arange(50, dtype=np.int64) * 3}))
+        for key in ("ka", "kb")[:builds]]
+    df = facts.join(dims[0].filter(col("ka") < 25), on=col("a") == col("ka"))
+    if builds == 2:
+        df = df.join(dims[1], on=col("b") == col("kb"))
+    df = df.agg(F.sum(col("v")).alias("s"))
+    collects, names = phases(traced(df, tmp_path, queries=2))
+    assert [n for n in names if n.startswith("PjitFunction(join.")
+            and n.endswith("_probe)")], names
+    for collect, spans in collects:
+        by = {}
+        for e in spans:
+            by.setdefault(e[2], []).append(e)
+        [execute] = by["srt:execute"]
+        gathers = by["srt:broadcast_collect"]
+        uploads = by["srt:broadcast_upload"]
+        assert len(gathers) == len(uploads) == builds
+        assert all(inside(e, execute) for e in gathers + uploads)
+        joins = by["srt:join_build"]
+        assert len(joins) == builds
+        for gather, upload, build in zip(gathers, uploads, joins):
+            assert gather[1] <= upload[0] and upload[1] <= build[0]
+            # the host form's bytes ride in the annotation: at capacity
+            assert int(upload[3]["bytes"]) >= 64 * (2 * 9 + 1)
+    moved = session.last_execution.aggregate()
+    assert moved[MN.BROADCAST_ROWS] == (25 + 50 if builds == 2 else 25)
+    assert moved[MN.BROADCAST_BYTES] == moved[MN.DATA_SIZE] > 0
+    # per build side its live-row count, per join the probe's scalars
+    assert moved[MN.JOIN_HOST_SYNCS] == 2 * builds
+
+
 def test_named_range_is_a_span_and_a_timer_and_never_a_sync():
     from spark_rapids_tpu.metrics.registry import DEVICE_SYNCS, Metrics
     m = Metrics()
