@@ -415,35 +415,6 @@ def _plain_decode_bool(raw: bytes, n_values: int, cap: int):
     return fn(host)
 
 
-def _bitpacked_unpack(buf: bytes, bit_width: int, count: int, cap: int):
-    """k-bit packed ints -> int32 [cap] on device (bw <= 24: each value's
-    bits live in <= 4 consecutive bytes)."""
-    if bit_width > 24:
-        raise DeviceDecodeUnsupported(f"index bit width {bit_width}")
-    nbytes = (cap * bit_width + 7) // 8 + 4
-    host = _pad_bytes(buf, nbytes)
-
-    def build():
-        def k(u8):
-            i = jnp.arange(cap, dtype=jnp.int32)
-            bitpos = i * bit_width
-            b0 = bitpos >> 3
-            sh = (bitpos & 7).astype(jnp.uint32)
-            w = (jnp.take(u8, b0, mode="clip").astype(jnp.uint32)
-                 | (jnp.take(u8, b0 + 1, mode="clip").astype(jnp.uint32)
-                    << 8)
-                 | (jnp.take(u8, b0 + 2, mode="clip").astype(jnp.uint32)
-                    << 16)
-                 | (jnp.take(u8, b0 + 3, mode="clip").astype(jnp.uint32)
-                    << 24))
-            return ((w >> sh) & jnp.uint32((1 << bit_width) - 1)).astype(
-                jnp.int32)
-        return k
-
-    fn = cached_kernel(("pq_bp", bit_width, cap), build)
-    return fn(host)
-
-
 def _single_bp_runs(value_pieces):
     """When EVERY piece is a dictionary page whose index stream is one
     bit-packed run (the standard writer layout), return
@@ -593,26 +564,22 @@ def _indices_decode_host(payload: bytes, n_values: int,
             vo += count
 
 
-def _indices_decode(payload: bytes, n_values: int, cap: int):
-    """Dictionary-index stream: [1B bit width][hybrid runs] -> int32[cap].
-
-    Single bit-packed run (the common writer output for a full page):
-    device unpack kernel.  Multi-segment streams (alternating short runs)
-    materialize on the host instead — per-segment device kernels would be
-    O(segments * capacity), and the run structure is already host-parsed."""
-    if not payload:
-        raise DeviceDecodeUnsupported("empty index page")
-    bw = payload[0]
-    if bw == 0:
-        return jnp.zeros(cap, dtype=jnp.int32)
-    segs = _rle_segments(payload[1:], bw, n_values)
-    if len(segs) == 1 and segs[0][0] == "bp" and bw <= 24:
-        _, count, bo, blen = segs[0]
-        return _bitpacked_unpack(payload[1 + bo:1 + bo + blen], bw, count,
-                                 cap)
-    host = np.zeros(cap, dtype=np.int32)
-    _indices_decode_host(payload, n_values, host, 0)
-    return jnp.asarray(host)
+def _chunk_dict_indices(value_pieces, vcap: int):
+    """Every dictionary page of a chunk -> ONE compact int32[vcap] index
+    array on the device, for numbers and strings alike.  Uniform single
+    bit-packed runs unpack on the DEVICE in one dispatch; mixed
+    RLE/bit-packed runs (the common pyarrow layout for low-cardinality
+    columns) expand on the host page after page into one array (control
+    plane on host, like the CSV tokenizer) and ship in one H2D."""
+    runs = _single_bp_runs(value_pieces)
+    if runs:
+        return _dict_indices_batched(runs, vcap)
+    host_idx = np.zeros(vcap, np.int32)
+    off = 0
+    for (_k, payload, nonnull) in value_pieces:
+        _indices_decode_host(payload, nonnull, host_idx, off)
+        off += nonnull
+    return jnp.asarray(host_idx)
 
 
 # --------------------------------------------------------------------------
@@ -941,8 +908,12 @@ def _decompress(codec: str, payload: bytes, uncompressed_size: int) -> bytes:
 
 
 def decode_column_chunk(path: str, col_meta, phys: str, dtype: DataType,
-                        num_rows: int, max_def: int, cap: int) -> Column:
+                        num_rows: int, max_def: int, cap: int,
+                        counts: Optional[dict] = None) -> Column:
     """One row-group column chunk -> device Column with `cap` capacity.
+    `counts`, where given, gets `page_copies` raised by the `_copy_range`
+    calls the assembly made for a page or a page group (the caller's own
+    dict: the decode runs on the column pool).
 
     Raises DeviceDecodeUnsupported for any page shape outside scope."""
     if phys not in _PHYS_OK:
@@ -967,42 +938,23 @@ def decode_column_chunk(path: str, col_meta, phys: str, dtype: DataType,
         raw = f.read(col_meta.total_compressed_size)
     codec = col_meta.compression
 
-    dict_values = None
-    def_levels: List[np.ndarray] = []
-    value_pieces: List[Tuple] = []   # ("plain"|"dict", payload, n_nonnull)
-
-    def _build_dict(data: bytes, n_dict: int):
-        if phys == "BOOLEAN":
-            raise DeviceDecodeUnsupported("boolean dictionary")
-        if phys == "BYTE_ARRAY":
-            mat, lens = _parse_byte_array_dict(data, n_dict)
-            return jnp.asarray(mat), jnp.asarray(lens)
-        return _plain_decode(data, n_dict, phys, bucket_rows(max(n_dict, 1)))
+    def _assembled(value_pieces, valid_np, dict_raw):
+        col, copies = _assemble_chunk(value_pieces, valid_np, dict_raw,
+                                      phys, dtype, num_rows, cap)
+        if counts is not None:
+            counts["page_copies"] = counts.get("page_copies", 0) + copies
+        return col
 
     from ..native import pq_page_walk
     pages = pq_page_walk(raw, num_rows)
     if pages is not None:
         # native header walk + per-page native level decode + pooled
         # decompression; mirrors the python loop below exactly
-        value_pieces, valid_np, dict_raw = _pages_from_table(
-            raw, pages, codec, num_rows, max_def)
-
-        def get_dict():
-            return _build_dict(*dict_raw) if dict_raw is not None else None
-
-        def get_dict_np():
-            # host assembly wants the NUMPY dictionary — straight from the
-            # decompressed page, never via a device round trip
-            if dict_raw is None or phys not in _PLAIN_NP:
-                return None
-            data_b, n_dict = dict_raw
-            dt = np.dtype(_PLAIN_NP[phys])
-            if len(data_b) < n_dict * dt.itemsize:
-                raise DeviceDecodeUnsupported("truncated dictionary page")
-            return np.frombuffer(data_b, dt, count=n_dict)
-
-        return _assemble_chunk(value_pieces, valid_np, get_dict,
-                               get_dict_np, phys, dtype, num_rows, cap)
+        return _assembled(*_pages_from_table(raw, pages, codec, num_rows,
+                                             max_def))
+    dict_raw = None   # (decompressed dictionary page, its value count)
+    def_levels: List[np.ndarray] = []
+    value_pieces: List[Tuple] = []   # ("plain"|"dict", payload, n_nonnull)
     pos = 0
     rows_seen = 0
     while rows_seen < num_rows and pos < len(raw):
@@ -1013,8 +965,8 @@ def decode_column_chunk(path: str, col_meta, phys: str, dtype: DataType,
         if ptype == _DICT_PAGE:
             info = header["dict"] or {}
             n_dict = info.get(1, 0)
-            data = _decompress(codec, payload, header["uncompressed_size"])
-            dict_values = _build_dict(data, n_dict)
+            dict_raw = (_decompress(codec, payload,
+                                    header["uncompressed_size"]), n_dict)
             continue
         if ptype == _DATA_PAGE:
             info = header["data_v1"]
@@ -1082,14 +1034,23 @@ def decode_column_chunk(path: str, col_meta, phys: str, dtype: DataType,
 
     valid_np = np.concatenate(def_levels)[:num_rows] if def_levels \
         else np.ones(0, dtype=bool)
-    return _assemble_chunk(
-        value_pieces, valid_np, lambda: dict_values,
-        lambda: (np.asarray(dict_values)
-                 if dict_values is not None and phys in _PLAIN_NP else None),
-        phys, dtype, num_rows, cap)
+    return _assembled(value_pieces, valid_np, dict_raw)
 
 
-def _assemble_numeric_host(value_pieces, valid_np, valid_host, get_dict_np,
+def _dict_numpy(dict_raw, phys: str):
+    """A fixed-width dictionary page as numpy, straight from the
+    decompressed page (host assembly never takes a device round trip);
+    None without a dictionary page or for another physical type."""
+    if dict_raw is None or phys not in _PLAIN_NP:
+        return None
+    data_b, n_dict = dict_raw
+    dt = np.dtype(_PLAIN_NP[phys])
+    if len(data_b) < n_dict * dt.itemsize:
+        raise DeviceDecodeUnsupported("truncated dictionary page")
+    return np.frombuffer(data_b, dt, count=n_dict)
+
+
+def _assemble_numeric_host(value_pieces, valid_np, valid_host, dict_raw,
                            phys, dtype: DataType, num_rows: int, cap: int,
                            vcap: int, total_nonnull: int):
     """CPU-backend numeric assembly entirely in numpy + ONE typed transfer.
@@ -1109,7 +1070,7 @@ def _assemble_numeric_host(value_pieces, valid_np, valid_host, get_dict_np,
     if not kinds <= {"plain", "dict"}:
         return None
     if "dict" in kinds:
-        dict_np = get_dict_np()
+        dict_np = _dict_numpy(dict_raw, phys)
         if dict_np is None:
             raise DeviceDecodeUnsupported("dict page missing")
     np_dt = _PLAIN_NP[phys]
@@ -1138,133 +1099,161 @@ def _assemble_numeric_host(value_pieces, valid_np, valid_host, get_dict_np,
                   jnp.asarray(valid_host), dtype)
 
 
-def _assemble_chunk(value_pieces, valid_np, get_dict, get_dict_np, phys,
-                    dtype: DataType, num_rows: int, cap: int) -> Column:
-    """Page pieces -> device Column: compact non-null values assemble with
-    batched per-kind dispatches, then null-expand to row positions."""
+def _assemble_strings(value_pieces, valid_np, valid_host, dict_raw,
+                      dtype: DataType, num_rows: int, cap: int, vcap: int):
+    """A BYTE_ARRAY chunk's pages -> device string Column in a number of
+    launches that does not depend on the number of pages (the page-wise
+    loop this replaces rewrote the chunk's 1M-row buffers twice a page:
+    606 pages a query cost TPC-H Q1 6.4 of its 7.5 s).  What the chunk
+    holds decides the road:
+
+      every page dictionary-coded (the standard writer layout): ONE
+        chunk-wide index array (`_chunk_dict_indices`) and ONE program
+        that expands it to row positions and gathers the dictionary's
+        bytes and lengths by it;
+      anything else (PLAIN, DELTA_LENGTH_BYTE_ARRAY, or a dictionary
+        prefix with a PLAIN suffix after the writer's dictionary
+        overflowed): every value is an (offset, length) into ONE host
+        payload, the dictionary page being one more stretch of it (it IS
+        a PLAIN byte-array page); nulls expand on the host, where the
+        offsets already are, and ONE `_byte_array_gather` at `cap` lays
+        out the rows.
+
+    Rows that are null or past `num_rows` come out zeroed, length 0."""
+    from ..columnar.column import bucket_strlen
+    if not dtype.is_string:
+        raise DeviceDecodeUnsupported("byte_array into non-string")
+    kinds = {k for (k, _p, _n) in value_pieces}
+    if not kinds <= {"plain", "delta_lba", "dict"}:
+        raise DeviceDecodeUnsupported(f"byte_array via {sorted(kinds)}")
+    if "dict" in kinds and dict_raw is None:
+        raise DeviceDecodeUnsupported("dict page missing")
+    no_nulls = bool(valid_np.all())
+    valid_dev = jnp.asarray(valid_host)
+    # all-null pages hold no values
+    live = [vp for vp in value_pieces if vp[2] > 0]
+
+    if kinds == {"dict"}:
+        dmat, dlens = _parse_byte_array_dict(*dict_raw)
+        n_cap, width = dmat.shape
+        idx = _chunk_dict_indices(live, vcap)
+        # no nulls at full capacity: the compact index IS the row index
+        expand = not (no_nulls and vcap == cap)
+
+        def build_sdict():
+            def k(dm, dl, ix, valid_v):
+                if expand:
+                    vi = jnp.cumsum(valid_v.astype(jnp.int32)) - 1
+                    ix = jnp.take(ix, jnp.clip(vi, 0, vcap - 1),
+                                  mode="clip")
+                data = jnp.take(dm, ix, axis=0, mode="clip")
+                lens = jnp.take(dl, ix, mode="clip")
+                return (jnp.where(valid_v[:, None], data,
+                                  jnp.zeros((), jnp.uint8)),
+                        jnp.where(valid_v, lens, 0))
+            return k
+
+        fn = cached_kernel(("pq_sdict", n_cap, width, vcap, cap, expand),
+                           build_sdict)
+        data, lengths = fn(dmat, dlens, idx, valid_dev)
+        return Column(data, valid_dev, dtype, lengths)
+
+    payloads, offs_l, lens_l = [], [], []
+    base = 0
+    max_len = 1
+    if "dict" in kinds:
+        darr, doffs, dlens = _scan_plain_byte_array(*dict_raw)
+        payloads.append(darr)
+        base = int(darr.size)
+        if dlens.size:
+            max_len = max(max_len, int(dlens.max()))
+    for kind, payload, nonnull in live:
+        if kind == "dict":
+            idx = np.zeros(nonnull, np.int32)
+            _indices_decode_host(payload, nonnull, idx, 0)
+            offs_l.append(np.take(doffs, idx, mode="clip"))
+            lens_l.append(np.take(dlens, idx, mode="clip"))
+            continue
+        if kind == "plain":
+            arr, offs, lens = _scan_plain_byte_array(payload, nonnull)
+        else:
+            # DELTA_LENGTH_BYTE_ARRAY: the lengths are a small
+            # DELTA_BINARY_PACKED block decoded on the host; the byte
+            # payload follows it, so offsets are one cumsum
+            lens, consumed = _delta_lengths_host(payload, nonnull)
+            if (lens < 0).any():
+                raise DeviceDecodeUnsupported("negative string length")
+            offs = np.zeros(nonnull, np.int64)
+            np.cumsum(lens[:-1], out=offs[1:])
+            offs += consumed
+            arr = np.frombuffer(payload, np.uint8)
+            if int(offs[-1] + lens[-1]) > arr.size:
+                raise DeviceDecodeUnsupported(
+                    "truncated delta_length byte payload")
+        payloads.append(arr)
+        offs_l.append(offs + base)
+        lens_l.append(lens)
+        base += int(arr.size)
+    if base >= 1 << 31:
+        raise DeviceDecodeUnsupported("chunk payload past int32 offsets")
+    offs = np.concatenate(offs_l) if offs_l else np.zeros(0, np.int64)
+    lens = np.concatenate(lens_l) if lens_l else np.zeros(0, np.int64)
+    if lens.size:
+        max_len = max(max_len, int(lens.max()))
+    if not no_nulls:
+        row_offs = np.zeros(num_rows, np.int64)
+        row_lens = np.zeros(num_rows, np.int64)
+        row_offs[valid_np] = offs
+        row_lens[valid_np] = lens
+        offs, lens = row_offs, row_lens
+    payload = np.concatenate(payloads) if payloads \
+        else np.zeros(0, np.uint8)
+    data, lengths = _byte_array_gather(payload, offs, lens, cap,
+                                       bucket_strlen(max_len))
+    return Column(data, valid_dev, dtype, lengths)
+
+
+def _assemble_chunk(value_pieces, valid_np, dict_raw, phys,
+                    dtype: DataType, num_rows: int, cap: int):
+    """Page pieces -> (device Column, `_copy_range` calls made): compact
+    non-null values assemble with batched per-kind dispatches, then
+    null-expand to row positions.  `dict_raw` is the decompressed
+    dictionary page and its value count, or None."""
     total_nonnull = int(valid_np.sum())
     vcap = bucket_rows(max(total_nonnull, 1))
     valid_host = np.zeros(cap, dtype=bool)
     valid_host[:num_rows] = valid_np
 
     col = _assemble_numeric_host(value_pieces, valid_np, valid_host,
-                                 get_dict_np, phys, dtype, num_rows, cap,
+                                 dict_raw, phys, dtype, num_rows, cap,
                                  vcap, total_nonnull)
     if col is not None:
-        return col
-    dict_values = get_dict()
-
+        return col, 0
     if phys == "BYTE_ARRAY":
-        if not dtype.is_string:
-            raise DeviceDecodeUnsupported("byte_array into non-string")
-        from ..columnar.column import bucket_strlen
-        # PLAIN pages: host scans the length-prefixed layout into
-        # offsets/lengths (native single pass, the CSV-tokenizer split);
-        # dictionary pages decode via index gather.  Mixed pages (writers
-        # fall back to PLAIN when the dictionary overflows) compose.
-        scans = []
-        max_len = 1
-        for kind, payload, nonnull in value_pieces:
-            if kind == "plain":
-                arr, offs, lens = _scan_plain_byte_array(payload, nonnull)
-                scans.append((arr, offs, lens))
-                if nonnull:
-                    max_len = max(max_len, int(lens[:nonnull].max()))
-            elif kind == "delta_lba":
-                # lengths decode through the DELTA_BINARY_PACKED device
-                # kernel; the byte payload follows the delta block, so
-                # offsets are one host cumsum over the (small) lengths
-                lvals, consumed = _delta_lengths_host(payload, nonnull)
-                lens = lvals.astype(np.int64)
-                if (lens < 0).any():
-                    raise DeviceDecodeUnsupported("negative string length")
-                offs = np.zeros(nonnull, np.int64)
-                if nonnull > 1:
-                    np.cumsum(lens[:-1], out=offs[1:])
-                offs += consumed
-                arr = np.frombuffer(payload, np.uint8)
-                if nonnull and int(offs[-1] + lens[-1]) > arr.size:
-                    raise DeviceDecodeUnsupported(
-                        "truncated delta_length byte payload")
-                scans.append((arr, offs, lens))
-                if nonnull:
-                    max_len = max(max_len, int(lens.max()))
-            elif kind == "dict":
-                if dict_values is None:
-                    raise DeviceDecodeUnsupported("dict page missing")
-                scans.append(None)
-                max_len = max(max_len, int(dict_values[0].shape[1]))
-            else:
-                raise DeviceDecodeUnsupported(f"byte_array via {kind}")
-        width = bucket_strlen(max_len)
-        cmat = jnp.zeros((vcap, width), dtype=jnp.uint8)
-        clen = jnp.zeros(vcap, dtype=jnp.int32)
-        off = 0
-        for (kind, payload, nonnull), scan in zip(value_pieces, scans):
-            if nonnull == 0:
-                continue
-            pcap = bucket_rows(nonnull)
-            if kind == "dict":
-                dmat, dlens = dict_values
-                if int(dmat.shape[1]) < width:
-                    dmat = jnp.pad(dmat,
-                                   ((0, 0), (0, width - dmat.shape[1])))
-                idx = _indices_decode(payload, nonnull, pcap)
-                pmat = jnp.take(dmat, idx, axis=0, mode="clip")
-                plen = jnp.take(dlens, idx, mode="clip").astype(jnp.int32)
-            else:  # plain / delta_lba: (payload, offsets, lengths) gather
-                arr, offs, lens = scan
-                pmat, plen = _byte_array_gather(arr, offs, lens, pcap,
-                                                width)
-            cmat = _copy_range(cmat, pmat, off, nonnull)
-            clen = _copy_range(clen, plen, off, nonnull)
-            off += nonnull
-
-        def build_sexpand():
-            def k(cm, cl, valid_v):
-                vi = jnp.cumsum(valid_v.astype(jnp.int32)) - 1
-                ridx = jnp.clip(vi, 0, cm.shape[0] - 1)
-                data2 = jnp.take(cm, ridx, axis=0, mode="clip")
-                lens2 = jnp.take(cl, ridx, mode="clip")
-                data2 = jnp.where(valid_v[:, None], data2,
-                                  jnp.zeros((), jnp.uint8))
-                lens2 = jnp.where(valid_v, lens2, 0)
-                return data2, lens2
-            return k
-
-        fn = cached_kernel(("pq_sexpand", vcap, cap, width), build_sexpand)
-        data2, lens2 = fn(cmat, clen, valid_host)
-        return Column(data2, jnp.asarray(valid_host), dtype, lens2)
+        return _assemble_strings(value_pieces, valid_np, valid_host,
+                                 dict_raw, dtype, num_rows, cap, vcap), 0
+    if dict_raw is None:
+        dict_values = None
+    elif phys == "BOOLEAN":
+        raise DeviceDecodeUnsupported("boolean dictionary")
+    else:
+        dict_values = _plain_decode(*dict_raw, phys,
+                                    bucket_rows(max(dict_raw[1], 1)))
+    copies = 0
 
     # assemble compact (non-null) value array on device.  The two
     # standard whole-chunk layouts take ONE-dispatch batched paths; mixed
-    # layouts (writer dictionary overflow etc.) keep the per-page loop.
+    # layouts (writer dictionary overflow etc.) go group by group below.
     # all-null pages contribute nothing; dropping them up front keeps
-    # the batched whole-chunk paths eligible (the per-page loop skipped
-    # them row by row)
+    # the batched whole-chunk paths eligible
     value_pieces = [vp for vp in value_pieces if vp[2] > 0]
     kinds = {k for (k, _p, _n) in value_pieces}
-    if kinds == {"dict"} and dict_values is not None \
-            and phys != "BOOLEAN":
-        runs = _single_bp_runs(value_pieces)
-        if runs is not None:
-            # uniform single-run pages: unpack on DEVICE, one dispatch
-            idx = _dict_indices_batched(runs, vcap)
-        else:
-            # mixed RLE/bit-packed runs (the common pyarrow layout for
-            # low-cardinality columns): host-vectorized run expansion
-            # into ONE chunk-wide index array (control plane on host,
-            # like the CSV tokenizer), one H2D
-            host_idx = np.zeros(vcap, np.int32)
-            off = 0
-            for (_k, payload, nonnull) in value_pieces:
-                _indices_decode_host(payload, nonnull, host_idx, off)
-                off += nonnull
-            idx = jnp.asarray(host_idx)
+    if kinds == {"dict"} and dict_values is not None:
+        idx = _chunk_dict_indices(value_pieces, vcap)
         compact = jnp.take(dict_values, idx, mode="clip").astype(
             dtype.jnp_dtype)
         return _expand_to_rows(compact, valid_host, vcap, cap, dtype,
-                               total_nonnull == num_rows)
+                               total_nonnull == num_rows), copies
     if kinds == {"plain"} and phys in ("INT32", "INT64", "FLOAT",
                                        "DOUBLE"):
         width = 4 if phys in ("INT32", "FLOAT") else 8
@@ -1272,7 +1261,7 @@ def _assemble_chunk(value_pieces, valid_np, get_dict, get_dict_np, phys,
         compact = _plain_decode(joined, total_nonnull, phys, vcap).astype(
             dtype.jnp_dtype)
         return _expand_to_rows(compact, valid_host, vcap, cap, dtype,
-                               total_nonnull == num_rows)
+                               total_nonnull == num_rows), copies
     if phys == "BOOLEAN":
         compact = jnp.zeros(vcap, dtype=jnp.bool_)
     else:
@@ -1323,13 +1312,15 @@ def _assemble_chunk(value_pieces, valid_np, get_dict, get_dict_np, phys,
                 else:
                     raise DeviceDecodeUnsupported(f"value kind {kind}")
                 compact = _copy_range(compact, sub, off, n)
+                copies += 1
                 off += n
             continue
         compact = _copy_range(compact, piece, off, gn)
+        copies += 1
         off += gn
 
     return _expand_to_rows(compact, valid_host, vcap, cap, dtype,
-                               total_nonnull == num_rows)
+                           total_nonnull == num_rows), copies
 
 
 def _expand_to_rows(compact, valid_host, vcap: int, cap: int,

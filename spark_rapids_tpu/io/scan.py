@@ -714,11 +714,13 @@ def _device_parquet_files(files, schema, options, conf, metrics, max_rows,
             host_names: List[str] = []
 
             def _decode_field(f):
-                """-> (name, Column | None, 'unsupported'|'error'|None);
-                runs on the column pool — each column's host control
-                plane (header walk, decompress, RLE) is independent."""
+                """-> (name, Column | None, 'unsupported'|'error'|None,
+                page copies); runs on the column pool — each column's
+                host control plane (header walk, decompress, RLE) is
+                independent, and so is its count."""
                 ci = name_to_leaf[f.name]
                 max_def = pf.schema.column(ci).max_definition_level
+                counts = {"page_copies": 0}
                 try:
                     rg_cols = []
                     for rg in chunk:
@@ -727,7 +729,7 @@ def _device_parquet_files(files, schema, options, conf, metrics, max_rows,
                             path, rgm.column(ci),
                             rgm.column(ci).physical_type,
                             f.dtype, rgm.num_rows, max_def,
-                            bucket_rows(max(rgm.num_rows, 1))),
+                            bucket_rows(max(rgm.num_rows, 1)), counts),
                             rgm.num_rows))
                     if len(rg_cols) == 1 \
                             and int(rg_cols[0][0].data.shape[0]) == cap:
@@ -735,7 +737,8 @@ def _device_parquet_files(files, schema, options, conf, metrics, max_rows,
                         # (the common layout: writer row groups ~= reader
                         # chunk budget): the decoded column IS the batch
                         # column — skip the zero-init + range copies
-                        return f.name, rg_cols[0][0], None
+                        return (f.name, rg_cols[0][0], None,
+                                counts["page_copies"])
                     if f.dtype.is_string:
                         width = max(c.max_len for c, _ in rg_cols)
                         rg_cols = [(c.pad_strings_to(width), nr)
@@ -755,15 +758,15 @@ def _device_parquet_files(files, schema, options, conf, metrics, max_rows,
                             lengths = _copy_range(lengths, col.lengths,
                                                   off, nr)
                         off += nr
-                    return f.name, Column(data, valid, f.dtype,
-                                          lengths), None
+                    return (f.name, Column(data, valid, f.dtype, lengths),
+                            None, counts["page_copies"])
                 except DeviceDecodeUnsupported:
-                    return f.name, None, "unsupported"
+                    return f.name, None, "unsupported", 0
                 except Exception:
                     # the hand-rolled page/run parsers must never be able
                     # to fail a query the pyarrow path could read: ANY
                     # other error also falls back, column-granular
-                    return f.name, None, "error"
+                    return f.name, None, "error", 0
 
             fields = [f for f in schema
                       if f.name not in part_names and f.name in name_to_leaf]
@@ -776,11 +779,12 @@ def _device_parquet_files(files, schema, options, conf, metrics, max_rows,
                 results = list(_column_pool().map(_decode_field, fields))
             else:
                 results = [_decode_field(f) for f in fields]
-            for name, colv, err in results:
+            for name, colv, err, copies in results:
                 if colv is not None:
                     out_cols[name] = colv
                     if metrics is not None:
                         metrics.add(MN.NUM_DEVICE_DECODED_COLUMNS, 1)
+                        metrics.add(MN.SCAN_PAGE_COPIES, copies)
                 else:
                     if err == "error" and metrics is not None:
                         metrics.add(MN.NUM_DEVICE_DECODE_ERRORS, 1)

@@ -397,7 +397,7 @@ def monitoring_doc() -> str:
         "`stage_executable`, the SPMD steps) and is named",
         "`<layer>.<role>`: the trace's `XLA Modules` line and JAX's",
         "`PjitFunction(...)` host spans read `jit_agg.whole_stage`,",
-        "`jit_scan.pq_bp`, `jit_join.hashjoin_probe`,",
+        "`jit_scan.pq_sdict`, `jit_join.hashjoin_probe`,",
         "`jit_dist.join_probe`, `jit_mem.contig_pack`,",
         "`jit_stage.wholeStage-3`.  `<layer>` comes from the module the",
         "program's builder lives in (`kernel_cache._LAYER_OF_MODULE`: `scan`,",

@@ -163,6 +163,15 @@ NUM_ROW_GROUPS_SKIPPED = register_metric(
 NUM_DEVICE_DECODED_COLUMNS = register_metric(
     "numDeviceDecodedColumns", COUNTER, MODERATE,
     "columns decoded by device kernels (vs host fallback)")
+SCAN_PAGE_COPIES = register_metric(
+    "scanPageCopies", COUNTER, MODERATE,
+    "masked range copies over a whole chunk buffer (`jit_scan.pq_copy_*`) "
+    "the Parquet device decode made to place ONE page or ONE group of "
+    "pages: 0 where every chunk assembled in whole-chunk launches "
+    "(strings always; numbers unless page encodings mix or are "
+    "DELTA_BINARY_PACKED / BYTE_STREAM_SPLIT / boolean PLAIN); grows with "
+    "the file's page count where it is not 0; present and 0 for a scan "
+    "that decoded a column on the device; a host integer")
 NUM_DEVICE_DECODE_ERRORS = register_metric(
     "numDeviceDecodeErrors", COUNTER, MODERATE,
     "columns that fell back to the host reader after a device decode error")

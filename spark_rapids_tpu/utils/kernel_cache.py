@@ -58,7 +58,7 @@ _COUNTERS = {"builds": 0, "stage_compiles": 0, "dispatches": 0,
 # --- program names ----------------------------------------------------------
 # Every executable this module jits is named `<layer>.<role>`, so a
 # profiler trace's `XLA Modules` line and jax's `PjitFunction(...)` host
-# spans read `jit_agg.whole_stage_bucket`, `jit_scan.pq_bp`,
+# spans read `jit_agg.whole_stage_bucket`, `jit_scan.pq_sdict`,
 # `jit_dist.join_probe` instead of the closure's name (`jit_k`).  What
 # stays un-named in a trace is then an eager op outside any compiled
 # program.  THE table: the module a program's builder lives in (relative
@@ -102,7 +102,7 @@ def program_layer(builder) -> str:
 
 
 def program_role(key: tuple) -> str:
-    """The role a cache key states: its string head (`pq_bp`,
+    """The role a cache key states: its string head (`pq_sdict`,
     `contig_pack`, `whole_stage`; an operator's class name is shortened,
     `TpuHashJoinExec` -> `hashjoin`) and, where the call site appended a
     word after the key's last structural (tuple) element, that word

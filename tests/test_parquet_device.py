@@ -400,3 +400,191 @@ def test_native_and_python_page_walks_agree(tmp_path, monkeypatch):
             # between the assembly strategies by design)
             np.testing.assert_array_equal(dn[vn], dp[vp],
                                           err_msg=f"{ci}:{name}")
+
+
+# --- string chunks of MANY pages: whole-chunk launches, no page copies ------
+
+_STRING_LAYOUTS = {
+    # name -> (write conf, distinct values the strings are drawn from)
+    "dict": (dict(use_dictionary=True), 50),
+    "plain": (dict(use_dictionary=False), 10**9),
+    "delta_length": (dict(use_dictionary=False,
+                          column_encoding={"s": "DELTA_LENGTH_BYTE_ARRAY"}),
+                     10**9),
+    # a dictionary that overflows: dictionary pages, then PLAIN ones
+    "dict_then_plain": (dict(use_dictionary=True,
+                             dictionary_pagesize_limit=2048), None),
+}
+
+
+def _many_page_strings(layout, nulls, n=9600, seed=11):
+    """`n` strings with empty ones among them and, with `nulls`, 15% NULLs,
+    a stretch of 700 NULLs and 1,600 more at the chunk's end, from a row
+    where a write batch of 64 or 200 rows begins (a writer closes a page on
+    its value bytes, so only there is a page all NULL whatever the
+    encoding)."""
+    rng = np.random.RandomState(seed)
+    distinct = _STRING_LAYOUTS[layout][1]
+
+    def long_tail(m):
+        return ["u" * int(w) + str(int(x)) for w, x in
+                zip(rng.randint(0, 30, m), rng.randint(0, 10**9, m))]
+    if distinct is None:
+        vals = ([f"k{int(x)}" for x in rng.randint(0, 8, n // 2)]
+                + long_tail(n - n // 2))
+    elif distinct <= 50:
+        vals = [f"v{int(x)}" * (int(x) % 4)   # "" for every 4th value
+                for x in rng.randint(0, distinct, n)]
+    else:
+        vals = long_tail(n)
+    vals = ["" if rng.rand() < 0.05 else v for v in vals]
+    if nulls:
+        vals = [None if rng.rand() < 0.15 else v for v in vals]
+        vals[1000:1700] = [None] * 700
+        vals[n - 1600:] = [None] * 1600
+    return vals
+
+
+def _spy_assembly(monkeypatch):
+    """Record (page kinds in order, non-null counts) of every chunk the
+    device decode assembles."""
+    from spark_rapids_tpu.io import parquet_device as pd_mod
+    seen = []
+    real = pd_mod._assemble_chunk
+
+    def spy(value_pieces, *a, **kw):
+        seen.append(([k for (k, _p, _n) in value_pieces],
+                     [n for (_k, _p, n) in value_pieces]))
+        return real(value_pieces, *a, **kw)
+    monkeypatch.setattr(pd_mod, "_assemble_chunk", spy)
+    return seen
+
+
+@pytest.mark.parametrize("page_rows", [64, 200])
+@pytest.mark.parametrize("nulls", [False, True], ids=["nonull", "nulls"])
+@pytest.mark.parametrize("layout", list(_STRING_LAYOUTS))
+def test_many_page_string_chunk(tmp_path, monkeypatch, layout, nulls,
+                                page_rows):
+    """A string chunk of at least 32 pages decodes in whole-chunk launches
+    whatever its page layout: same answers as the CPU session's, and
+    `scanPageCopies` does not grow with the page count (0 for a uniform
+    layout, at most 2 for dictionary-then-PLAIN)."""
+    vals = _many_page_strings(layout, nulls)
+    p = str(tmp_path / "t.parquet")
+    pq.write_table(pa.table({"s": pa.array(vals, pa.string())}), p,
+                   compression="snappy", data_page_size=256,
+                   write_batch_size=page_rows, **_STRING_LAYOUTS[layout][0])
+    seen = _spy_assembly(monkeypatch)
+    s = TpuSession()
+    got = [r[0] for r in s.read.parquet(p).collect()]
+    cpu = TpuSession({"spark.rapids.sql.enabled": "false"})
+    want = [r[0] for r in cpu.read.parquet(p).collect()]
+    assert got == want == vals
+
+    [(kinds, nonnulls)] = seen
+    assert len(kinds) >= 32, len(kinds)
+    assert set(kinds) == {
+        "dict": {"dict"}, "plain": {"plain"},
+        "delta_length": {"delta_lba"},
+        "dict_then_plain": {"dict", "plain"}}[layout], set(kinds)
+    assert (0 in nonnulls) == nulls   # the all-NULL page
+    totals = s.query_metrics_total
+    assert totals["numDeviceDecodedColumns"] == 1
+    assert totals.get("numDeviceDecodeErrors", 0) == 0
+    assert "scanPageCopies" in totals   # present even where it is 0
+    assert totals["scanPageCopies"] <= (2 if layout == "dict_then_plain"
+                                        else 0)
+
+
+@pytest.mark.parametrize("layout", list(_STRING_LAYOUTS))
+def test_many_page_string_chunk_python_walk(tmp_path, monkeypatch, layout):
+    """The same chunks through the pure-python page walk, length scan and
+    run decode (no native library): same strings, no page copies."""
+    from spark_rapids_tpu import native
+    vals = _many_page_strings(layout, nulls=True, n=3200)
+    p = str(tmp_path / "t.parquet")
+    pq.write_table(pa.table({"s": pa.array(vals, pa.string())}), p,
+                   compression="snappy", data_page_size=64,
+                   write_batch_size=64, **_STRING_LAYOUTS[layout][0])
+    monkeypatch.setattr(native, "get_lib", lambda: None)
+    s = TpuSession()
+    assert [r[0] for r in s.read.parquet(p).collect()] == vals
+    totals = s.query_metrics_total
+    assert totals["numDeviceDecodedColumns"] == 1
+    assert totals["scanPageCopies"] == 0
+
+
+@pytest.mark.parametrize("page_rows", [50, 100])
+def test_mixed_numeric_chunk_copies_once_a_group(tmp_path, monkeypatch,
+                                                 page_rows):
+    """The numeric branch's count: a dictionary prefix and a PLAIN suffix
+    are two page groups and two range copies whatever the page count (on
+    the CPU backend numbers assemble on the host, so the device branch is
+    asked for here)."""
+    from spark_rapids_tpu.io import parquet_device as pd_mod
+    monkeypatch.setattr(pd_mod, "_assemble_numeric_host",
+                        lambda *a, **kw: None)
+    rng = np.random.RandomState(1)
+    vals = np.concatenate([rng.randint(0, 8, 3000),
+                           rng.randint(0, 2**40, 3000)]).astype(np.int64)
+    p = str(tmp_path / "t.parquet")
+    pq.write_table(pa.table({"x": vals}), p, compression="NONE",
+                   dictionary_pagesize_limit=1024, data_page_size=512,
+                   write_batch_size=page_rows)
+    seen = _spy_assembly(monkeypatch)
+    s = TpuSession()
+    assert [r[0] for r in s.read.parquet(p).collect()] == vals.tolist()
+    [(kinds, _nonnulls)] = seen
+    assert len(kinds) >= 32 and set(kinds) == {"dict", "plain"}
+    assert s.query_metrics_total["scanPageCopies"] == 2
+
+
+@pytest.mark.parametrize("layout", ["dict", "dict_runs", "plain",
+                                    "dict_then_plain"])
+def test_page_sizes_build_no_new_kernel(tmp_path, layout):
+    """Two files of other page counts and page sizes whose chunks bucket
+    to the same shapes: the second builds no kernel (`window_compiles`
+    stays 0 when a scan meets a file written with other page sizes)."""
+    from spark_rapids_tpu.utils import kernel_cache
+    if layout == "dict_runs":
+        # 3 distinct values: RLE and bit-packed runs alternate in a page,
+        # so the index array is made on the host
+        rng = np.random.RandomState(2)
+        vals = [None if rng.rand() < 0.1 else "ANR"[int(x)]
+                for x in rng.randint(0, 3, 9600)]
+        conf = dict(use_dictionary=True)
+    else:
+        vals = _many_page_strings(layout, nulls=True)
+        conf = _STRING_LAYOUTS[layout][0]
+    table = pa.table({"s": pa.array(vals, pa.string())})
+
+    def read(name, page_rows):
+        p = str(tmp_path / name)
+        pq.write_table(table, p, compression="NONE", data_page_size=64,
+                       write_batch_size=page_rows, **conf)
+        pages = len(_data_pages(p))
+        got = [r[0] for r in TpuSession().read.parquet(p).collect()]
+        assert got == vals
+        return pages
+
+    pages_a = read("a.parquet", 100)
+    builds = kernel_cache.stats()["builds"]
+    pages_b = read("b.parquet", 110)
+    assert pages_a != pages_b and min(pages_a, pages_b) >= 32
+    assert kernel_cache.stats()["builds"] == builds
+
+
+def _data_pages(path):
+    """The data pages of row group 0, column 0 (the native page walk)."""
+    from spark_rapids_tpu import native
+    from spark_rapids_tpu.io import parquet_device as pd_mod
+    rgm = pq.ParquetFile(path).metadata.row_group(0)
+    cm = rgm.column(0)
+    start = cm.dictionary_page_offset \
+        if cm.dictionary_page_offset is not None else cm.data_page_offset
+    with open(path, "rb") as f:
+        f.seek(start)
+        raw = f.read(cm.total_compressed_size)
+    pages = native.pq_page_walk(raw, rgm.num_rows)
+    return [t for t in pages["ptype"]
+            if int(t) in (pd_mod._DATA_PAGE, pd_mod._DATA_PAGE_V2)]
