@@ -596,10 +596,28 @@ def q35(t):
             .limit(100))
 
 
-def q36(t):
-    """Gross-margin ROLLUP by category/class with an in-category margin
-    rank (window over a rollup)."""
+def _hierarchy_rank(rolled, measure, ascending):
+    """The tail queries 36 and 86 share, as published: `lochierarchy` from
+    grouping(), the rank of `measure` among the rows of one level under one
+    parent, the order by level, parent and rank, the first 100 rows."""
     from spark_rapids_tpu.plan.logical import Window
+    level = F.grouping("i_category") + F.grouping("i_class")
+    w = Window.partition_by(
+        level, F.when(F.grouping("i_class") == 0, col("i_category"))
+    ).order_by(col(measure).asc() if ascending else col(measure).desc())
+    return (rolled
+            .select(col(measure), col("i_category"), col("i_class"),
+                    level.alias("lochierarchy"),
+                    F.rank().over(w).alias("rank_within_parent"))
+            .order_by(col("lochierarchy").desc(),
+                      F.when(col("lochierarchy") == 0, col("i_category")),
+                      col("rank_within_parent"))
+            .limit(100))
+
+
+def q36(t):
+    """Gross-margin ROLLUP by category/class, ranked within the parent
+    (window over a rollup, partitioned by grouping())."""
     dd = t["date_dim"].filter(col("d_year") == 2001)
     st = t["store"].filter(col("s_state").isin("TN", "SD", "AL", "GA",
                                                "MI", "OH", "TX", "CA"))
@@ -608,16 +626,10 @@ def q36(t):
               .join(t["item"], on=col("ss_item_sk") == col("i_item_sk"))
               .join(st, on=col("ss_store_sk") == col("s_store_sk"))
               .rollup(col("i_category"), col("i_class"))
-              .agg(F.sum(col("ss_net_profit")).alias("profit"),
-                   F.sum(col("ss_ext_sales_price")).alias("sales"))
-              .with_column("gross_margin",
-                           col("profit") / col("sales")))
-    w = Window.partition_by(col("i_category")) \
-        .order_by(col("gross_margin"))
-    return (rolled
-            .with_column("rank_within_parent", F.rank().over(w))
-            .order_by(col("i_category"), col("rank_within_parent"))
-            .limit(100))
+              .agg((F.sum(col("ss_net_profit"))
+                    / F.sum(col("ss_ext_sales_price")))
+                   .alias("gross_margin")))
+    return _hierarchy_rank(rolled, "gross_margin", ascending=True)
 
 
 def q43(t):
@@ -1585,9 +1597,8 @@ def q70(t):
 
 
 def q86(t):
-    """q36's web twin: net-paid ROLLUP by category/class with an
-    in-category rank (ws_ext_sales_price stands in for ws_net_paid)."""
-    from spark_rapids_tpu.plan.logical import Window
+    """q36's web twin: net-paid ROLLUP by category/class, ranked within
+    the parent (ws_ext_sales_price stands in for ws_net_paid)."""
     dd = t["date_dim"].filter(col("d_month_seq").between(24, 35))
     rolled = (t["web_sales"]
               .join(dd, on=col("ws_sold_date_sk") == col("d_date_sk"))
@@ -1595,12 +1606,7 @@ def q86(t):
               .rollup(col("i_category"), col("i_class"))
               .agg(F.sum(col("ws_ext_sales_price"))
                    .alias("total_sum")))
-    w = Window.partition_by(col("i_category")) \
-        .order_by(col("total_sum").desc())
-    return (rolled
-            .with_column("rank_within_parent", F.rank().over(w))
-            .order_by(col("i_category"), col("rank_within_parent"))
-            .limit(100))
+    return _hierarchy_rank(rolled, "total_sum", ascending=False)
 
 
 def q97(t):

@@ -22,6 +22,7 @@ from .exec.base import CpuExec, ExecContext, ExecNode, TpuExec
 from .exec import basic as B
 from .plan import logical as L
 from .plan.logical import ColumnExpr, SortOrder, col, functions, lit
+from .plan.grouping import has_grouping, resolve_grouping
 from .plan.overrides import PlanMeta, plan_schema
 from .plan.physical import convert
 from .plan import transitions as T
@@ -590,16 +591,28 @@ class DataFrame:
 
         rewritten = [extract(e) for e in exprs]
         if not win:
-            return DataFrame(self.session,
-                             L.LogicalProject(exprs, self.plan))
+            return self._resolved(L.LogicalProject(exprs, self.plan))
         groups: dict = {}
         for e in win:
             spec = e.args[1]
             groups.setdefault(spec._group_key(), (spec, []))[1].append(e)
         child = self.plan
-        for _k, (spec, es) in groups.items():
-            child = L.LogicalWindow(es, spec.parts, spec.orders, child)
-        return DataFrame(self.session, L.LogicalProject(rewritten, child))
+        # grouping() in a window's keys, or above it: the rollup's id has
+        # to come up through every window below its last user
+        later = [has_grouping([es, spec.parts, spec.orders])
+                 for spec, es in groups.values()] + [has_grouping(rewritten)]
+        for i, (spec, es) in enumerate(groups.values()):
+            child = resolve_grouping(
+                L.LogicalWindow(es, spec.parts, spec.orders, child),
+                self.session.conf, want_id=any(later[i + 1:]))
+        return self._resolved(L.LogicalProject(rewritten, child))
+
+    def _resolved(self, node) -> "DataFrame":
+        """The frame of `node`, a node just built over this frame's plan,
+        with any grouping() / grouping_id() in it resolved against the
+        rollup or cube below (plan/grouping.py)."""
+        return DataFrame(self.session,
+                         resolve_grouping(node, self.session.conf))
 
     def select(self, *cols) -> "DataFrame":
         return self._project(self._wrap_cols(cols))
@@ -612,8 +625,7 @@ class DataFrame:
     withColumn = with_column
 
     def filter(self, condition: ColumnExpr) -> "DataFrame":
-        return DataFrame(self.session,
-                         L.LogicalFilter(condition, self.plan))
+        return self._resolved(L.LogicalFilter(condition, self.plan))
 
     where = filter
 
@@ -664,7 +676,7 @@ class DataFrame:
                 os.append(SortOrder(col(o)))
             else:
                 os.append(SortOrder(o))
-        return DataFrame(self.session, L.LogicalSort(os, self.plan))
+        return self._resolved(L.LogicalSort(os, self.plan))
 
     orderBy = sort = order_by
 
@@ -823,7 +835,7 @@ class GroupedData:
             else:
                 before = len(leaf_aggs)
                 rewritten = walk(e)
-                if len(leaf_aggs) == before:
+                if len(leaf_aggs) == before and not has_grouping(e):
                     raise ValueError(
                         f"aggregate expression {e!r} contains no aggregate "
                         "function")
@@ -834,14 +846,18 @@ class GroupedData:
         group_keys = list(self.keys)
         if self.rollup:
             child_plan, group_keys = self._expand_rollup(child_plan)
-        agg_plan = L.LogicalAggregate(group_keys, leaf_aggs, child_plan)
+        agg_plan = L.LogicalAggregate(
+            group_keys, leaf_aggs, child_plan,
+            rollup_keys=([k.output_name for k in self.keys]
+                         if self.rollup else None))
         key_cols = [col(k.output_name) for k in self.keys]
         if not compound and not self.rollup:
             return DataFrame(self.df.session, agg_plan)
         if not compound:
             projections = [col(a.output_name) for a in leaf_aggs]
-        # rollup drops the internal grouping-id column here
-        return DataFrame(self.df.session, L.LogicalProject(
+        # rollup drops the internal grouping-id column here, after any
+        # grouping() / grouping_id() among the outputs has read it
+        return self.df._resolved(L.LogicalProject(
             key_cols + projections, agg_plan))
 
     def _expand_rollup(self, child_plan):

@@ -1027,6 +1027,12 @@ class TpuHashAggregateExec(TpuExec):
         if not batches:
             return None, (source, batches, src_iter)
         k = len(batches)
+        if pre_builder is not None:
+            # the absorbed row-local child runs inside this aggregate's
+            # program: its per-batch counters (an Expand's fan-out) are
+            # added here, on its own plan node
+            for b in batches:
+                self.children[0].count_input(b.capacity)
         grouped = bool(self.grouping)
         update = self._update_kernel if grouped else self._global_kernel
         merge = self._merge_kernel
@@ -1154,6 +1160,8 @@ class TpuHashAggregateExec(TpuExec):
                 _donation.record_donated_dispatch(
                     len(donate_leaf_argnums), self.metrics)
             out = fn(pvals, *all_leaves) if pre_params else fn(*all_leaves)
+        if grouped:
+            self.metrics.add(MN.AGG_SORT_PATH_BATCHES, k)
         self.metrics.add(MN.NUM_FUSED_STAGES, 1)
         record_output_batch(self.metrics, out, ctx.runtime)
         return out, None
@@ -1257,7 +1265,11 @@ class TpuHashAggregateExec(TpuExec):
                 # parameter-threaded like RowLocalExec.execute's plain
                 # path, so the replay shares the same compiled kernel
                 child_fn = child.parameterized_kernel()
-                input_iter = (child_fn(b) for b in upstream)
+
+                def replay(b):
+                    child.count_input(b.capacity)
+                    return child_fn(b)
+                input_iter = (replay(b) for b in upstream)
             else:
                 input_iter = upstream
         else:
@@ -1314,6 +1326,7 @@ class TpuHashAggregateExec(TpuExec):
                         hot["bucket_fn"] = None
                         _BUCKET_DIRTY_KEYS.add(key)
                 if partial is None:
+                    self.metrics.add(MN.AGG_SORT_PATH_BATCHES, 1)
                     partial = update(b, jnp.int64(hot["offset"])) \
                         if needs_off else update(b)
             if needs_off:
@@ -1463,6 +1476,8 @@ class TpuHashAggregateExec(TpuExec):
             ctx_checkpoint(ctx, allow_suspend=True)
             self.metrics.add(MN.AGG_STREAMED_BATCHES, 1)
             self.metrics.add(MN.AGG_SYNC_FREE_BATCHES, 1)
+            if pre_builder is not None:
+                self.children[0].count_input(batch.capacity)
             run(batch)
             streamed += 1
         if not streamed:

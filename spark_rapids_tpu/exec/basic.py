@@ -158,6 +158,16 @@ class RowLocalExec(TpuExec):
     def expressions(self) -> List[E.Expression]:
         return []
 
+    def count_input(self, capacity: int, metrics=None) -> int:
+        """Host-side counters for ONE input batch of `capacity` rows that
+        went through `batch_fn`'s program, added to `metrics` (the plan
+        node's whose counters reach the query's totals; this operator's
+        own by default), and the capacity that came out.  Called by
+        whoever launched the program: this operator, the stage it is
+        fused in, or an aggregate that absorbed it.  Shapes only: never a
+        device read."""
+        return capacity
+
     def kernel_key(self) -> tuple:
         """Structural cache key; must fully determine batch_fn's closure."""
         from ..utils.kernel_cache import expr_key
@@ -235,6 +245,7 @@ class RowLocalExec(TpuExec):
                     lambda: functools.partial(E.eval_with_row_offset,
                                               self.batch_fn()))
                 self._record_batch_cost(batch)
+                self.count_input(batch.capacity)
                 with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                     record_dispatch()
                     out = fn(batch, jnp.int64(offset))
@@ -252,6 +263,7 @@ class RowLocalExec(TpuExec):
                 fn = cached_kernel(key + (E.current_input_file(),),
                                    self.batch_fn)
                 self._record_batch_cost(batch)
+                self.count_input(batch.capacity)
                 with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                     record_dispatch()
                     out = fn(batch)
@@ -265,6 +277,7 @@ class RowLocalExec(TpuExec):
         fn = self.parameterized_kernel()
         for batch in self.children[0].execute(ctx):
             self._record_batch_cost(batch)
+            self.count_input(batch.capacity)
             with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                 record_dispatch()
                 out = fn(batch)
@@ -359,6 +372,13 @@ class FusedPipelineExec(RowLocalExec):
         for s in self.stages:
             out.extend(s.expressions())
         return out
+
+    def count_input(self, capacity, metrics=None):
+        # the fused operators are not plan nodes: their counts go on this
+        # node, whose metrics the query's totals are folded from
+        for s in self.stages:
+            capacity = s.count_input(capacity, metrics or self.metrics)
+        return capacity
 
     def kernel_key(self):
         return ("FusedPipelineExec",
@@ -517,6 +537,13 @@ class TpuExpandExec(RowLocalExec):
 
     def expressions(self):
         return [e for proj in self.projections for e in proj]
+
+    def count_input(self, capacity, metrics=None):
+        metrics = metrics or self.metrics
+        out = capacity * len(self.projections)
+        metrics.add(MN.EXPAND_OUTPUT_ROWS, out)
+        metrics.add(MN.EXPAND_BATCHES, 1)
+        return out
 
     def kernel_key(self):
         from ..utils.kernel_cache import schema_key

@@ -234,6 +234,7 @@ class TpuWholeStageExec(FusedPipelineExec):
             ctx_checkpoint(ctx, allow_suspend=True)
             # captured BEFORE the dispatch: a donating executable
             # consumes the batch, so no metadata read may follow it
+            self.count_input(batch.capacity)
             in_bytes = batch.device_size_bytes() if moderate else 0
             in_rows = (batch.known_rows if batch.known_rows is not None
                        else batch.capacity) if moderate else 0

@@ -27,6 +27,7 @@ from .base import (CpuExec, ExecContext, ExecNode, TpuExec,
                    record_output_batch)
 from ..ops.sort_keys import sort_order
 from ..metrics import names as MN
+from ..utils.tracing import named_range
 
 
 class TpuWindowExec(TpuExec):
@@ -119,16 +120,26 @@ class TpuWindowExec(TpuExec):
                 _PrefetchedSource(batches, self.children[0].schema))
             del batches  # the source owns (and drains) the only reference
             for part in ex.execute(ctx):
-                with self.metrics.timer(MN.WINDOW_TIME):
-                    out = fn(part)
+                out = self._run(fn, [part])
                 record_output_batch(self.metrics, out, ctx.runtime)
                 yield out
             return
-        batch = batches[0] if len(batches) == 1 else concat_batches(batches)
-        with self.metrics.timer(MN.WINDOW_TIME):
-            out = fn(batch)
+        out = self._run(fn, batches)
         record_output_batch(self.metrics, out, ctx.runtime)
         yield out
+
+    def _run(self, fn, batches) -> ColumnarBatch:
+        """One launch of the window kernel over `batches` as one batch,
+        inside a `srt:window` span (the coalesce and the launch; the
+        child's own work is outside it) and counted at capacity."""
+        with named_range("window", self.metrics, MN.WINDOW_TIME,
+                         batches=len(batches),
+                         rows=sum(b.capacity for b in batches)):
+            batch = batches[0] if len(batches) == 1 \
+                else concat_batches(batches)
+            self.metrics.add(MN.WINDOW_ROWS, batch.capacity)
+            self.metrics.add(MN.WINDOW_BATCHES, 1)
+            return fn(batch)
 
 
 # --------------------------------------------------------------------------

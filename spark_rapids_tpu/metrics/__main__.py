@@ -386,6 +386,13 @@ def monitoring_doc() -> str:
         "`srt:broadcast_upload` | `TpuBroadcastExchangeExec"
         ".broadcast_batch` | `host_to_batch`: the host form back to the "
         "device for the join (`bytes=` in the annotation)",
+        "`srt:window` | `exec/window.py TpuWindowExec._run`, one a launch "
+        "of the window kernel (one a query unless the external path "
+        "partitions the input), AFTER the child has been drained | the "
+        "coalesce of the drained batches to one and the enqueue of "
+        "`jit_sort.window` (`rows=` at capacity and `batches=` in the "
+        "annotation; timer `windowTime`, counters `windowRows`, "
+        "`windowBatches`)",
         "`srt:<operator>` | `exec/` | `srt:agg_whole_stage`, "
         "`srt:agg_whole_stage_bucket`, `srt:agg_update`, `srt:agg_merge`, "
         "`srt:whole_stage_<id>`, `srt:join_build`, `srt:join_stream`, "

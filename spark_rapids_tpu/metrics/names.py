@@ -115,6 +115,26 @@ MERGE_AGG_TIME = register_metric(
     "mergeAggTime", TIMER, MODERATE, "partial-aggregate merge time")
 WINDOW_TIME = register_metric(
     "windowTime", TIMER, MODERATE, "window function time")
+WINDOW_ROWS = register_metric(
+    "windowRows", COUNTER, ESSENTIAL,
+    "rows AT CAPACITY the window kernel ran over (TpuWindowExec: one sort "
+    "by partition and order keys, the functions as segmented scans, the "
+    "inverse permutation): what its cost follows, live or not; a host "
+    "integer from the batch's shape, never a sync")
+WINDOW_BATCHES = register_metric(
+    "windowBatches", COUNTER, ESSENTIAL,
+    "launches of the window kernel: 1 where the input coalesced to one "
+    "batch, one a hash partition on the external path; a host integer")
+EXPAND_OUTPUT_ROWS = register_metric(
+    "expandOutputRows", COUNTER, ESSENTIAL,
+    "rows AT CAPACITY an Expand (ROLLUP/CUBE fan-out) emitted: its input "
+    "batch's capacity times its projections, summed over the batches; "
+    "added on the host by whoever ran the Expand's program (the operator, "
+    "the whole stage it is fused in, or the aggregate that absorbed that "
+    "stage), on the stage's plan node; never a sync")
+EXPAND_BATCHES = register_metric(
+    "expandBatches", COUNTER, ESSENTIAL,
+    "input batches an Expand fanned out; a host integer")
 GENERATE_TIME = register_metric(
     "generateTime", TIMER, MODERATE, "generator (explode) time")
 COLLECT_TIME = register_metric(
@@ -322,6 +342,16 @@ AGG_DENSE_BATCHES = register_metric(
     "each bucket's representative, nothing scattered or gathered per row; "
     "a batch of more takes further passes); read from the same device "
     "value as the batch's clean check, never a sync of its own")
+AGG_SORT_PATH_BATCHES = register_metric(
+    "aggSortPathBatches", COUNTER, ESSENTIAL,
+    "input batches whose grouped-aggregate update ran the SORT-based "
+    "program (`_update_kernel`: group ids from one sort of the keys, then "
+    "segmented reductions): every batch of a whole-stage program that was "
+    "not the bucket program, and in the streaming loop every batch the "
+    "bucket update did not take (a dirty batch, a latched-dirty shape, an "
+    "aggregate that is not bucketable); with aggDenseBatches, which road "
+    "the batches of a grouped aggregate took; a host integer, never a "
+    "sync")
 AGG_STREAMED_BATCHES = register_metric(
     "aggStreamedBatches", COUNTER, ESSENTIAL,
     "input batches that went through the aggregate's streaming loop (what "

@@ -254,6 +254,20 @@ class functions:
         return functions._agg("Last", e)
 
     @staticmethod
+    def grouping(c):
+        """1 where grouping column `c` of the rollup or cube below is
+        aggregated away in the row (a subtotal's NULL), 0 where the row
+        groups by it (a NULL there is the data's); a byte, as in Spark.
+        Resolved by plan/grouping.py; an analysis error anywhere else."""
+        return ColumnExpr("Grouping", (col(c) if isinstance(c, str) else c,))
+
+    @staticmethod
+    def grouping_id():
+        """The `grouping()` bits of all grouping columns of the rollup or
+        cube below, the first column's the highest; a long."""
+        return ColumnExpr("GroupingID", ())
+
+    @staticmethod
     def when(cond, value):
         return WhenBuilder([(cond, _wrap(value))])
 
@@ -619,10 +633,15 @@ class LogicalFilter(LogicalPlan):
 
 class LogicalAggregate(LogicalPlan):
     def __init__(self, grouping: Sequence[ColumnExpr],
-                 aggregates: Sequence[ColumnExpr], child: LogicalPlan):
+                 aggregates: Sequence[ColumnExpr], child: LogicalPlan,
+                 rollup_keys: Optional[List[str]] = None):
         self.grouping = list(grouping)
         self.aggregates = list(aggregates)
         self.children = (child,)
+        # over a ROLLUP/CUBE Expand: the grouping columns in order, which
+        # `_grouping_id` (the last grouping key) holds one bit each for;
+        # what grouping() / grouping_id() resolve against (plan/grouping.py)
+        self.rollup_keys = rollup_keys
 
 
 class LogicalJoin(LogicalPlan):

@@ -429,7 +429,10 @@ _EXEC_DOC_ROWS = [
     ("SortExec", "order-preserving integer key encoding, one lexsort; "
      "external (partitioned) sort above the in-memory threshold"),
     ("WindowExec", "sort-once segmented-scan windows; external window"),
-    ("ExpandExec", "grouping-set projections"),
+    ("ExpandExec", "grouping-set projections (ROLLUP/CUBE); "
+     "`F.grouping(col)` and `F.grouping_id()` over them are bits of its "
+     "grouping-id column, resolved when the DataFrame is built "
+     "(plan/grouping.py), an analysis error outside a rollup or cube"),
     ("GenerateExec", "explode/posexplode"),
     ("UnionExec", "batch interleave"),
     ("CollectLimitExec", "device head-N"),

@@ -22,7 +22,7 @@ from spark_rapids_tpu import types as T  # noqa: E402
 from spark_rapids_tpu.engine import TpuSession  # noqa: E402
 from spark_rapids_tpu.metrics import names as MN  # noqa: E402
 from spark_rapids_tpu.parallel.distributed import default_quota  # noqa: E402
-from spark_rapids_tpu.plan.logical import col, functions as F  # noqa: E402
+from spark_rapids_tpu.plan.logical import Window, col, functions as F  # noqa: E402
 from spark_rapids_tpu.utils import kernel_cache as KC  # noqa: E402
 from spark_rapids_tpu.utils.tracing import SPAN_PREFIX, named_range  # noqa: E402
 
@@ -247,6 +247,51 @@ def test_a_broadcast_join_spans_its_collect_and_its_upload(tmp_path, builds):
     assert moved[MN.BROADCAST_BYTES] == moved[MN.DATA_SIZE] > 0
     # per build side its live-row count, per join the probe's scalars
     assert moved[MN.JOIN_HOST_SYNCS] == 2 * builds
+
+
+def test_a_window_over_a_rollup_spans_its_launch_and_counts_at_capacity(
+        tmp_path):
+    """Query 36's shape in small: the window kernel's one launch a query
+    sits in a `srt:window` span (opened after the child has been drained,
+    so the aggregate's spans lie before it, not inside), with `rows` at
+    capacity and `batches` in the annotation; the Expand's fan-out, the
+    sort-based update's batches and the window's rows are host counters."""
+    rng = np.random.default_rng(33)
+    session = TpuSession({"spark.rapids.sql.variableFloatAgg.enabled":
+                          "true"})
+    n = 6_000
+    facts = session.from_arrow(pa.table({
+        # 40 x 10 pairs and the subtotals: with 1,024 buckets such a batch
+        # is dirty, so the sort-based whole-stage program answers
+        "a": [f"a{i}" for i in rng.integers(0, 40, n)],
+        "b": [f"b{i}" for i in rng.integers(0, 10, n)],
+        "v": rng.uniform(1, 2, n)}))
+    level = F.grouping("a") + F.grouping("b")
+    w = Window.partition_by(
+        level, F.when(F.grouping("b") == 0, col("a"))).order_by(col("s"))
+    df = (facts.rollup("a", "b").agg(F.sum(col("v")).alias("s"))
+          .select(col("a"), col("b"), col("s"), level.alias("level"),
+                  F.rank().over(w).alias("r")))
+    collects, names = phases(traced(df, tmp_path, queries=2))
+    assert "PjitFunction(sort.window)" in names, names
+    for collect, spans in collects:
+        by = {}
+        for e in spans:
+            by.setdefault(e[2], []).append(e)
+        [execute] = by["srt:execute"]
+        [window] = by["srt:window"]
+        assert inside(window, execute)
+        [agg] = by["srt:agg_whole_stage"]
+        assert agg[1] <= window[0]
+        assert int(window[3]["batches"]) == 1
+        assert int(window[3]["rows"]) == 3 * 8192      # Expand's capacity
+    moved = session.last_execution.aggregate()
+    assert moved[MN.EXPAND_OUTPUT_ROWS] == 3 * 8192
+    assert moved[MN.EXPAND_BATCHES] == 1
+    assert moved[MN.AGG_SORT_PATH_BATCHES] == 1
+    assert moved[MN.WINDOW_ROWS] == 3 * 8192
+    assert moved[MN.WINDOW_BATCHES] == 1
+    assert len(df.collect()) == 40 * 10 + 40 + 1
 
 
 def test_named_range_is_a_span_and_a_timer_and_never_a_sync():
