@@ -270,9 +270,10 @@ AGG_MERGE_FAN_IN = _conf(
     "and host syncs across more input batches.", int)
 AGG_BUCKET_GROUPS = _conf(
     "spark.rapids.sql.tpu.agg.bucketGroups", True,
-    "Low-cardinality grouped-aggregate fast path: rows belong to "
-    "hash buckets and per-bucket states replace the per-batch sort when "
-    "every bucket holds one distinct key (checked exactly per batch; "
+    "Low-cardinality grouped-aggregate fast path: a batch of at most "
+    "1,024 groups is reduced in dense passes of 32 groups, chosen by the "
+    "rows' own 31-bit hash ids, in place of the per-batch sort when "
+    "every id stands for one distinct key (checked exactly per batch; "
     "dirty batches fall back to the sort path).  Applies to "
     "sum/count/avg and non-string min/max without distinct.", _to_bool)
 

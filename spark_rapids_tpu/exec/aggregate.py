@@ -43,8 +43,8 @@ from ..metrics import names as MN
 _I64_MAX = np.int64(2**63 - 1)
 _I64_MIN = np.int64(-(2**63))
 
-# kernel keys whose bucket fast-path probe came back dirty (cardinality
-# above the bucket count): skip the probe for them from then on
+# kernel keys whose bucket update came back dirty (more groups a batch
+# than its state holds): skip it for them from then on
 _BUCKET_DIRTY_KEYS: set = set()
 
 
@@ -548,8 +548,9 @@ class TpuHashAggregateExec(TpuExec):
 
     # ---- low-cardinality bucket fast path ---------------------------------
 
+    # groups a batch's bucket state holds
     _BUCKETS = 1024
-    # occupied buckets one pass of _bucket_update_kernel reduces: the
+    # groups one pass of _bucket_update_kernel reduces: the
     # largest of 8, 16, 32 at which a 1M-row pass on a v5e stays under a
     # tenth of what the scatter form it replaced took (7.0 ms against
     # 980 ms, PERF.md PR 26)
@@ -570,33 +571,50 @@ class TpuHashAggregateExec(TpuExec):
                 return False
         return True
 
+    @staticmethod
+    def _bucket_ids(h1):
+        """int32 group id of a row from its 64-bit key hash: the top 31
+        bits, so an id is never negative.  The one place that says how wide
+        an id is (tests narrow it to force two keys onto one id)."""
+        return (h1 >> jnp.uint64(33)).astype(jnp.int32)
+
     def _bucket_update_kernel(self, batch: ColumnarBatch):
         """-> (took: int32[], state batch at capacity _BUCKETS).
 
-        The sort-free grouped update: every live row belongs to the
-        h1-hash bucket `sid`, and the per-bucket reductions are the
-        partial state IF every occupied bucket holds one distinct group.
-        That is checked EXACTLY per batch (`clean`: each live row's key
-        VALUE-equals its bucket representative's, with Spark key
-        semantics: nulls equal, NaN equal, -0.0 == 0.0).  More distinct
-        groups than buckets forces a collision, so high-cardinality
-        batches fail the check and take the sort path; no cardinality
-        estimate is needed.  The state has the sort path's schema, so
-        the merge/finalize kernels take either.
+        The sort-free grouped update: every live row carries the 31-bit
+        fold `sid` of its keys' h1 hash, and the reductions over the rows
+        of one id are a group's partial state IF every id in the batch
+        stands for one distinct group.  That is checked EXACTLY per batch
+        (`clean`: each live row's key VALUE-equals its id's
+        representative's, with Spark key semantics: nulls equal, NaN
+        equal, -0.0 == 0.0, string length compared).  Two distinct keys
+        share an id once in 2**31 pairs, so a batch is answered here
+        whenever it holds at most _BUCKETS distinct groups; one of more,
+        or one with such a collision, is dirty and takes the sort path.
+        The state has the sort path's schema and capacity _BUCKETS, its
+        groups a dense prefix in id order, so the merge/finalize kernels
+        take either.
 
         Nothing is scattered or gathered per row.  A pass takes the next
-        _DENSE_GROUPS occupied buckets in order (successive masked minima
-        of `sid`), compares each row against those buckets' ids and their
+        _DENSE_GROUPS ids present in the batch in order (successive masked
+        minima of `sid`), compares each row against those ids and their
         representatives' keys (a [G, cap] broadcast compare inside its
         fusion) and makes every aggregate a masked reduce along the rows;
-        a `while_loop` on the device runs as many passes as the batch's
-        own occupancy asks for and stops at the first dirty one.
+        slot g of pass p is state row p * G + g.  A `while_loop` on the
+        device runs as many passes as the batch's own groups ask for, at
+        most _BUCKETS / G, and stops at the first dirty one.  The ids are
+        uniform, so the (G + 1)-th smallest says how many groups the
+        batch holds: where the first pass reads more than four times
+        _BUCKETS from it (1,024 groups read so less than once in 10**9
+        batches) the loop ends there, dirty, and not after every pass
+        the state has room for.
 
-        What the chip showed (v5e, TPC-H Q1, 1M-row batches of 6 groups;
-        PERF.md, PR 26): as `segment_sum/min/max` over `sid` XLA lowers
-        the reductions to serial scatter-adds of 74-80 ms each and each
-        scatter-set of the probe costs 5 ms, a batch 980 ms; one pass
-        here takes the same batch in 7.0 ms.
+        What the chip showed (v5e; PERF.md, PRs 26 and 34): as
+        `segment_sum/min/max` XLA lowers the reductions to serial
+        scatter-adds of 74-80 ms each, a 1M-row batch of TPC-H Q1 980 ms;
+        one pass here takes the same batch in 7.0 ms.  A 786,432-row
+        batch of 171 groups over two string keys and an int: six passes,
+        11.2 ms, where the sort-based update takes 708 ms; a pass 1.3 ms.
 
         `took`: -1 dirty, 1 clean in one pass, 0 clean in more: the one
         integer a caller reads where it read `clean`."""
@@ -607,28 +625,39 @@ class TpuHashAggregateExec(TpuExec):
         live = batch.sel
         cap = batch.capacity
         h1, _h2 = hash_columns_double(keys, live)
-        ids = (h1 & jnp.uint64(B - 1)).astype(jnp.int32)
-        sid = jnp.where(live, ids, B)  # B = trash bucket for dead rows
+        # dead rows carry an id no live row can: a live row that folds to
+        # it is clamped one below (one more possible collision, which
+        # `clean` catches), never dropped
+        dead = jnp.int32(np.iinfo(np.int32).max)
+        sid = jnp.where(live, jnp.minimum(self._bucket_ids(h1), dead - 1),
+                        dead)
+        # ids are uniform over 2**31: with n groups the (G + 1)-th
+        # smallest sits near (G + 1) * 2**31 / n; below this, n > 4 B
+        too_many_below = jnp.int32(((G + 1) << 31) // (4 * B))
         iota = jnp.arange(cap, dtype=jnp.int32)
         agg_fields = self._state_schema.fields[len(keys):]
 
-        def next_bucket(prev, _):
-            nxt = jnp.min(jnp.where(sid > prev, sid, B))
+        def next_id(prev, _):
+            nxt = jnp.min(jnp.where(sid > prev, sid, dead))
             return nxt, nxt
 
         def one_pass(carry):
             prev, _more, passes, clean, occ, rep, state = carry
-            # the next G + 1 occupied buckets in order, B where no more
+            # the next G + 1 ids present, in order, `dead` where no more
             # are: slot g stands for the g-th, the last one only says
             # whether another pass has to follow
-            _, lowest = jax.lax.scan(next_bucket, prev, None, length=G + 1)
-            slot_bucket = lowest[:G]
-            match = sid[None, :] == slot_bucket[:, None]      # [G, cap]
+            _, lowest = jax.lax.scan(next_id, prev, None, length=G + 1)
+            slot_id = lowest[:G]
+            match = sid[None, :] == slot_id[:, None]          # [G, cap]
             rep_row = jnp.max(jnp.where(match, iota[None, :], 0), axis=1)
             eq = jnp.ones((G, cap), jnp.bool_)
             for k in keys:
                 eq &= _key_equal_slots(k, rep_row)
             clean &= jnp.all(jnp.where(match & live[None, :], eq, True))
+            # a batch read as too many groups ends the loop as a dirty
+            # pass does (only the first pass can read so: later ones see
+            # larger ids)
+            clean &= lowest[G] >= too_many_below
 
             def reduce(op, vals, mask, fill):
                 reducer = {"sum": jnp.sum, "min": jnp.min,
@@ -642,20 +671,21 @@ class TpuHashAggregateExec(TpuExec):
                               jnp.int32(0)).astype(jnp.int64)
 
             def put(into, r):
-                # unused slots hold B, which "drop" keeps out of the state
-                return into.at[slot_bucket].set(r, mode="drop")
+                # this pass's G slots, after those of the passes before
+                return jax.lax.dynamic_update_slice(into, r, (passes * G,))
             slots = self._bucket_states(cols, live, count, reduce)
             state = [(put(d, c.data), put(v, c.valid))
                      for (d, v), c in zip(state, slots)]
-            return (slot_bucket[G - 1], lowest[G] < B, passes + 1, clean,
-                    put(occ, slot_bucket < B), put(rep, rep_row), state)
+            # unused slots hold `dead`: the groups found are a dense prefix
+            return (slot_id[G - 1], lowest[G] < dead, passes + 1, clean,
+                    put(occ, slot_id < dead), put(rep, rep_row), state)
 
         def unfinished(carry):
-            _prev, more, _passes, clean, *_ = carry
-            return more & clean
+            _prev, more, passes, clean, *_ = carry
+            return more & clean & (passes < B // G)
 
         empty = jnp.zeros(B, jnp.bool_)
-        _, _, passes, clean, occ, rep, state = jax.lax.while_loop(
+        _, more, passes, clean, occ, rep, state = jax.lax.while_loop(
             unfinished, one_pass,
             (jnp.int32(-1), jnp.bool_(True), jnp.int32(0), jnp.bool_(True),
              empty, jnp.zeros(B, jnp.int32),
@@ -667,15 +697,17 @@ class TpuHashAggregateExec(TpuExec):
         state_cols = [c.with_valid(c.valid & occ).mask_invalid()
                       if not c.dtype.is_string else c
                       for c in key_state + agg_state]
-        took = jnp.where(clean, (passes == 1).astype(jnp.int32),
+        # groups left over after the last pass the state has room for are
+        # as dirty as a collision
+        took = jnp.where(clean & ~more, (passes == 1).astype(jnp.int32),
                          jnp.int32(-1))
         return took, ColumnarBatch(state_cols, occ, self._state_schema)
 
     def _bucket_states(self, cols, live, count, reduce) -> List[Column]:
         """Aggregate state columns of one pass of _bucket_update_kernel,
         a row a slot, over the pass's two reducers: `count(mask)` ->
-        int64 rows of each slot's bucket under mask, `reduce(op, vals,
-        mask, fill)` -> the bucket's sum/min/max of vals under mask
+        int64 rows of each slot's group under mask, `reduce(op, vals,
+        mask, fill)` -> the group's sum/min/max of vals under mask
         (`fill` where none)."""
         state_cols: List[Column] = []
         for a, col in zip(self.aggregates, cols):
@@ -1130,9 +1162,9 @@ class TpuHashAggregateExec(TpuExec):
                 and key not in _BUCKET_DIRTY_KEYS:
             # sort-free program first: per-batch bucket states + an exact
             # all-clean check; only the k*_BUCKETS-row merge sorts.  A
-            # dirty batch (high cardinality / bucket collision) falls
-            # through to the sort-based program below and latches the
-            # key dirty so later executions skip the probe.
+            # dirty batch (more than _BUCKETS groups / two keys of one
+            # id) falls through to the sort-based program below and
+            # latches the key dirty so later executions skip the probe.
             fnb = cached_kernel(key + ("bucket",), build_bucket)
             with named_range("agg_whole_stage_bucket", self.metrics,
                              MN.COMPUTE_AGG_TIME):
@@ -1143,6 +1175,7 @@ class TpuHashAggregateExec(TpuExec):
             n_dense = int(n_dense)  # host sync: dirty, or one-pass batches
             self.metrics.add(MN.AGG_HOST_SYNCS, 1)
             if n_dense >= 0:
+                self.metrics.add(MN.AGG_BUCKET_BATCHES, k)
                 self.metrics.add(MN.AGG_DENSE_BATCHES, n_dense)
                 self.metrics.add(MN.NUM_FUSED_STAGES, 1)
                 record_output_batch(self.metrics, out, ctx.runtime)
@@ -1317,6 +1350,7 @@ class TpuHashAggregateExec(TpuExec):
                     took = int(took)  # host sync: pick the sort-free state
                     self.metrics.add(MN.AGG_HOST_SYNCS, 1)
                     if took >= 0:
+                        self.metrics.add(MN.AGG_BUCKET_BATCHES, 1)
                         self.metrics.add(MN.AGG_DENSE_BATCHES, took)
                         partial = bstate
                     else:
@@ -1333,6 +1367,8 @@ class TpuHashAggregateExec(TpuExec):
                 hot["offset"] += self._live_rows_host(b)
             return partial
 
+        # present and 0 where the bucket update takes every batch
+        self.metrics.add(MN.AGG_SORT_PATH_BATCHES, 0)
         from ..serve.lifecycle import ctx_checkpoint
         for batch in input_iter:
             # stage-boundary lifecycle checkpoint (serve/lifecycle.py):

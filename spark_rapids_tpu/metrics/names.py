@@ -338,20 +338,32 @@ BROADCAST_ROWS = register_metric(
 AGG_DENSE_BATCHES = register_metric(
     "aggDenseBatches", COUNTER, ESSENTIAL,
     "input batches whose grouped-aggregate bucket update finished in one "
-    "dense pass (at most 32 occupied buckets: masked reductions against "
-    "each bucket's representative, nothing scattered or gathered per row; "
-    "a batch of more takes further passes); read from the same device "
-    "value as the batch's clean check, never a sync of its own")
+    "dense pass (at most 32 groups in the batch: masked reductions "
+    "against each group's representative, nothing scattered or gathered "
+    "per row; a batch of more takes further passes and counts in "
+    "aggBucketBatches alone); read from the same device value as the "
+    "batch's clean check, never a sync of its own")
+AGG_BUCKET_BATCHES = register_metric(
+    "aggBucketBatches", COUNTER, ESSENTIAL,
+    "input batches whose grouped-aggregate bucket update was TAKEN, in "
+    "any number of dense passes (the batch held at most 1,024 groups, 32 "
+    "a pass, chosen by the rows' own 31-bit hash ids, and every id stood "
+    "for one distinct key): all batches of a whole-stage bucket program, "
+    "or each streaming-loop batch whose `took` read clean; added on the "
+    "host where that integer is read; with aggSortPathBatches, which "
+    "road the batches of a grouped aggregate took")
 AGG_SORT_PATH_BATCHES = register_metric(
     "aggSortPathBatches", COUNTER, ESSENTIAL,
     "input batches whose grouped-aggregate update ran the SORT-based "
     "program (`_update_kernel`: group ids from one sort of the keys, then "
     "segmented reductions): every batch of a whole-stage program that was "
     "not the bucket program, and in the streaming loop every batch the "
-    "bucket update did not take (a dirty batch, a latched-dirty shape, an "
-    "aggregate that is not bucketable); with aggDenseBatches, which road "
-    "the batches of a grouped aggregate took; a host integer, never a "
-    "sync")
+    "bucket update did not take (a dirty batch: more than 1,024 groups, "
+    "or two keys of one hash id; a latched-dirty shape; an aggregate "
+    "that is not bucketable); the grouped loop adds 0 once, so it reads "
+    "0 and not absent where the bucket update took every batch; with "
+    "aggBucketBatches, which road the batches of a grouped aggregate "
+    "took; a host integer, never a sync")
 AGG_STREAMED_BATCHES = register_metric(
     "aggStreamedBatches", COUNTER, ESSENTIAL,
     "input batches that went through the aggregate's streaming loop (what "

@@ -111,9 +111,15 @@ def test_streaming_loop_against_reference(name, rows, streams_by_default):
     assert moved["aggStreamedBatches"] == 5
     assert moved.get("numFusedStages", 0) == first.get("numFusedStages", 0)
     # the counts repeat exactly, scan or scan-cache hit
-    for counter in ("aggStreamedBatches", "aggHostSyncs", "aggDenseBatches"):
+    for counter in ("aggStreamedBatches", "aggHostSyncs", "aggDenseBatches",
+                    "aggBucketBatches", "aggSortPathBatches"):
         assert moved.get(counter, 0) == first.get(counter, 0), counter
     if name == "q1":
+        # the bucket update takes every batch; the sort path's counter is
+        # there and says 0
+        assert moved["aggBucketBatches"] == 5
+        assert "aggSortPathBatches" in moved
+        assert moved["aggSortPathBatches"] == 0
         # grouped: every batch's live rows are read once (capacity >=
         # 8192), the bucket check once, and every fold reads a count per
         # part: 5 pending parts at the end of the input

@@ -406,42 +406,46 @@ def test_cube_grouping_sets():
     assert (None, None, 100) in rows
 
 
-# ---- the bucket update: dense passes over the occupied buckets -------------
+# ---- the bucket update: dense passes over the batch's own hash ids ----------
 
-def _bucket_of(values, dtype):
-    """Bucket of each key value, as _bucket_update_kernel hashes it."""
+def _narrow_ids(h1):
+    """A 10-bit stand-in for TpuHashAggregateExec._bucket_ids: two keys of
+    one id are then a matter of a few thousand tries, not of 2**31."""
+    import jax.numpy as jnp
+    return (h1 & jnp.uint64(1023)).astype(jnp.int32)
+
+
+def _ids_of(values, dtype, fold=TpuHashAggregateExec._bucket_ids):
+    """Hash id of each key value, as _bucket_update_kernel folds it."""
     import numpy as np
     from spark_rapids_tpu.columnar import ColumnarBatch
     from spark_rapids_tpu.ops.hashing import hash_columns_double
     b = ColumnarBatch.from_pydict(
         {"k": list(values)}, T.Schema([T.StructField("k", dtype)]))
     h1, _ = hash_columns_double([b.column(0)], b.sel)
-    return [int(x) & (TpuHashAggregateExec._BUCKETS - 1)
-            for x in np.asarray(h1)[:len(values)]]
+    return np.asarray(fold(h1))[:len(values)].tolist()
 
 
-def _int_keys_in_distinct_buckets(n):
-    keys, seen = [], set()
-    for v, bk in zip(range(4096), _bucket_of(range(4096), T.LongType)):
-        if bk not in seen:
-            seen.add(bk)
-            keys.append(v)
-        if len(keys) == n:
-            return keys
-    raise AssertionError("not enough buckets")
+def _int_keys_of_distinct_ids(n):
+    ids = _ids_of(range(n), T.LongType)
+    assert len(set(ids)) == n, "two of the first integers share an id"
+    return list(range(n))
 
 
-def _two_int_keys_in_one_bucket():
+def _two_int_keys_of_one_id():
+    """A genuine collision of the 31-bit fold: among 2**18 consecutive
+    integers some 16 pairs are expected."""
     first = {}
-    for v, bk in zip(range(4096), _bucket_of(range(4096), T.LongType)):
-        if bk in first:
-            return first[bk], v
-        first[bk] = v
+    for v, i in enumerate(_ids_of(range(2 ** 18), T.LongType)):
+        if i in first:
+            return first[i], v
+        first[i] = v
     raise AssertionError("no collision")
 
 
 _NAN = float("nan")
 _G = TpuHashAggregateExec._DENSE_GROUPS
+_B = TpuHashAggregateExec._BUCKETS
 _BATCH = 64          # rows a reader batch in these cases
 
 
@@ -458,14 +462,14 @@ def _numeric_aggs(df, tag=""):
                   f.max(col("d")).alias("mxd"))
 
 
-def _rows_for(keys_per_batch, key_dtype=T.LongType):
-    """One batch of _BATCH rows per key list, keys cycling; v and d carry
+def _rows_for(keys_per_batch, key_dtype=T.LongType, batch=_BATCH):
+    """One batch of `batch` rows per key list, keys cycling; v and d carry
     nulls, NaN, +-0.0 and both signs."""
     import random
     rng = random.Random(7)
     k, v, d = [], [], []
     for keys in keys_per_batch:
-        for i in range(_BATCH):
+        for i in range(batch):
             k.append(keys[i % len(keys)])
             v.append(None if i % 11 == 3 else rng.randint(-10**6, 10**6))
             d.append(rng.choice([None, _NAN, 0.0, -0.0])
@@ -483,7 +487,7 @@ def _case_one_group():
 
 
 def _case_exactly_g_groups():
-    keys = _int_keys_in_distinct_buckets(_G)
+    keys = _int_keys_of_distinct_ids(_G)
     data, schema = _rows_for([keys, keys])
     return dict(data=data, schema=schema, dense=[1, 1],
                 q=lambda df: _numeric_aggs(df.group_by("k")))
@@ -492,7 +496,7 @@ def _case_exactly_g_groups():
 def _case_g_plus_one_groups():
     """Batch 1 holds G + 1 groups and takes a second pass, batch 2 holds
     G."""
-    keys = _int_keys_in_distinct_buckets(_G + 1)
+    keys = _int_keys_of_distinct_ids(_G + 1)
     data, schema = _rows_for([keys, keys[:-1]])
     return dict(data=data, schema=schema, dense=[0, 1],
                 q=lambda df: _numeric_aggs(df.group_by("k")))
@@ -501,7 +505,7 @@ def _case_g_plus_one_groups():
 def _case_two_full_passes():
     """Batch 1 holds 2 G groups, a row each: two full passes and no third;
     batch 2 one pass and a part of the next."""
-    keys = _int_keys_in_distinct_buckets(2 * _G)
+    keys = _int_keys_of_distinct_ids(2 * _G)
     assert len(keys) == _BATCH
     data, schema = _rows_for([keys, keys[:_G + 5]])
     return dict(data=data, schema=schema, dense=[0, 0],
@@ -509,9 +513,11 @@ def _case_two_full_passes():
 
 
 def _case_two_keys_in_one_bucket():
-    """Dirty: the bucket program's answer is dropped, the sort program
-    answers and the kernel key is latched (own aliases: a key of its own)."""
-    a, b = _two_int_keys_in_one_bucket()
+    """Dirty: two integers whose 31-bit ids agree, so only the exact key
+    compare tells them apart.  The bucket program's answer is dropped, the
+    sort program answers and the kernel key is latched (own aliases: a key
+    of its own)."""
+    a, b = _two_int_keys_of_one_id()
     data, schema = _rows_for([[a, b], [a]])
     return dict(data=data, schema=schema, dense=[-1, 1], dirty=True,
                 q=lambda df: _numeric_aggs(df.group_by("k"), tag="_dirty"))
@@ -538,20 +544,23 @@ def _case_string_keys_unequal_length():
 
 
 def _case_string_keys_same_bytes_one_bucket():
-    """Two keys of one bucket whose padded bytes agree and whose lengths
-    differ: only the length compare tells them apart, and it must (dirty)."""
+    """Two keys of one id whose padded bytes agree and whose lengths
+    differ: only the length compare tells them apart, and it must (dirty).
+    Such a pair is one in 2**31 under the kernel's fold, so the case runs
+    under _narrow_ids."""
     import itertools
     import string
     stems = ["".join(p) for p in itertools.product(
         string.ascii_letters + string.digits, repeat=2)]
     longer = [x + "\x00" for x in stems]
-    hits = [(x, y) for x, y, bx, by in zip(
-        stems, longer, _bucket_of(stems, T.StringType),
-        _bucket_of(longer, T.StringType)) if bx == by]
-    assert hits, "no stem shares a bucket with its zero-padded twin"
+    hits = [(x, y) for x, y, ix, iy in zip(
+        stems, longer, _ids_of(stems, T.StringType, _narrow_ids),
+        _ids_of(longer, T.StringType, _narrow_ids)) if ix == iy]
+    assert hits, "no stem shares an id with its zero-padded twin"
     data, schema = _rows_for([list(hits[0]), ["zz"]],
                              key_dtype=T.StringType)
     return dict(data=data, schema=schema, dense=[-1, 1], dirty=True,
+                fold=_narrow_ids,
                 q=lambda df: _numeric_aggs(df.group_by("k"), tag="_len"))
 
 
@@ -585,6 +594,55 @@ def _case_all_nan_and_no_valid_groups():
                 q=lambda df: _numeric_aggs(df.group_by("k")))
 
 
+def _case_rollup_171_groups():
+    """The rollup report's shape: two string keys that the subtotals
+    leave NULL and the grouping id, 171 groups a batch: six passes."""
+    cats = ["category%02d" % i for i in range(10)]
+    classes = ["class%02d" % i for i in range(16)]
+    groups = ([(c, s, 0) for c in cats for s in classes]
+              + [(c, None, 1) for c in cats] + [(None, None, 3)])
+    assert len(groups) == 171
+    data, schema = _rows_for([list(range(171))] * 2, batch=256)
+    rows = [groups[i] for i in data.pop("k")]
+    data["cat"], data["cls"], data["gid"] = map(list, zip(*rows))
+    schema = T.Schema([T.StructField("cat", T.StringType),
+                       T.StructField("cls", T.StringType),
+                       T.StructField("gid", T.IntegerType)]
+                      + list(schema)[1:])
+    return dict(data=data, schema=schema, batch=256, dense=[0, 0],
+                state_rows=[171, 171],
+                q=lambda df: _numeric_aggs(df.group_by("cat", "cls", "gid")))
+
+
+def _case_exactly_b_groups():
+    """As many groups as the state holds: clean at the last pass."""
+    keys = _int_keys_of_distinct_ids(_B)
+    data, schema = _rows_for([keys, keys[:_G]], batch=_B)
+    return dict(data=data, schema=schema, batch=_B, dense=[0, 1],
+                state_rows=[_B, _G],
+                q=lambda df: _numeric_aggs(df.group_by("k")))
+
+
+def _case_b_plus_one_groups():
+    """One group more than the state holds: dirty after the last pass, the
+    sort program answers, the key is latched."""
+    keys = _int_keys_of_distinct_ids(_B + 1)
+    data, schema = _rows_for([keys, keys[:3]], batch=2 * _B)
+    return dict(data=data, schema=schema, batch=2 * _B, dense=[-1, 1],
+                state_rows=[_B, 3], dirty=True,
+                q=lambda df: _numeric_aggs(df.group_by("k"), tag="_over"))
+
+
+def _case_high_cardinality_bails_after_one_pass():
+    """60,000 groups a batch: the first pass reads that from the ids it
+    found and the loop ends there, with one pass's groups in the state."""
+    keys = list(range(60000))
+    data, schema = _rows_for([keys], batch=65536)
+    return dict(data=data, schema=schema, batch=65536, dense=[-1],
+                state_rows=[_G], dirty=True,
+                q=lambda df: _numeric_aggs(df.group_by("k"), tag="_many"))
+
+
 _BUCKET_CASES = {
     "one_group": _case_one_group,
     "exactly_G_groups": _case_exactly_g_groups,
@@ -597,6 +655,11 @@ _BUCKET_CASES = {
         _case_string_keys_same_bytes_one_bucket,
     "all_dead_batch": _case_all_dead_batch,
     "all_nan_and_no_valid_groups": _case_all_nan_and_no_valid_groups,
+    "rollup_171_groups": _case_rollup_171_groups,
+    "exactly_B_groups": _case_exactly_b_groups,
+    "B_plus_one_groups": _case_b_plus_one_groups,
+    "high_cardinality_bails_after_one_pass":
+        _case_high_cardinality_bails_after_one_pass,
 }
 
 
@@ -611,19 +674,22 @@ def _find_agg(node):
 
 @pytest.mark.parametrize("path", ["whole_stage", "streaming", "kernel"])
 @pytest.mark.parametrize("case", list(_BUCKET_CASES))
-def test_bucket_update_dense_passes(case, path):
+def test_bucket_update_dense_passes(case, path, monkeypatch):
     """_bucket_update_kernel in one pass and in more against the sort
     path's _update_kernel on the same batches (`kernel`) and against the
     CPU executors (ops/cpu_eval.py) through the whole-stage program and
     the streaming loop; `took` / aggDenseBatches count the batches of one
-    pass."""
+    pass, aggBucketBatches those of any number."""
     import jax
     from spark_rapids_tpu.engine import TpuSession
     from spark_rapids_tpu.exec import aggregate as A
     from spark_rapids_tpu.exec.base import ExecContext
     c = _BUCKET_CASES[case]()
-    conf = {**FLOAT_AGG,
-            "spark.rapids.sql.reader.batchSizeRows": str(_BATCH)}
+    if "fold" in c:
+        monkeypatch.setattr(TpuHashAggregateExec, "_bucket_ids",
+                            staticmethod(c["fold"]))
+    conf = {**FLOAT_AGG, "spark.rapids.sql.reader.batchSizeRows":
+            str(c.get("batch", _BATCH))}
     if path == "streaming":
         conf["spark.rapids.sql.tpu.wholeStage.enabled"] = "false"
     s = TpuSession(conf)
@@ -638,10 +704,16 @@ def test_bucket_update_dense_passes(case, path):
             bucket = jax.jit(agg._bucket_update_kernel)
             result = jax.jit(lambda st: agg._finalize_kernel(
                 agg._merge_kernel(st)))
-            for b, want in zip(batches, c["dense"]):
+            state_rows = c.get("state_rows", [None] * len(batches))
+            for b, want, rows in zip(batches, c["dense"], state_rows):
                 took, bstate = bucket(b)
                 assert int(took) == want
                 assert bstate.capacity == agg._BUCKETS
+                if rows is not None:
+                    # the groups found are the state's first rows, G a
+                    # pass: their number is the passes that ran
+                    sel = bstate.sel.tolist()
+                    assert sel == [True] * rows + [False] * (_B - rows)
                 if want >= 0:
                     assert_rows_equal(
                         result(agg._update_kernel(b)).to_pylist(),
@@ -653,15 +725,48 @@ def test_bucket_update_dense_passes(case, path):
                 c["data"], c["schema"])).collect()
         assert_rows_equal(cpu, tpu)
         counted = s.query_metrics_total.get("aggDenseBatches", 0)
+        taken = s.query_metrics_total.get("aggBucketBatches", 0)
+        by_sort = s.query_metrics_total.get("aggSortPathBatches", 0)
         latched = A._BUCKET_DIRTY_KEYS - dirty_before
         if c.get("dirty"):
             # the whole-stage program drops every batch's bucket state;
             # the loop stops probing at the first dirty batch
-            assert counted == 0 and len(latched) == 1
+            assert counted == 0 and taken == 0 and len(latched) == 1
+            assert by_sort == len(c["dense"])
         else:
             assert counted == sum(c["dense"]) and not latched
+            assert taken == len(c["dense"]) and by_sort == 0
     finally:
         A._BUCKET_DIRTY_KEYS.intersection_update(dirty_before)
+
+
+def test_bucket_update_counts_a_row_of_the_largest_id(monkeypatch):
+    """The fold reaches the id the dead rows carry; a live row that folds
+    to it is clamped one below and still counted."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.exec.base import ExecContext
+    largest = 2 ** 31 - 1
+    assert int(TpuHashAggregateExec._bucket_ids(
+        jnp.uint64(2 ** 64 - 1))) == largest
+    monkeypatch.setattr(
+        TpuHashAggregateExec, "_bucket_ids",
+        staticmethod(lambda h1: jnp.full(h1.shape, largest, jnp.int32)))
+    data, schema = _rows_for([[5]])
+    data["keep"] = [i % 3 != 0 for i in range(_BATCH)]
+    schema = T.Schema(list(schema) + [T.StructField("keep", T.BooleanType)])
+    s = TpuSession(FLOAT_AGG)
+    query = _numeric_aggs(s.from_pydict(data, schema).filter(col("keep"))
+                          .group_by("k"), tag="_top")
+    agg = _find_agg(s.plan(query.plan))
+    [b] = agg.children[0].execute(ExecContext(s.conf, runtime=s.runtime))
+    took, bstate = jax.jit(agg._bucket_update_kernel)(b)
+    assert int(took) == 1
+    result = jax.jit(lambda st: agg._finalize_kernel(agg._merge_kernel(st)))
+    [row] = result(bstate).to_pylist()
+    assert row[:2] == (5, sum(data["keep"]))       # k, count(*)
+    assert_rows_equal(result(agg._update_kernel(b)).to_pylist(), [row])
 
 
 def _per_row_indexed(jaxpr, cap):

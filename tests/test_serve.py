@@ -31,7 +31,6 @@ import pyarrow as pa
 import pytest
 
 from spark_rapids_tpu.engine import TpuSession
-from spark_rapids_tpu.exec import aggregate as AGG
 from spark_rapids_tpu.plan.logical import col, functions as F, lit
 from spark_rapids_tpu.serve.plan_cache import (PlanCache, extract_parameters,
                                                plan_cache_key)
@@ -163,11 +162,9 @@ def test_submit_matches_collect_across_variants():
         variants = [(10.0, 40, 2.0), (55.0, 20, 7.0)]
         for i, (cut, k, scale) in enumerate(variants):
             expected = _q_agg(df, cut, k, scale).to_arrow()
-            # like with like: collect()'s kernel key carries its literals
-            # and probes the bucket update afresh, so submit()'s shared
-            # key does too (the 40-group variant latches it dirty: 20 and
-            # 32 share a bucket)
-            AGG._BUCKET_DIRTY_KEYS.clear()
+            # like with like: 40 and 20 groups are both clean for the
+            # bucket update, under collect()'s kernel key, which carries
+            # its literals, and under submit()'s shared one
             fut = s.submit(_q_agg(df, cut, k, scale))
             assert fut.result(300).equals(expected)
             assert fut.plan_cache == ("miss" if i == 0 else "hit")
@@ -196,29 +193,28 @@ def test_variant_resubmission_compiles_nothing_new():
         # and the warm path actually ran through the caches
         assert after["kernel_hits"] + after["stage_hits"] > \
             before["kernel_hits"] + before["stage_hits"]
-        # sanity: the warm results are still right.  r1 took the sort
-        # program (the 40-group submission latched the shape's key dirty):
-        # collect() on that path is its like
-        sort_side = _session({"spark.rapids.sql.tpu.agg.bucketGroups":
-                              "false"})
-        assert r1.equals(_q_agg(sort_side.from_arrow(_TABLE),
-                                66.0, 11, 5.5).to_arrow())
+        # sanity: the warm results are still right (r1 took the bucket
+        # program, as collect() does)
+        assert r1.equals(_q_agg(df, 66.0, 11, 5.5).to_arrow())
         assert r2.equals(_q_rowlocal(df, 30.0, 31.5).to_arrow())
     finally:
         s.shutdown_serving()
 
 
 def test_latched_key_answers_differ_from_collect_in_float_order_only():
-    """The cross-path drift, by name: once a 40-group variant has latched
-    the shape's kernel key dirty, submit() answers a 20-group variant
-    through the sort program (a group's doubles summed in row order) while
-    collect(), whose key carries its literals, takes the bucket update's
-    dense form (summed as a tree).  Keys, counts and order are exact; the
-    sums agree to 1e-12 (variableFloatAgg, on in `_session`)."""
+    """The cross-path drift, by name: once a 1,100-group variant (more
+    than a bucket state holds) has latched the shape's kernel key dirty,
+    submit() answers a 20-group variant through the sort program (a
+    group's doubles summed in row order) while collect(), whose key
+    carries its literals, takes the bucket update's dense form (summed as
+    a tree).  Keys, counts and order are exact; the sums agree to 1e-12
+    (variableFloatAgg, on in `_session`)."""
     s = _session()
     try:
-        df = s.from_arrow(_TABLE)
-        s.submit(_q_agg(df, 10.0, 40, 2.0)).result(300)
+        rng = np.random.RandomState(11)
+        df = s.from_arrow(_TABLE.set_column(
+            1, "b", pa.array(rng.randint(0, 1100, N_ROWS).astype(np.int64))))
+        s.submit(_q_agg(df, 10.0, 1100, 2.0)).result(300)
         dense0 = s.query_metrics_total.get("aggDenseBatches", 0)
         got = s.submit(_q_agg(df, 55.0, 20, 7.0)).result(300)
         assert s.query_metrics_total.get("aggDenseBatches", 0) == dense0

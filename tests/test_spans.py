@@ -255,14 +255,14 @@ def test_a_window_over_a_rollup_spans_its_launch_and_counts_at_capacity(
     sits in a `srt:window` span (opened after the child has been drained,
     so the aggregate's spans lie before it, not inside), with `rows` at
     capacity and `batches` in the annotation; the Expand's fan-out, the
-    sort-based update's batches and the window's rows are host counters."""
+    bucket update's batches and the window's rows are host counters."""
     rng = np.random.default_rng(33)
     session = TpuSession({"spark.rapids.sql.variableFloatAgg.enabled":
                           "true"})
     n = 6_000
     facts = session.from_arrow(pa.table({
-        # 40 x 10 pairs and the subtotals: with 1,024 buckets such a batch
-        # is dirty, so the sort-based whole-stage program answers
+        # 40 x 10 pairs and the subtotals: 441 groups, which the bucket
+        # whole-stage program answers in 14 dense passes
         "a": [f"a{i}" for i in rng.integers(0, 40, n)],
         "b": [f"b{i}" for i in rng.integers(0, 10, n)],
         "v": rng.uniform(1, 2, n)}))
@@ -281,15 +281,17 @@ def test_a_window_over_a_rollup_spans_its_launch_and_counts_at_capacity(
         [execute] = by["srt:execute"]
         [window] = by["srt:window"]
         assert inside(window, execute)
-        [agg] = by["srt:agg_whole_stage"]
+        [agg] = by["srt:agg_whole_stage_bucket"]
         assert agg[1] <= window[0]
         assert int(window[3]["batches"]) == 1
-        assert int(window[3]["rows"]) == 3 * 8192      # Expand's capacity
+        # the capacity the aggregate hands on: one batch's bucket state
+        assert int(window[3]["rows"]) == 1024
     moved = session.last_execution.aggregate()
     assert moved[MN.EXPAND_OUTPUT_ROWS] == 3 * 8192
     assert moved[MN.EXPAND_BATCHES] == 1
-    assert moved[MN.AGG_SORT_PATH_BATCHES] == 1
-    assert moved[MN.WINDOW_ROWS] == 3 * 8192
+    assert moved[MN.AGG_BUCKET_BATCHES] == 1
+    assert not moved.get(MN.AGG_SORT_PATH_BATCHES)
+    assert moved[MN.WINDOW_ROWS] == 1024
     assert moved[MN.WINDOW_BATCHES] == 1
     assert len(df.collect()) == 40 * 10 + 40 + 1
 
