@@ -9,7 +9,10 @@ the pair gives the CPU-vs-TPU comparison oracle the test suite uses.
 """
 from __future__ import annotations
 
+import functools
 from typing import Iterator, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from ..columnar import ColumnarBatch
 from ..config import TpuConf
@@ -20,6 +23,7 @@ from ..metrics import names as MN
 from ..metrics.registry import Metrics  # noqa: F401
 from ..metrics.roofline import cost_accounting_enabled
 from ..types import Schema
+from ..utils.tracing import SPAN_PREFIX
 
 
 def record_output_batch(metrics: Metrics, batch, runtime=None) -> None:
@@ -138,8 +142,88 @@ class ExecContext:
         return ctx
 
 
+#: the entry points an operator is pulled through; `ExecNode` wraps each
+#: one a subclass defines, once, when the class is created
+_PULLED = ("execute", "execute_cpu", "execute_partitions")
+
+
+def _pull_spans(node, it, name: str, args: dict):
+    """`it`, with every `next()` on it inside a profiler annotation `name`
+    (the bare `TraceAnnotation`: it starts when it is built, so one is built
+    a pull; `name` and `args` are built once by the caller).  A child is
+    pulled from inside its parent's pull, so the spans nest as the plan
+    does and the innermost open one is the operator whose Python is
+    running.  `node._in_pull` is set for the pull, so an entry point of the
+    SAME node called from inside it opens no twin.  Closing this generator
+    (a LIMIT that stops pulling) closes `it`; an exception leaves through
+    the annotation's `__exit__`."""
+    it = iter(it)
+    try:
+        while True:
+            with TraceAnnotation(name, **args):
+                node._in_pull = True
+                try:
+                    item = next(it)
+                except StopIteration:
+                    return
+                finally:
+                    node._in_pull = False
+            yield item
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
+
+
+def _with_pull_span(fn):
+    """Wrap one of `_PULLED` so that what it returns is pulled inside
+    `srt:op:<ClassName>@<node id>` (no suffix before
+    `QueryExecution._assign_ids` / `adopt` has numbered the node), with
+    `q=<query id>` where the context knows the query.  `@`, not `#`: the
+    profiler writes arguments as `name#k=v#` and splits at the first `#`,
+    so a `#` in a name that carries arguments loses both.  One span a pull
+    of a NODE: an entry point reached from inside the node's own pull (a
+    subclass's `super().execute(ctx)`, an `execute` that drains
+    `self.execute_partitions`) is handed on as it is."""
+    @functools.wraps(fn)
+    def pulled(self, ctx, *args, **kwargs):
+        if self._in_pull:
+            return fn(self, ctx, *args, **kwargs)
+        name = f"{SPAN_PREFIX}op:{type(self).__name__}"
+        nid = getattr(self, "_node_id", None)
+        if nid is not None:
+            name = f"{name}@{nid}"
+        qe = getattr(ctx, "query_execution", None)
+        # a plain method that hands on `super().execute(ctx)` calls the
+        # inner entry point here, before anything is pulled
+        self._in_pull = True
+        try:
+            it = fn(self, ctx, *args, **kwargs)
+        finally:
+            self._in_pull = False
+        return _pull_spans(self, it, name,
+                           {} if qe is None else {"q": qe.query_id})
+    return pulled
+
+
 class ExecNode:
     """Base physical operator."""
+
+    def __init_subclass__(cls, **kwargs):
+        # THE one place an operator gets its pull span: whatever entry
+        # points the class itself defines, wrapped at class creation, so a
+        # query pays nothing when it begins, nodes that adaptive
+        # re-planning adds later are covered, and no operator opens the
+        # span by hand (`metrics/query.py _instrument`'s journal spans are
+        # the other system: another clock, off by default)
+        super().__init_subclass__(**kwargs)
+        for entry in _PULLED:
+            fn = vars(cls).get(entry)
+            if callable(fn):
+                setattr(cls, entry, _with_pull_span(fn))
+
+    #: this node is inside one of its own pulls (`_pull_spans`)
+    _in_pull = False
 
     def __init__(self, *children: "ExecNode"):
         self.children: List[ExecNode] = list(children)

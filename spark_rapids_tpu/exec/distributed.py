@@ -181,21 +181,27 @@ class TpuDistributedJoinExec(TpuHashJoinExec):
             yield from super().execute(ctx)
             return
         produced = False
-        with named_range("dist_join", self.metrics,
-                         MN.DISTRIBUTED_JOIN_TIME):
-            # stream the probe side: every supported join type
-            # (inner/left/left_semi/left_anti) is per-left-row independent,
-            # so per-chunk results compose by concatenation
-            for out in run_distributed_join_streaming(
-                    self, self.mesh,
-                    _sharded_chunks(self.children[0], ctx, self.mesh, n,
-                                    chunk_rows),
-                    right, use_allgather=self.use_allgather,
-                    cache_key=self.kernel_key(),
-                    on_exchange=_ici_declarer(self.metrics)):
-                produced = True
-                record_output_batch(self.metrics, out, ctx.runtime)
-                yield out
+        # stream the probe side: every supported join type
+        # (inner/left/left_semi/left_anti) is per-left-row independent,
+        # so per-chunk results compose by concatenation
+        chunks = run_distributed_join_streaming(
+            self, self.mesh,
+            _sharded_chunks(self.children[0], ctx, self.mesh, n, chunk_rows),
+            right, use_allgather=self.use_allgather,
+            cache_key=self.kernel_key(),
+            on_exchange=_ici_declarer(self.metrics))
+        while True:
+            # one span (and the timer) a chunk, closed before the yield: a
+            # span held open across a yield clocks the consumer too, and
+            # straddles this operator's own pull spans (`exec/base.py`)
+            with named_range("dist_join", self.metrics,
+                             MN.DISTRIBUTED_JOIN_TIME):
+                out = next(chunks, None)
+            if out is None:
+                break
+            produced = True
+            record_output_batch(self.metrics, out, ctx.runtime)
+            yield out
         if not produced:
             yield _empty_batch(self.schema)
 

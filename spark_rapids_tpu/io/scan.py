@@ -714,6 +714,15 @@ def _device_parquet_files(files, schema, options, conf, metrics, max_rows,
             host_names: List[str] = []
 
             def _decode_field(f):
+                # the thread that launches carries the span: on the column
+                # pool nothing else is open, and a launch's owner is read
+                # from its CALLING thread (`pq_copy_*`, `pq_sdict`, the
+                # numeric dictionary gathers' eager `jnp.take`)
+                with named_range("scan_column", rows=num_rows,
+                                 column=f.name):
+                    return _decode_column(f)
+
+            def _decode_column(f):
                 """-> (name, Column | None, 'unsupported'|'error'|None,
                 page copies); runs on the column pool — each column's
                 host control plane (header walk, decompress, RLE) is

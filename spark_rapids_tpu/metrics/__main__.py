@@ -326,18 +326,37 @@ def monitoring_doc() -> str:
         "read it with `python chipbench/xplane.py <file.xplane.pb>` (or",
         "TensorBoard's profile plugin / Perfetto).",
         "",
-        "`utils.tracing.named_range` is the only way the program opens a",
-        "span: a `jax.profiler.TraceAnnotation` named `srt:<name>` plus a",
-        "`jax.named_scope(<name>)`, optionally feeding a timer metric",
+        "`utils.tracing.named_range` is the way the program opens a span",
+        "by hand: a `jax.profiler.TraceAnnotation` named `srt:<name>` and",
+        "nothing else (no `jax.named_scope`: a scope marks only ops traced",
+        "inside it and nothing reads it), optionally feeding a timer metric",
         "(`named_range(name, metrics, MN.X)`: span and timer are one",
         "site).  `^srt:` separates the program's spans from JAX's runtime",
         "spans.  A span never reads or syncs the device; host-known counts",
         "ride as annotation arguments (`q=<query id>`, the journal's, on",
         "the phase spans; `rows=`, `bytes=`).  The span that caused a span",
-        "is the one it nests in on its thread.",
+        "is the one it nests in on its thread.  Every operator's PULL span",
+        "is opened for it, at one site (`exec/base.py",
+        "ExecNode.__init_subclass__`): each `next()` on what an operator's",
+        "`execute`, `execute_cpu` or `execute_partitions` returns runs",
+        "inside `srt:op:<ClassName>@<node id>`, so the innermost open",
+        "`srt:op:` span of a thread is the operator whose Python is",
+        "running, a launch's owner is the span its host call",
+        "(`PjitFunction(<name>)`) was made in, and an operator's self time",
+        "is its spans less what its children's cover.  One span a pull of",
+        "a NODE: an entry point reached from inside the node's own pull (a",
+        "subclass's `super().execute(ctx)`, an `execute` that drains",
+        "`self.execute_partitions`) opens no twin.",
         "",
         "Span | Where | Covers",
         "-----|-------|-------",
+        "`srt:op:<ClassName>@<node id>` | `exec/base.py`, around every "
+        "pull of every operator's iterator (the bare annotation, `q=<query "
+        "id>` where the context knows it; no `@<id>` before "
+        "`QueryExecution` has numbered the node; not `#`, which ends a "
+        "name that carries arguments) | one `next()`: the "
+        "operator's own Python, its launches, and nested inside it its "
+        "children's pulls and its hand-placed spans below",
         "`srt:plan` | `DataFrame.to_arrow` / `to_device_batches`, the "
         "scheduler's worker | `session.plan` (pushdown, tagging, overrides, "
         "transitions, fusion) and the output schema; the query id does not "
@@ -362,6 +381,12 @@ def monitoring_doc() -> str:
         "row-group chunk, ORC per column, CSV per chunk), on the prefetch "
         "thread when prefetch is on | footer and page parsing, "
         "decompression, H2D enqueue, decode dispatches (timer `scanTime`)",
+        "`srt:scan_column` | `io/scan.py _decode_field`, one a column and "
+        "row-group chunk, on the Parquet column pool's threads (on the "
+        "prefetch thread where a chunk has one column) | that column's "
+        "header walk, decompression and every launch it makes "
+        "(`jit_scan.pq_*`, the dictionary gathers' eager `jnp.take`): the "
+        "span of the thread that launches (`rows=`, `column=`)",
         "`srt:agg_shrink` | `exec/aggregate.py`, the GROUPED streaming "
         "loop only (its update sorts at capacity), one a batch of capacity "
         "8,192 or more, before its `srt:agg_update` | the read of the "
@@ -397,7 +422,11 @@ def monitoring_doc() -> str:
         "`srt:agg_whole_stage_bucket`, `srt:agg_update`, `srt:agg_merge`, "
         "`srt:whole_stage_<id>`, `srt:join_build`, `srt:join_stream`, "
         "`srt:join_full_tail`, `srt:dist_agg`, `srt:dist_join`, "
-        "`srt:dist_sort`, and a row-local operator's own name",
+        "`srt:dist_sort`, and a row-local operator's own name.  No span "
+        "stays open across a `yield`: `srt:dist_join` and its timer "
+        "`distributedJoinTime` are one a probe chunk and leave the "
+        "consumer's time out since PR 35 (held across the yields before "
+        "it: earlier readings of either are not comparable)",
         "",
         "Every executable the program compiles goes through",
         "`utils/kernel_cache.named_jit` (`cached_kernel`,",

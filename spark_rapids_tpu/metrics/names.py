@@ -152,7 +152,9 @@ D2H_TIME = register_metric(
 DISTRIBUTED_AGG_TIME = register_metric(
     "distributedAggTime", TIMER, MODERATE, "SPMD distributed aggregate time")
 DISTRIBUTED_JOIN_TIME = register_metric(
-    "distributedJoinTime", TIMER, MODERATE, "SPMD distributed join time")
+    "distributedJoinTime", TIMER, MODERATE,
+    "SPMD distributed join time: the join's own work, one reading a probe "
+    "chunk (the consumer's time between chunks is not in it)")
 DISTRIBUTED_SORT_TIME = register_metric(
     "distributedSortTime", TIMER, MODERATE, "SPMD distributed sort time")
 NUM_ICI_EXCHANGES = register_metric(

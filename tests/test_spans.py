@@ -3,6 +3,7 @@ utils/kernel_cache.named_jit): the collect path's phases as `srt:` spans on
 the profiler's clock, every compiled program under `<layer>.<role>`, and
 the two counters the spans and the SPMD operators feed (`scanTime`,
 `iciBytesMoved`)."""
+import collections
 import glob
 import os
 import re
@@ -76,6 +77,64 @@ def traced(df, tmp_path, queries=5):
             for line in host.lines]
 
 
+def q6_plan():
+    session = TpuSession({"spark.rapids.sql.variableFloatAgg.enabled": "true"})
+    return session, q6(session.from_arrow(lineitem())), 5
+
+
+def streamed_plan(grouped=True):
+    """An input past half of batchSizeBytes, so the aggregate streams."""
+    session = TpuSession({
+        "spark.rapids.sql.variableFloatAgg.enabled": "true",
+        "spark.rapids.sql.reader.batchSizeRows": "65536",
+        "spark.rapids.sql.batchSizeBytes": "12m"})
+    frame = session.from_arrow(lineitem())
+    df = (frame.filter(col("l_quantity") < 24.0).group_by(col("l_discount"))
+          .agg(F.sum(col("l_extendedprice") * col("l_shipdate")).alias("v"))
+          if grouped else q6(frame))
+    return session, df, 3
+
+
+def broadcast_join_plan(builds=1):
+    rng = np.random.default_rng(31)
+    session = TpuSession({})
+    n = 20_000
+    facts = session.from_arrow(pa.table({
+        "a": rng.integers(0, 40, n).astype(np.int64),
+        "b": rng.integers(0, 30, n).astype(np.int64),
+        "v": rng.integers(0, 1000, n).astype(np.int64)}))
+    dims = [session.from_arrow(pa.table({
+        key: np.arange(50, dtype=np.int64),
+        key + "_w": np.arange(50, dtype=np.int64) * 3}))
+        for key in ("ka", "kb")[:builds]]
+    df = facts.join(dims[0].filter(col("ka") < 25), on=col("a") == col("ka"))
+    if builds == 2:
+        df = df.join(dims[1], on=col("b") == col("kb"))
+    return session, df.agg(F.sum(col("v")).alias("s")), 3
+
+
+PLANS = {"q6": q6_plan, "streamed_grouped": streamed_plan,
+         "broadcast_join": broadcast_join_plan}
+
+
+@pytest.fixture(scope="module")
+def traced_plan(tmp_path_factory):
+    """-> f(name): (session, the host threads' events) of ONE traced run of
+    `PLANS[name]`, shared by every test that reads that plan's trace: a
+    profiler session and its queries are what these tests cost, and the
+    other workers' tests feel it.  Nothing collects on the session after
+    the trace, so `last_execution` is the last traced query's."""
+    made = {}
+
+    def get(name):
+        if name not in made:
+            session, df, queries = PLANS[name]()
+            made[name] = session, traced(
+                df, tmp_path_factory.mktemp(name), queries)
+        return made[name]
+    return get
+
+
 def phases(threads):
     """-> per traced collect, (the collect's span, the program's spans
     inside it on the querying thread), and every thread's span names."""
@@ -125,10 +184,9 @@ def check_phases(collects, session):
     assert statistics.median(covered) >= 0.95, covered
 
 
-def test_a_warm_query_lies_under_the_programs_phase_spans(tmp_path):
-    session = TpuSession({"spark.rapids.sql.variableFloatAgg.enabled": "true"})
-    collects, names = phases(traced(q6(session.from_arrow(lineitem())),
-                                    tmp_path))
+def test_a_warm_query_lies_under_the_programs_phase_spans(traced_plan):
+    session, threads = traced_plan("q6")
+    collects, names = phases(threads)
     assert len(collects) == 5
     check_phases(collects, session)
     # the one program of the query runs under its layer's name; what jax
@@ -157,23 +215,20 @@ def test_a_parquet_query_adds_the_scans_span_and_timer(tmp_path):
 
 
 @pytest.mark.parametrize("grouped", [False, True], ids=["keyless", "grouped"])
-def test_a_streamed_aggregate_marks_its_bail_and_spans_each_batch(tmp_path,
-                                                                  grouped):
+def test_a_streamed_aggregate_marks_its_bail_and_spans_each_batch(
+        tmp_path, traced_plan, grouped):
     """An input past half of batchSizeBytes: the whole-stage probe leaves a
     zero-length `srt:agg_whole_stage_bail` that says why.  The GROUPED
     streaming loop shrinks each batch under `srt:agg_shrink`, before and
     outside its `srt:agg_update`; the loop of an aggregate with no grouping
     keys has no shrink to span: one `srt:agg_update` a batch around one
     `agg.stream_step`, and no host read."""
-    session = TpuSession({
-        "spark.rapids.sql.variableFloatAgg.enabled": "true",
-        "spark.rapids.sql.reader.batchSizeRows": "65536",
-        "spark.rapids.sql.batchSizeBytes": "12m"})
-    frame = session.from_arrow(lineitem())
-    df = (frame.filter(col("l_quantity") < 24.0).group_by(col("l_discount"))
-          .agg(F.sum(col("l_extendedprice") * col("l_shipdate")).alias("v"))
-          if grouped else q6(frame))
-    collects, names = phases(traced(df, tmp_path, queries=2))
+    if grouped:
+        session, threads = traced_plan("streamed_grouped")
+    else:
+        session, df, _ = streamed_plan(grouped=False)
+        threads = traced(df, tmp_path, queries=2)
+    collects, names = phases(threads)
     assert "PjitFunction(agg.whole_stage)" not in names
     assert ("PjitFunction(agg.stream_step)" in names) == (not grouped)
     batches = -(-ROWS // 65536)
@@ -203,28 +258,19 @@ def test_a_streamed_aggregate_marks_its_bail_and_spans_each_batch(tmp_path,
 
 
 @pytest.mark.parametrize("builds", [1, 2], ids=["one_build", "two_builds"])
-def test_a_broadcast_join_spans_its_collect_and_its_upload(tmp_path, builds):
+def test_a_broadcast_join_spans_its_collect_and_its_upload(
+        tmp_path, traced_plan, builds):
     """A build side under `spark.sql.autoBroadcastJoinThreshold`: its
     exchange collects the child to the host under `srt:broadcast_collect`
     and hands it back to the device under `srt:broadcast_upload`, once a
     build side and query (every `collect()` plans anew), both inside
     `srt:execute` and before the join's own `srt:join_build`."""
-    rng = np.random.default_rng(31)
-    session = TpuSession({})
-    n = 20_000
-    facts = session.from_arrow(pa.table({
-        "a": rng.integers(0, 40, n).astype(np.int64),
-        "b": rng.integers(0, 30, n).astype(np.int64),
-        "v": rng.integers(0, 1000, n).astype(np.int64)}))
-    dims = [session.from_arrow(pa.table({
-        key: np.arange(50, dtype=np.int64),
-        key + "_w": np.arange(50, dtype=np.int64) * 3}))
-        for key in ("ka", "kb")[:builds]]
-    df = facts.join(dims[0].filter(col("ka") < 25), on=col("a") == col("ka"))
-    if builds == 2:
-        df = df.join(dims[1], on=col("b") == col("kb"))
-    df = df.agg(F.sum(col("v")).alias("s"))
-    collects, names = phases(traced(df, tmp_path, queries=2))
+    if builds == 1:
+        session, threads = traced_plan("broadcast_join")
+    else:
+        session, df, _ = broadcast_join_plan(builds)
+        threads = traced(df, tmp_path, queries=2)
+    collects, names = phases(threads)
     assert [n for n in names if n.startswith("PjitFunction(join.")
             and n.endswith("_probe)")], names
     for collect, spans in collects:
@@ -310,6 +356,305 @@ def test_named_range_is_a_span_and_a_timer_and_never_a_sync():
     from spark_rapids_tpu.utils import tracing
     src = inspect.getsource(tracing.named_range)
     assert not re.search(r"block_until_ready|device_get|np\.asarray", src)
+
+
+# -- operator pull spans -------------------------------------------------------
+
+OP = SPAN_PREFIX + "op:"
+
+
+def op_spans(spans):
+    return [e for e in spans if e[2].startswith(OP)]
+
+
+def enclosing(e, spans):
+    """The innermost of `spans` that holds `e` (not `e` itself)."""
+    around = [o for o in spans if o is not e and inside(e, o)
+              and (o[0], -o[1]) <= (e[0], -e[1])]
+    return max(around, key=lambda o: (o[0], -o[1]), default=None)
+
+
+def straddling(spans):
+    """Pairs of `spans` that overlap without one holding the other (a span
+    held open across a `yield` does that to its operator's pull spans)."""
+    spans = sorted(spans, key=lambda e: (e[0], -e[1]))
+    return [(a[2], b[2]) for i, a in enumerate(spans) for b in spans[i + 1:]
+            if b[0] < a[1] < b[1]]
+
+
+@pytest.mark.parametrize("plan, operators", [
+    ("q6", {"DeviceToHostExec", "TpuHashAggregateExec", "TpuScanMemoryExec"}),
+    ("broadcast_join", {"TpuBroadcastHashJoinExec", "TpuHashAggregateExec"}),
+    ("streamed_grouped", {"TpuHashAggregateExec", "TpuScanMemoryExec"}),
+], ids=["q6", "broadcast_join", "streamed_grouped_aggregate"])
+def test_every_launch_of_a_query_lies_in_an_operators_pull_span(
+        traced_plan, plan, operators):
+    """`exec/base.py` wraps every operator's iterator once: each pull is a
+    `srt:op:<ClassName>@<node id>` span with the query's id, a child's pull
+    lies inside its parent's, so every jitted call the querying thread makes
+    inside `srt:execute` has an operator, and the operators' self times
+    (a span less the operator spans right inside it) and the semaphore's
+    wait are `srt:execute`'s."""
+    session, threads = traced_plan(plan)
+    collects, names = phases(threads)
+    [thread] = [th for th in threads if any(e[2] == COLLECT for e in th)]
+    qe = session.last_execution
+    ancestors = {}
+    for nid, parent in qe._parent_of.items():
+        chain = []
+        while parent is not None:
+            chain.append(parent)
+            parent = qe._parent_of[parent]
+        ancestors[nid] = chain
+    shares = []
+    for collect, spans in collects:
+        [execute] = [e for e in spans if e[2] == "srt:execute"]
+        ops = op_spans(spans)
+        assert {e[2][len(OP):].split("@")[0] for e in ops} >= operators
+        assert all(inside(e, execute) for e in ops)
+        assert not straddling(spans)
+        # every jitted call of the drain is some operator's
+        calls = [e for e in thread if e[2].startswith("PjitFunction(")
+                 and inside(e, execute)]
+        assert calls
+        for call in calls:
+            assert enclosing(call, ops) is not None, call
+        # the spans carry the node's id and the query's, and nest as the
+        # plan does: an operator is pulled from inside an ancestor's pull
+        self_ns = {}
+        for e in ops:
+            assert e[3]["q"] == execute[3]["q"]
+            name, nid = e[2][len(OP):].split("@")
+            assert name == type(qe.nodes[int(nid)]).__name__
+            outer = enclosing(e, ops)
+            if outer is None:
+                assert int(nid) == 0
+            else:
+                onid = int(outer[2].split("@")[1])
+                assert onid in ancestors[int(nid)], (e[2], outer[2])
+                self_ns[onid] = self_ns.get(onid, 0) - (e[1] - e[0])
+            self_ns[int(nid)] = self_ns.get(int(nid), 0) + e[1] - e[0]
+        assert all(ns >= 0 for ns in self_ns.values()), self_ns
+        # beside the root's pulls `srt:execute` holds the semaphore's wait
+        [semaphore] = [e for e in spans if e[2] == "srt:semaphore"]
+        shares.append((sum(self_ns.values()) + semaphore[1] - semaphore[0])
+                      / (execute[1] - execute[0]))
+    assert int(collects[-1][1][2][3]["q"]) == qe.query_id
+    # within 2% (a loaded host only ever lowers a query's share)
+    assert 0.98 <= max(shares) <= 1.0 and statistics.median(shares) >= 0.95, \
+        shares
+
+
+def toy_operators():
+    from spark_rapids_tpu.exec.base import TpuExec
+
+    class ToySourceExec(TpuExec):
+        """Five batches, and whether its generator was closed."""
+        closed = False
+
+        def execute(self, ctx):
+            try:
+                yield from range(5)
+            finally:
+                self.closed = True
+
+    class ToyHeadExec(TpuExec):
+        """Stops after one batch of its child, as a LIMIT does."""
+
+        def execute(self, ctx):
+            for batch in self.children[0].execute(ctx):
+                yield batch
+                return
+
+    class ToyRaisingExec(TpuExec):
+        def execute(self, ctx):
+            for batch in self.children[0].execute(ctx):
+                if batch == 2:
+                    raise ValueError("batch 2")
+                yield batch
+
+    return ToySourceExec, ToyHeadExec, ToyRaisingExec
+
+
+def traced_block(tmp_path, body):
+    """`body()` under the profiler -> this thread's (start, end, name)."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation(COLLECT):
+            body()
+        with jax.profiler.TraceAnnotation("test:after"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    [pb] = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(pb)
+    [host] = [p for p in data.planes if p.name == "/host:CPU"]
+    [thread] = [sorted((int(e.start_ns), int(e.start_ns + e.duration_ns),
+                        e.name) for e in line.events)
+                for line in host.lines
+                if any(e.name == COLLECT for e in line.events)]
+    return thread
+
+
+def test_a_limit_that_stops_pulling_closes_the_child_and_every_span(tmp_path):
+    Source, Head, _ = toy_operators()
+    source = Source()
+    head = Head(source)
+    # a class's own entry points are wrapped when the class is made
+    assert hasattr(Source.execute, "__wrapped__")
+    got = []
+    thread = traced_block(tmp_path, lambda: got.extend(head.execute(None)))
+    assert got == [0] and source.closed
+    names = [n for _, _, n in thread if n.startswith(OP)]
+    # no id before a query numbers the nodes; two pulls of the head (the
+    # second ends it), one of the source, each a finished span
+    assert sorted(names) == [OP + "ToyHeadExec"] * 2 + [OP + "ToySourceExec"]
+    [after] = [e for e in thread if e[2] == "test:after"]
+    assert all(e[1] <= after[0] for e in thread if e[2].startswith(OP))
+    # the same through a plan: the scan's batches past the limit are never
+    # pulled and its generator is closed with the query
+    session = TpuSession({"spark.rapids.sql.reader.batchSizeRows": "4096"})
+    df = session.from_arrow(lineitem(40_000)).limit(10)
+    collects, _ = phases(traced(df, tmp_path / "plan", queries=1))
+    scans = [e for e in op_spans(collects[0][1]) if "ScanMemory" in e[2]]
+    assert 1 <= len(scans) < 40_000 // 4096
+    assert len(df.collect()) == 10
+
+
+def test_an_operator_that_raises_leaves_no_span_open(tmp_path):
+    Source, _, Raising = toy_operators()
+    source = Source()
+    got = []
+
+    def body():
+        with pytest.raises(ValueError, match="batch 2"):
+            got.extend(Raising(source).execute(None))
+    thread = traced_block(tmp_path, body)
+    assert got == [0, 1] and source.closed
+    ops = [e for e in thread if e[2].startswith(OP)]
+    # the raising pull's span is finished too: three pulls each
+    assert len(ops) == 6
+    [after] = [e for e in thread if e[2] == "test:after"]
+    assert all(e[1] <= after[0] for e in ops)
+    # and a generator closed by hand closes its child
+    source = Source()
+    pulled = Raising(source).execute(None)
+    assert next(pulled) == 0
+    pulled.close()
+    assert source.closed
+
+
+def test_an_entry_point_reached_inside_the_nodes_own_pull_opens_no_twin(
+        tmp_path):
+    """A subclass that hands on `super().execute(ctx)` (the SPMD join's
+    fallback onto the hash join), as a generator or as a plain method, and
+    an `execute` that drains the node's own `execute_partitions`: one span
+    a pull of the NODE, under the class's own name."""
+    Source, _, _ = toy_operators()
+
+    class ToyYieldFromExec(Source):
+        def execute(self, ctx):
+            yield from super().execute(ctx)
+
+    class ToyHandsOnExec(Source):
+        def execute(self, ctx):
+            return super().execute(ctx)
+
+    class ToyPartitionsExec(Source):
+        def execute_partitions(self, ctx):
+            yield from enumerate(range(5))
+
+        def execute(self, ctx):
+            for _, batch in self.execute_partitions(ctx):
+                yield batch
+
+    nodes = [ToyYieldFromExec(), ToyHandsOnExec(), ToyPartitionsExec()]
+    got = []
+    thread = traced_block(
+        tmp_path, lambda: got.extend(list(n.execute(None)) for n in nodes))
+    assert got == [list(range(5))] * 3 and nodes[0].closed
+    names = collections.Counter(n for _, _, n in thread if n.startswith(OP))
+    # five batches and the pull that ends it, each node
+    assert names == {OP + type(n).__name__: 6 for n in nodes}
+    assert not any(n._in_pull for n in nodes)
+    # from outside a pull the partitions are an entry point of their own
+    assert len(list(nodes[2].execute_partitions(None))) == 5
+
+
+def test_a_parquet_columns_launches_lie_in_its_pool_threads_span(tmp_path):
+    """The launching threads of a Parquet scan are the column pool's: each
+    opens `srt:scan_column` around one column of one row-group chunk, so a
+    launch made there has an owner on ITS thread (string columns decode on
+    the device on every backend)."""
+    rng = np.random.default_rng(41)
+    n = 30_000
+    path = str(tmp_path / "t.parquet")
+    papq.write_table(pa.table({
+        "s": [f"k{i}" for i in rng.integers(0, 20, n)],
+        "t": [f"w{i}" for i in rng.integers(0, 7, n)],
+        "v": rng.integers(0, 1000, n).astype(np.int64)}), path,
+        compression="snappy", use_dictionary=True)
+    session = TpuSession({})
+    df = session.read.parquet(path).group_by("s", "t").agg(
+        F.sum(col("v")).alias("x"))
+    threads = traced(df, tmp_path / "trace", queries=2)
+    pool = [th for th in threads
+            if any(e[2] == "srt:scan_column" for e in th)]
+    assert pool and not any(e[2] == COLLECT for th in pool for e in th)
+    columns = [e for th in pool for e in th if e[2] == "srt:scan_column"]
+    assert {e[3]["column"] for e in columns} == {"s", "t", "v"}
+    assert {int(e[3]["rows"]) for e in columns} == {n}
+    calls = 0
+    for th in pool:
+        spans = [e for e in th if e[2] == "srt:scan_column"]
+        for e in th:
+            if e[2].startswith("PjitFunction("):
+                calls += 1
+                assert enclosing(e, spans) is not None, e
+    assert calls > 0
+
+
+def test_the_spmd_joins_span_closes_before_it_yields(tmp_path):
+    """`srt:dist_join` is one span a probe chunk, inside ONE pull of the
+    join: held open across the `yield` it clocked the consumer too and
+    straddled the join's pull spans, which no reader can flatten."""
+    rows = 1000
+    session = TpuSession({
+        "spark.rapids.sql.tpu.mesh.devices": "4",
+        "spark.sql.autoBroadcastJoinThreshold": "-1"})
+    left = session.from_arrow(pa.table({
+        "k": np.arange(rows, dtype=np.int32),
+        "v": np.arange(rows, dtype=np.int64)}))
+    right = session.from_arrow(pa.table({
+        "k": np.arange(rows, dtype=np.int32),
+        "w": np.arange(rows, dtype=np.int64) * 3}))
+    df = left.join(right, on="k").group_by("k").agg(
+        F.sum(col("v") + col("w")).alias("s"))
+    collects, _ = phases(traced(df, tmp_path, queries=2))
+    for _, spans in collects:
+        pulls = [e for e in spans
+                 if e[2].startswith(OP + "TpuDistributedJoinExec@")]
+        chunks = [e for e in spans if e[2] == "srt:dist_join"]
+        assert pulls and chunks
+        assert all(enclosing(e, pulls) is not None for e in chunks)
+        assert not straddling(spans)
+    assert len(df.collect()) == rows
+
+
+def test_a_named_range_leaves_the_name_stack_alone():
+    """No `jax.named_scope`: what is traced inside a span is named as
+    outside it (and the persistent compile cache's keys do not move with a
+    span's name)."""
+    from jax._src import source_info_util
+    outside = str(source_info_util.current_name_stack())
+    with named_range("agg_update", rows=3):
+        assert str(source_info_util.current_name_stack()) == outside
+        lowered = jax.jit(lambda x: x + 1).lower(1.0).as_text(
+            debug_info=True)
+    assert "agg_update" not in lowered
 
 
 # -- program names -----------------------------------------------------------
