@@ -317,6 +317,22 @@ JOIN_MERGED_WINDOW_BATCHES = register_metric(
     "(utils/packed_sort.merge_windows: three single-operand sorts and two "
     "prefix scans, no gather) and not from a binary search per row; a "
     "host integer, never a sync")
+JOIN_OUTPUT_SPACE_BATCHES = register_metric(
+    "joinOutputSpaceBatches", COUNTER, ESSENTIAL,
+    "stream batches (mesh: finished stream chunks) of an inner, left or "
+    "full join whose pairs were placed from the output's side "
+    "(exec/join.py _gather_kernel: the count walk's verified-candidate "
+    "bits, one scatter and prefix scans; no key compared twice, no loop "
+    "over the stream batch); equals joinMergedWindowBatches for those "
+    "join types and stays 0 for semi and anti joins; a host integer")
+JOIN_WALK_STEPS = register_metric(
+    "joinWalkSteps", COUNTER, ESSENTIAL,
+    "static step counts of the count walks the join launched, summed: "
+    "the probe's speculative duplication bucket a stream batch, a "
+    "recount's bucket, the mesh drivers' max_dup a chunk and a retry; "
+    "each step gathers the build side's keys over the whole stream "
+    "batch, so against joinMergedWindowBatches it says how wide the "
+    "walks ran; a host integer")
 JOIN_HOST_SYNCS = register_metric(
     "joinHostSyncs", COUNTER, ESSENTIAL,
     "host reads of a device value the join made (exec/join.py): the "

@@ -529,15 +529,15 @@ def distributed_join_step(join, mesh: Mesh, max_dup: int, out_cap: int,
         build, bkeys, h1s = join._build_kernel(rex)
         lo, hi, max_dup_t = join._window_kernel(lex, h1s)
         dup_overflow = jnp.maximum(max_dup_t.astype(jnp.int32) - max_dup, 0)
-        counts, starts, total = join._count_kernel(
+        counts, starts, total, hits = join._count_kernel(
             max_dup, lex, build, bkeys, lo, hi, vary_axes=(axis,))
         if join.join_type in ("left_semi", "left_anti"):
             out = join._semi_kernel(lex, counts)
             out = ColumnarBatch(out.columns, out.sel, join._schema)
             cap_overflow = jnp.int32(0)
         else:
-            out = join._gather_kernel(max_dup, out_cap, lex, build, bkeys,
-                                      lo, hi, counts, starts, total,
+            out = join._gather_kernel(out_cap, lex, build, lo, counts,
+                                      starts, total, hits,
                                       vary_axes=(axis,))
             cap_overflow = jnp.maximum(total.astype(jnp.int32) - out_cap, 0)
         return (out, jax.lax.psum(lovf, axis), jax.lax.psum(rovf, axis),
@@ -546,6 +546,13 @@ def distributed_join_step(join, mesh: Mesh, max_dup: int, out_cap: int,
 
     return shard_map(step, mesh=mesh, in_specs=(P(axis), P(axis)),
                      out_specs=(P(axis), P(), P(), P(), P()))
+
+
+def _count_finished_chunk(join):
+    """The host counters of one stream chunk through the SPMD probe."""
+    join.metrics.add(MN.JOIN_MERGED_WINDOW_BATCHES, 1)
+    if join.join_type not in ("left_semi", "left_anti"):
+        join.metrics.add(MN.JOIN_OUTPUT_SPACE_BATCHES, 1)
 
 
 def run_distributed_join(join, mesh: Mesh, left: ColumnarBatch,
@@ -570,6 +577,7 @@ def run_distributed_join(join, mesh: Mesh, left: ColumnarBatch,
             quota_r, use_allgather)
         with mesh:
             out, l_ovf, r_ovf, dup_ovf, cap_ovf = step(left, right)
+        join.metrics.add(MN.JOIN_WALK_STEPS, max_dup)
         retry = False
         if not use_allgather and int(l_ovf) > 0:
             if quota_l >= lcap:  # pragma: no cover - cap always fits
@@ -589,7 +597,7 @@ def run_distributed_join(join, mesh: Mesh, left: ColumnarBatch,
             out_cap = out_cap * 2
             retry = True
         if not retry:
-            join.metrics.add(MN.JOIN_MERGED_WINDOW_BATCHES, 1)
+            _count_finished_chunk(join)
             return out
 
 
@@ -637,15 +645,15 @@ def distributed_join_probe_step(join, mesh: Mesh, max_dup: int,
         build, bkeys, h1s = join._build_kernel(rex)
         lo, hi, max_dup_t = join._window_kernel(lex, h1s)
         dup_overflow = jnp.maximum(max_dup_t.astype(jnp.int32) - max_dup, 0)
-        counts, starts, total = join._count_kernel(
+        counts, starts, total, hits = join._count_kernel(
             max_dup, lex, build, bkeys, lo, hi, vary_axes=(axis,))
         if join.join_type in ("left_semi", "left_anti"):
             out = join._semi_kernel(lex, counts)
             out = ColumnarBatch(out.columns, out.sel, join._schema)
             cap_overflow = jnp.int32(0)
         else:
-            out = join._gather_kernel(max_dup, out_cap, lex, build, bkeys,
-                                      lo, hi, counts, starts, total,
+            out = join._gather_kernel(out_cap, lex, build, lo, counts,
+                                      starts, total, hits,
                                       vary_axes=(axis,))
             cap_overflow = jnp.maximum(total.astype(jnp.int32) - out_cap, 0)
         return (out, jax.lax.psum(lovf, axis),
@@ -702,6 +710,7 @@ def run_distributed_join_streaming(join, mesh: Mesh, left_chunks,
                 quota_l, quota_r, use_allgather)
             with mesh:
                 out, l_ovf, dup_ovf, cap_ovf = pstep(chunk, rex)
+            join.metrics.add(MN.JOIN_WALK_STEPS, max_dup)
             if on_exchange is not None:
                 on_exchange(exchange_ici_bytes(
                     chunk, n, lcap if use_allgather else quota_l))
@@ -720,7 +729,7 @@ def run_distributed_join_streaming(join, mesh: Mesh, left_chunks,
                 retry = True
             if not retry:
                 break
-        join.metrics.add(MN.JOIN_MERGED_WINDOW_BATCHES, 1)
+        _count_finished_chunk(join)
         yield out
 
 

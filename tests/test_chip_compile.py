@@ -232,6 +232,9 @@ def _largest(programs, name_part):
     ("minmax", "agg.whole_stage_bucket", 1 << 20),
     # the join's fused window+count kernel over a full probe batch
     ("q3_join", "join.hashjoin_probe", 1 << 20),
+    # its gather: the pairs placed from the output's side (a scatter,
+    # two scans, popcounts), the payload columns taken at output capacity
+    ("q3_join", "join.hashjoin_gather", 1 << 20),
     # the 64-bit sort: packed u64 keys, revenue (a double: the f32-pair
     # keys, where the f64 comparator took 9 minutes) DESC then o_orderdate
     ("q3_join", "sort.sort", 1 << 20),
@@ -250,6 +253,9 @@ def test_smoke_program_compiles_for_v5e(smoke_programs, one_chip,
         # from a merge (sorts and scans), where two binary searches were
         # a `while` of 21 dependent 1M-row gathers each (PR 30)
         assert len(re.findall(r"\bwhile\(", compiled.as_text())) <= 1
+    if kernel == "join.hashjoin_gather":
+        # no walk: nothing loops over the stream batch (PR 36)
+        assert not re.findall(r"\bwhile\(", compiled.as_text())
 
 
 def test_f64_bitcast_is_what_the_tpu_branches_avoid(one_chip,

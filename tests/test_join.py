@@ -366,3 +366,172 @@ def test_left_outer_alias_matches_left():
 
     from compare import assert_rows_equal
     assert_rows_equal(q("left"), q("left_outer"))
+
+
+# --------------------------------------------------------------------------
+# the probe kernels against a plain pairing (PR 36: pairs placed in output
+# space from the count walk's verified-candidate bits)
+# --------------------------------------------------------------------------
+
+def _find_join(node):
+    from spark_rapids_tpu.exec.join import TpuHashJoinExec
+    if isinstance(node, TpuHashJoinExec):
+        return node
+    for c in node.children:
+        got = _find_join(c)
+        if got is not None:
+            return got
+    return None
+
+
+def _slot_rows(batch):
+    """Every slot of the batch as a row, dead ones too, and the live mask."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar import ColumnarBatch
+    every = ColumnarBatch(batch.columns,
+                          jnp.ones(batch.capacity, jnp.bool_), batch.schema)
+    return every.to_pylist(), np.asarray(batch.sel)
+
+
+def _pairing_case(case, tag):
+    """-> (left columns, right columns, key type).  Column names carry
+    `tag`: the kernels are cached by expression and schema, and one case
+    traces them under forged hashes."""
+    rng = random.Random(len(case) * 1000 + 36)
+    kt = T.StringType if case == "string_key" else T.LongType
+    n_l, n_r = 90, 70
+
+    def key(v):
+        return f"key-{v:03d}" if kt is T.StringType else v
+
+    if case == "unique_keys":
+        lk = rng.sample(range(200), n_l)
+        rk = rng.sample(range(200), n_r)
+    elif case == "many_to_many_dup_3":
+        lk = [v for v in range(30) for _ in range(3)]
+        rk = [v for v in range(10, 34) for _ in range(3)][:n_r]
+        rng.shuffle(lk)
+        rng.shuffle(rk)
+    elif case == "dup_above_32":
+        lk = [7] * 3 + rng.sample(range(100, 300), n_l - 3)
+        rk = [7] * 40 + rng.sample(range(100, 300), n_r - 40)
+        rng.shuffle(lk)
+        rng.shuffle(rk)
+    elif case == "out_cap_larger_than_total":
+        lk = list(range(n_l))
+        rk = [5, 5, 41] + list(range(1000, 1000 + n_r - 3))
+    else:
+        lk = [rng.randint(0, 25) for _ in range(n_l)]
+        rk = [rng.randint(5, 30) for _ in range(n_r)]
+    lk = [key(v) for v in lk]
+    rk = [key(v) for v in rk]
+    if case == "null_and_dead_rows":
+        lk = [None if rng.random() < 0.15 else v for v in lk]
+        rk = [None if rng.random() < 0.15 else v for v in rk]
+    left = {f"k_{tag}": lk, f"a_{tag}": list(range(len(lk)))}
+    right = {f"kr_{tag}": rk, f"b_{tag}": [1000 + j for j in range(len(rk))]}
+    if case == "with_condition":
+        left[f"x_{tag}"] = [rng.randint(0, 9) for _ in lk]
+        right[f"y_{tag}"] = [rng.randint(0, 9) for _ in rk]
+    return left, right, kt
+
+
+@pytest.mark.parametrize("case", [
+    "unique_keys", "many_to_many_dup_3", "window_wider_than_matches",
+    "dup_above_32", "null_and_dead_rows", "string_key", "with_condition",
+    "out_cap_larger_than_total"])
+@pytest.mark.parametrize("how", ["inner", "left", "full"])
+def test_probe_kernels_against_plain_pairing(how, case, monkeypatch):
+    """The output batch row for row, IN ORDER: for each live stream row in
+    order, the build rows of its window whose key is equal (and whose pair
+    passes the condition), in window order, which is the hash-sorted build
+    side's; a `left` / `full` row without one comes out once with the
+    right side null, in its place; the `full` tail is the never-matched
+    build rows in that order."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.exec import join as J
+    from spark_rapids_tpu.exec.base import ExecContext
+
+    if case == "window_wider_than_matches":
+        real = J.hash_columns_double
+
+        def forged(cols, sel):
+            # 4 prefixes with the low bits free: a window holds about a
+            # quarter of the build side, nearly all of it other keys
+            h1, h2 = real(cols, sel)
+            h = ((h1 >> jnp.uint64(62)) << jnp.uint64(62)) \
+                | (h1 & jnp.uint64(0xFF))
+            return jnp.where(sel, h, jnp.uint64(2**64 - 1)), h2
+        monkeypatch.setattr(J, "hash_columns_double", forged)
+
+    tag = f"{case[:6]}{len(case)}_{how}"
+    ldata, rdata, kt = _pairing_case(case, tag)
+    s = TpuSession({})
+
+    def frame(data):
+        return s.from_pydict(data, T.Schema(
+            [T.StructField(n, kt if n.startswith("k") else T.LongType)
+             for n in data]))
+    on = col(f"k_{tag}") == col(f"kr_{tag}")
+    if case == "with_condition":
+        # the planner keeps conditional outer joins off the device; the
+        # kernels' per-pair condition is the same for every type, so the
+        # inner plan's node stands in with its type set
+        on = on & (col(f"x_{tag}") > col(f"y_{tag}"))
+        join = _find_join(s.plan(frame(ldata).join(frame(rdata), on,
+                                                   "inner").plan))
+        join.join_type = how
+    else:
+        join = _find_join(s.plan(frame(ldata).join(frame(rdata), on,
+                                                   how).plan))
+    assert join is not None
+    ctx = ExecContext(s.conf, s.runtime)
+    (lb,) = list(join.children[0].execute(ctx))
+    (rb,) = list(join.children[1].execute(ctx))
+    if case == "null_and_dead_rows":
+        rng = np.random.default_rng(36)
+        lb = lb.with_sel(lb.sel & jnp.asarray(rng.random(lb.capacity) > 0.2))
+        rb = rb.with_sel(rb.sel & jnp.asarray(rng.random(rb.capacity) > 0.2))
+
+    # the reference: window order is the hash-sorted build side's
+    sorted_build, _bkeys, _h = jax.jit(join._build_kernel)(rb)
+    brows, blive = _slot_rows(sorted_build)
+    lrows, llive = _slot_rows(lb)
+    lnames, rnames = lb.schema.names, rb.schema.names
+    lk_i = [i for i, n in enumerate(lnames) if n.startswith("k")][0]
+    rk_i = [i for i, n in enumerate(rnames) if n.startswith("k")][0]
+
+    def passes(lrow, brow):
+        if lrow[lk_i] is None or lrow[lk_i] != brow[rk_i]:
+            return False
+        if case != "with_condition":
+            return True
+        both = dict(zip(lnames + rnames, lrow + brow))
+        return both[f"x_{tag}"] > both[f"y_{tag}"]
+
+    expect, hit = [], np.zeros(len(brows), bool)
+    for i, lrow in enumerate(lrows):
+        if not llive[i]:
+            continue
+        found = [j for j, brow in enumerate(brows)
+                 if blive[j] and passes(lrow, brow)]
+        hit[found] = True
+        expect += [lrow + brows[j] for j in found]
+        if not found and how != "inner":
+            expect.append(lrow + (None,) * len(rnames))
+    if how == "full":
+        expect += [(None,) * len(lnames) + brow
+                   for j, brow in enumerate(brows) if blive[j] and not hit[j]]
+    assert expect, "the case produces no row"
+
+    got = [row for out in join._join_stream(rb, [lb]) for row in
+           out.to_pylist()]
+    assert got == expect
+    if case == "dup_above_32":
+        assert join._dup_guess == 64                    # two words a row
+    if case == "window_wider_than_matches":
+        assert join._dup_guess >= 16

@@ -4,7 +4,9 @@ merge of build and stream hashes where a binary search a row ran (PR 30).
 The helper against numpy's searchsorted, the structure of the programs
 that call it (no loop that gathers from the build hashes, a gather count
 that does not grow with the build side), and joined answers where many
-different keys share one hash prefix.
+different keys share one hash prefix.  And the structure of the gather
+program behind it (PR 36): pairs placed from the output's side, no loop
+that gathers or scatters over the stream batch.
 """
 import numpy as np
 import pytest
@@ -224,6 +226,154 @@ def test_structure_checks_see_a_binary_search():
     assert _gathers(large) > _gathers(small) >= 10
 
 
+def _indexed_ops(jaxpr, in_loop=False):
+    """[(primitive, inside a loop?, rows of its indices)] for every gather
+    and scatter of the program, nested calls and loop bodies included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            found.append((name.split("-")[0], in_loop,
+                          eqn.invars[1].aval.shape[0]))
+        looping = in_loop or name in ("while", "scan")
+        for sub in _inner_jaxprs(eqn):
+            found += _indexed_ops(sub, looping)
+    return found
+
+
+def _walk_gather(join, max_dup, out_cap, lbatch, build, bkeys, lo, hi,
+                 counts, starts, total):
+    """The gather that went (`_gather_kernel` until PR 36), letter for
+    letter: every step of the walk gathers the build side's keys over the
+    whole STREAM batch, compares them again, and scatters three arrays of
+    stream capacity into the output slots."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar import ColumnarBatch
+    from spark_rapids_tpu.exec.join import _row_equal
+    from spark_rapids_tpu.types import Schema
+    lkeys = [e.eval(lbatch) for e in join.left_keys]
+    cap_b = build.capacity
+    live = lbatch.sel
+    blive = build.sel
+
+    l_idx = jnp.zeros(out_cap, jnp.int32)
+    b_idx = jnp.zeros(out_cap, jnp.int32)
+    matched = jnp.zeros(out_cap, jnp.bool_)
+    b_hit = jnp.zeros(cap_b, jnp.bool_)
+    rows = jnp.arange(lbatch.capacity, dtype=jnp.int32)
+
+    def body(d, carry):
+        l_out, b_out, m_out, bh, rank = carry
+        bidx = jnp.clip(lo + d, 0, cap_b - 1)
+        ok = live & ((lo + d) < hi) & jnp.take(blive, bidx, mode="clip")
+        for lk, bk in zip(lkeys, bkeys):
+            ok &= _row_equal(lk, bk, bidx)
+        if join.condition is not None:
+            ok &= join._pair_condition_ok(lbatch, build, bidx)
+        slot = jnp.where(ok, starts + rank, out_cap)  # out_cap = dropped
+        l_out = l_out.at[slot].set(rows, mode="drop")
+        b_out = b_out.at[slot].set(bidx, mode="drop")
+        m_out = m_out.at[slot].set(True, mode="drop")
+        bh = bh.at[jnp.where(ok, bidx, cap_b)].set(True, mode="drop")
+        return l_out, b_out, m_out, bh, rank + ok.astype(jnp.int32)
+
+    zero_rank = jnp.zeros(lbatch.capacity, jnp.int32)
+    l_idx, b_idx, matched, b_hit, _ = jax.lax.fori_loop(
+        0, max_dup, body, (l_idx, b_idx, matched, b_hit, zero_rank))
+    if join.join_type in ("left", "full"):
+        slot = jnp.where(live, starts, out_cap)
+        already = jnp.take(matched, jnp.clip(slot, 0, out_cap - 1),
+                           mode="clip")
+        slot = jnp.where(already, out_cap, slot)
+        l_idx = l_idx.at[slot].set(rows, mode="drop")
+
+    sel = jnp.arange(out_cap, dtype=jnp.int32) < total
+    lcols = [c.take(l_idx) for c in lbatch.columns]
+    rcols = []
+    for c in build.columns:
+        taken = c.take(b_idx)
+        rcols.append(taken.with_valid(taken.valid & matched)
+                     .mask_invalid())
+    lfields, rfields = join._joined_fields(lbatch.schema, build.schema)
+    joined = ColumnarBatch(lcols + rcols, sel, Schema(lfields + rfields))
+    out = ColumnarBatch(joined.columns, joined.sel, join._schema)
+    if join.join_type == "full":
+        return out, b_hit
+    return out
+
+
+_STREAM_CAP = 4096
+
+
+def _gather_programs(how, max_dup, out_cap=1 << 17):
+    """-> (the gather program's jaxpr, the walk form's, a function that
+    runs both on one batch): keys of 0..49 duplicate about twenty times
+    a side, so a walk of `max_dup` 64 covers every window and the output
+    is some 80,000 rows of a 4,096-row stream batch."""
+    import jax
+    join, lb, rb = _join_and_batches(1024, _STREAM_CAP, how)
+    build, bkeys, h1s = jax.jit(join._build_kernel)(rb)
+    lo, hi, counts, starts, hits, scalars = jax.jit(
+        lambda *a: join._probe_kernel(max_dup, *a))(lb, build, bkeys, h1s)
+    total = scalars[1]
+    assert max_dup < 64 or int(scalars[0]) <= 64 and int(total) <= out_cap
+
+    def placed(lb, build, lo, counts, starts, total, hits):
+        return join._gather_kernel(out_cap, lb, build, lo, counts, starts,
+                                   total, hits)
+
+    def walked(lb, build, bkeys, lo, hi, counts, starts, total):
+        return _walk_gather(join, max_dup, out_cap, lb, build, bkeys, lo,
+                            hi, counts, starts, total)
+    new_args = (lb, build, lo, counts, starts, total, hits)
+    old_args = (lb, build, bkeys, lo, hi, counts, starts, total)
+    return (jax.make_jaxpr(placed)(*new_args).jaxpr,
+            jax.make_jaxpr(walked)(*old_args).jaxpr,
+            lambda: (jax.jit(placed)(*new_args), jax.jit(walked)(*old_args)))
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "full"])
+def test_gather_program_places_pairs_without_a_walk(how):
+    """The finding PR 36 pins: one step of the gather walk cost 60 ms a
+    1M-row stream batch whatever the join put out (a 1M-row gather is 7.5
+    ms a `u32`, a scatter 5.6).  The gather program places its pairs from
+    the output's side: no gather or scatter inside any loop, as many of
+    them at a window width of 8 as at 1, and one scatter at most whose
+    indices have the stream batch's capacity (the rows' first slots)."""
+    narrow, _, _ = _gather_programs(how, 1)
+    wide, _, _ = _gather_programs(how, 8)
+    for jaxpr in (narrow, wide):
+        ops = _indexed_ops(jaxpr)
+        assert ops and not [op for op in ops if op[1]], ops
+        assert len([op for op in ops
+                    if op[0] == "scatter" and op[2] == _STREAM_CAP]) <= 1, ops
+    assert _indexed_ops(narrow) == _indexed_ops(wide)
+    assert _gathers(narrow) == _gathers(wide)
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "full"])
+def test_structure_checks_see_a_gather_walk(how):
+    """The checks above do fire on the form that went, and it was the
+    same join: both forms give the same rows in the same slots."""
+    import numpy as np
+    placed_jaxpr, narrow, _ = _gather_programs(how, 1)
+    _, wide, run = _gather_programs(how, 64)
+    in_loop = [op for op in _indexed_ops(wide) if op[1]]
+    assert len([op for op in in_loop if op[0] == "gather"]) >= 3
+    assert len([op for op in in_loop
+                if op[0] == "scatter" and op[2] == _STREAM_CAP]) >= 3
+    assert _gathers(wide) >= _gathers(narrow) + 63 * 3
+    assert _gathers(placed_jaxpr) < _gathers(narrow)
+    placed, walked = run()
+    if how == "full":
+        np.testing.assert_array_equal(np.asarray(placed[1]),
+                                      np.asarray(walked[1]))
+        placed, walked = placed[0], walked[0]
+    assert int(placed.num_rows()) > 1024
+    assert placed.to_pylist() == walked.to_pylist()
+
+
 # --------------------------------------------------------------------------
 # answers where many different keys share a hash prefix
 # --------------------------------------------------------------------------
@@ -291,3 +441,28 @@ def test_merged_window_counter_counts_probe_batches():
     moved = s.query_metrics_total["joinMergedWindowBatches"] \
         - before.get("joinMergedWindowBatches", 0)
     assert moved >= 1
+
+
+@pytest.mark.parametrize("how", ["inner", "left_semi"])
+def test_output_space_and_walk_step_counters(how):
+    """`joinOutputSpaceBatches` is `joinMergedWindowBatches` where pairs
+    are placed (inner, left, full) and 0 for a semi join; `joinWalkSteps`
+    sums the step counts the count walks were asked for: the first batch
+    probes at 8 and reads a window width of 1, the three after it at 1."""
+    from spark_rapids_tpu.engine import TpuSession
+    s = TpuSession({"spark.rapids.sql.reader.batchSizeRows": "128"})
+    left = s.from_pydict({"kc": list(range(500))},
+                         T.Schema([T.StructField("kc", T.LongType)]))
+    right = s.from_pydict({"krc": list(range(0, 500, 5))},
+                          T.Schema([T.StructField("krc", T.LongType)]))
+    before = dict(s.query_metrics_total)
+    rows = left.join(right, col("kc") == col("krc"), how).collect()
+    assert len(rows) == 100
+
+    def moved(name):
+        return s.query_metrics_total.get(name, 0) - before.get(name, 0)
+    batches = moved("joinMergedWindowBatches")
+    assert batches >= 1
+    assert moved("joinOutputSpaceBatches") == \
+        (batches if how == "inner" else 0)
+    assert moved("joinWalkSteps") == 8 + (batches - 1)
