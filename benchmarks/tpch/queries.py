@@ -317,6 +317,12 @@ def q17(t):
 
 
 def q18(t):
+    """An equivalent rewrite of query 18, not the published form: the
+    `IN (subquery)` and the join to LINEITEM become ONE inner join to the
+    big orders, with the quantity carried from the subquery's aggregate
+    instead of summed again.  `chipbench/queries/q18.py` is the published
+    form (a `left_semi` join, LINEITEM joined again, the five-key
+    group-by)."""
     big = (t["lineitem"].group_by(col("l_orderkey"))
            .agg(F.sum(col("l_quantity")).alias("sum_qty"))
            .filter(col("sum_qty") > 300)

@@ -512,6 +512,7 @@ class TpuHashJoinExec(TpuExec):
                 semi_fn = cached_kernel(key + ("semi",),
                                         lambda: self._semi_kernel)
                 out = semi_fn(lb, counts)
+                self.metrics.add(MN.JOIN_SEMI_BATCHES, 1)
                 out = ColumnarBatch(out.columns, out.sel, self._schema)
                 return out, None, total
             out_cap = bucket_rows(max(total, 1))

@@ -445,6 +445,17 @@ def monitoring_doc() -> str:
         "launch that carries no such name is an eager `jnp` op outside any",
         "compiled program.",
         "",
+        "The benchmark (`chipbench/`) reads these names and counters.  Two",
+        "of its per-layer metrics, by example: `join_semi_batches_per_query`",
+        "is the counter `joinSemiBatches` a query (a left semi or anti",
+        "join's stream batches through its membership mask, the program",
+        "`jit_join.hashjoin_semi`); `agg_whole_stage_roofline_share` is the",
+        "grouped whole-stage SORT program's share of its HBM roofline: the",
+        "bytes it must move over 819 GB/s, over the device time of the",
+        "launches named `jit_agg.whole_stage` (not",
+        "`jit_agg.whole_stage_bucket`).  `chipbench/README.md` and",
+        "`PERF.md` list the rest.",
+        "",
         "## Metric catalog",
         "",
         "Name | Kind | Level | Description",

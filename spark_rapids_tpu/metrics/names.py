@@ -325,6 +325,15 @@ JOIN_OUTPUT_SPACE_BATCHES = register_metric(
     "bits, one scatter and prefix scans; no key compared twice, no loop "
     "over the stream batch); equals joinMergedWindowBatches for those "
     "join types and stays 0 for semi and anti joins; a host integer")
+JOIN_SEMI_BATCHES = register_metric(
+    "joinSemiBatches", COUNTER, ESSENTIAL,
+    "stream batches (mesh: finished stream chunks) of a left semi or left "
+    "anti join answered by the membership mask (exec/join.py "
+    "_semi_kernel, jit_join.hashjoin_semi: the stream batch with its "
+    "selection cut to the rows the count walk matched, or did not; no "
+    "gather); added on the host where the mask's program is launched; "
+    "joinOutputSpaceBatches plus this equals joinMergedWindowBatches; a "
+    "host integer")
 JOIN_WALK_STEPS = register_metric(
     "joinWalkSteps", COUNTER, ESSENTIAL,
     "static step counts of the count walks the join launched, summed: "
