@@ -143,15 +143,19 @@ def group_rows(key_cols: Sequence[Column], live, value_cols=None):
     """-> (order, gid_sorted, boundary_sorted, num_groups).
 
     order: stable permutation putting equal keys adjacent, dead rows last.
-    gid_sorted[i]: group id of sorted position i (garbage for dead rows).
+    gid_sorted[i]: group id of sorted position i (garbage for dead rows),
+    `cumsum(boundary) - 1`: it rises by one at each group's first row, so
+    once the callers move dead rows to cap - 1 it is sorted and
+    `_seg_bounds` reads each group's rows off the places it changes.
     `value_cols`: optional minor sort keys — equal values land adjacent
     WITHIN each group (the distinct-aggregate dedup needs this)."""
     from ..utils.packed_sort import stable_argsort
     cap = live.shape[0]
     if not key_cols and not value_cols:
         # one group — but the contract (dead rows LAST) must still hold:
-        # merge states interleave live/dead rows, and the searchsorted
-        # segmented reducers require gid sorted after the dead->cap-1 remap
+        # merge states interleave live/dead rows, and the segmented
+        # reducers read their bounds off gid, sorted after the dead->cap-1
+        # remap
         order = stable_argsort([((~live).astype(jnp.uint64), 1)], cap)
         gid = jnp.zeros(cap, dtype=jnp.int32)
         live_s = jnp.take(live, order)
@@ -216,67 +220,134 @@ def _shift1_rows(m):
 # segment reducers (sorted ids, masked)
 # --------------------------------------------------------------------------
 #
-# INTEGER sums/counts exploit sortedness: prefix-sum + two searchsorted
-# gathers instead of XLA scatter-add (scatter serializes on the TPU;
-# cumsum/compare/gather are native VPU shapes).  Exact even under int64
-# overflow — modular addition is associative, so a prefix DIFFERENCE wraps
-# to the same value the per-segment wrap produces.  FLOATS keep the
-# scatter: a segment sum as a difference of two running prefixes loses the
-# segment entirely once the running total dwarfs it (1e300-scale values in
-# a batch would absorb 1e5-scale segment sums to 0.0) — not an "order
-# variance" the variableFloatAgg conf covers, but catastrophic
-# cancellation.  min/max have no invertible prefix form and keep
-# segment_min/max.
+# The segment bounds come from the sorted ids' own boundaries
+# (`_seg_bounds`): a row whose id differs from its neighbour's starts or
+# ends a segment, and one scatter each places those rows.  Two binary
+# searches over every segment id would be a `while` of dependent gathers
+# (on a v5e 4.4 of the 10.15 s TPC-H Q18's sort program took, PERF.md).
+# Integer sums, min and max are a scan that RESTARTS at each segment's
+# first row (`_seg_scan`), read at the segment's last row: no scatter (a
+# scatter-min serializes on the TPU: 73 ms over 1M rows on a v5e where the
+# scan and its bounds take 26) and no difference of running prefixes (a
+# cumsum and two 64-bit gathers, 62 ms more than the bounds; PERF.md).
+# All three are exact in any order: integer sums wrap as per-segment
+# accumulation does, modular addition being associative.  FLOAT sums keep the scatter: it adds a segment's
+# rows in row order, which a scan's tree does not, and the streaming tier
+# answers bit for bit what a batch query would (three partial sums folded
+# as (a + b) + c, never a + (b + c)); a difference of running prefixes
+# would not even be close (a float segment vanishes under a 1e300-scale
+# running total: catastrophic cancellation, not an "order variance" the
+# variableFloatAgg conf covers).
 
-def _seg_multi(reqs, gid, cap):
+def _seg_bounds(gid, cap):
+    """-> (start, end): int32 [cap], segment g is rows [start[g], end[g])
+    of sorted `gid`; an id no row carries has start == end == 0.
+
+    A row starts a segment where its id differs from the row before's
+    (row 0 always) and ends one where it differs from the row after's
+    (the last row always); one scatter each puts those rows at their ids,
+    every other row goes out of range and is dropped.  Nothing searches
+    and nothing loops, and ids past `cap` (fewer segments than rows) are
+    dropped the same way."""
+    n = gid.shape[0]
+    rows = jnp.arange(n, dtype=jnp.int32)
+    step = gid[1:] != gid[:-1]
+    first = jnp.concatenate([jnp.ones(1, jnp.bool_), step])
+    last = jnp.concatenate([step, jnp.ones(1, jnp.bool_)])
+    start = jnp.zeros(cap, jnp.int32).at[jnp.where(first, gid, cap)].set(
+        rows, mode="drop")
+    end = jnp.zeros(cap, jnp.int32).at[jnp.where(last, gid, cap)].set(
+        rows + 1, mode="drop")
+    return start, end
+
+
+def _first_rows(bounds, ngroups, cap):
+    """Sorted position of each group's first row (a group's rows are all
+    live, dead rows sort last); cap - 1 for the slots past `ngroups`."""
+    return jnp.where(jnp.arange(cap, dtype=jnp.int32) < ngroups,
+                     bounds[0], cap - 1)
+
+
+def _seg_scan(scans, first):
+    """Inclusive scans that restart at every `first` row: `scans` is a
+    list of (combine, values), one result each.  Step j combines a row
+    with the row 2**j before it unless a segment starts in between, so
+    after ceil(log2 n) steps each row holds its segment's reduction up to
+    itself (Hillis-Steele); a step is shifts and selects, nothing loops
+    on the device and nothing scatters."""
+    n = first.shape[0]
+    vals = [v for _, v in scans]
+    k = 1
+    while k < n:
+        vals = [jnp.where(first, v, combine(
+                    jnp.concatenate([v[:k], v[:-k]]), v))
+                for (combine, _), v in zip(scans, vals)]
+        first = first | jnp.concatenate([jnp.ones(k, jnp.bool_),
+                                         first[:-k]])
+        k *= 2
+    return vals
+
+
+def _reduce_identity(op, dtype):
+    """What segment_sum/min/max give a segment no row carries."""
+    if op == "sum":
+        return jnp.zeros((), dtype)
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.asarray(jnp.inf if op == "min" else -jnp.inf, dtype)
+    info = jnp.iinfo(dtype)
+    return jnp.asarray(info.max if op == "min" else info.min, dtype)
+
+
+_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def _seg_multi(reqs, gid, cap, bounds=None):
     """All requested segmented reductions over sorted `gid`.
 
     `reqs`: list of (op, vals, contribute, fill) with op in
     'sum'|'min'|'max' — contribute masks rows out (sum: add 0; min/max:
-    compare fill).  Returns one [cap] array per request: integer sums
-    via prefix-diff, float sums via scatter segment_sum (a restart-free
-    prefix would cancel catastrophically), min/max via segment_min/max —
-    sharing one searchsorted pair across every request."""
+    compare fill).  Returns one [cap] array per request, what
+    segment_sum/min/max give: float sums BY that scatter (row order),
+    the rest as restarting scans read at each segment's last row, which
+    share one shifted segment-start mask.  `bounds`: `_seg_bounds(gid,
+    cap)`, made here where the caller has none to share."""
     n = gid.shape[0]
-    results = []
-    seg = jnp.arange(cap, dtype=gid.dtype)
-    start = jnp.searchsorted(gid, seg, side="left")
-    end = jnp.searchsorted(gid, seg, side="right")
-    end_ix = jnp.clip(end - 1, 0, n - 1)
+    start, end = _seg_bounds(gid, cap) if bounds is None else bounds
+    last_row = jnp.clip(end - 1, 0, n - 1)
     nonempty = end > start
-    for op, vals, contribute, fill in reqs:
+    results = [None] * len(reqs)
+    scans, scanned = [], []
+    for i, (op, vals, contribute, fill) in enumerate(reqs):
         if op == "sum":
-            v = jnp.where(contribute, vals, jnp.zeros((), vals.dtype))
-            if jnp.issubdtype(vals.dtype, jnp.floating):
-                results.append(jax.ops.segment_sum(
-                    v, gid, num_segments=cap, indices_are_sorted=True))
-                continue
-            c = jnp.cumsum(v)
-            zero = jnp.zeros((), c.dtype)
-            total = jnp.where(end > 0, c[end_ix], zero)
-            prev = jnp.where(start > 0, c[jnp.clip(start - 1, 0, n - 1)],
-                             zero)
-            results.append(jnp.where(nonempty, total - prev,
-                                     zero).astype(vals.dtype))
+            fill = jnp.zeros((), vals.dtype)
+        v = jnp.where(contribute, vals, fill)
+        if op == "sum" and jnp.issubdtype(vals.dtype, jnp.floating):
+            results[i] = jax.ops.segment_sum(v, gid, num_segments=cap,
+                                             indices_are_sorted=True)
         else:
-            v = jnp.where(contribute, vals, fill)
-            reducer = (jax.ops.segment_min if op == "min"
-                       else jax.ops.segment_max)
-            results.append(reducer(v, gid, num_segments=cap,
-                                   indices_are_sorted=True))
+            scans.append((_COMBINE[op], v))
+            scanned.append(i)
+    if scans:
+        first = jnp.concatenate([jnp.ones(1, jnp.bool_),
+                                 gid[1:] != gid[:-1]])
+        for i, r in zip(scanned, _seg_scan(scans, first)):
+            results[i] = jnp.where(nonempty, jnp.take(r, last_row),
+                                   _reduce_identity(reqs[i][0], r.dtype))
     return results
 
 
-def _seg_sum(vals, gid, contribute, cap):
-    return _seg_multi([("sum", vals, contribute, 0)], gid, cap)[0]
+def _seg_sum(vals, gid, contribute, cap, bounds=None):
+    return _seg_multi([("sum", vals, contribute, 0)], gid, cap, bounds)[0]
 
 
-def _seg_min(vals, gid, contribute, cap, fill):
-    return _seg_multi([("min", vals, contribute, fill)], gid, cap)[0]
+def _seg_min(vals, gid, contribute, cap, fill, bounds=None):
+    return _seg_multi([("min", vals, contribute, fill)], gid, cap,
+                      bounds)[0]
 
 
-def _seg_max(vals, gid, contribute, cap, fill):
-    return _seg_multi([("max", vals, contribute, fill)], gid, cap)[0]
+def _seg_max(vals, gid, contribute, cap, fill, bounds=None):
+    return _seg_multi([("max", vals, contribute, fill)], gid, cap,
+                      bounds)[0]
 
 
 class _AggState:
@@ -298,12 +369,13 @@ class _AggState:
         raise NotImplementedError(f)
 
 
-def _update_one(agg: AggregateExpression, col, gid, live_s, cap,
+def _update_one(agg: AggregateExpression, col, gid, live_s, cap, bounds,
                 dedup=None):
     """Compute state columns for one aggregate from sorted input values.
 
     `dedup`: for distinct aggregates, the is-first-occurrence-of-(group,
-    value) mask over sorted rows — duplicate values contribute nothing."""
+    value) mask over sorted rows — duplicate values contribute nothing.
+    `bounds`: the kernel's `_seg_bounds(gid, cap)`."""
     f = agg.func
     if f == "Count":
         if col is None:  # count(*)
@@ -312,7 +384,8 @@ def _update_one(agg: AggregateExpression, col, gid, live_s, cap,
             contribute = live_s & col.valid
         if agg.distinct and dedup is not None:
             contribute = contribute & dedup
-        cnt = _seg_sum(contribute.astype(jnp.int64), gid, live_s, cap)
+        cnt = _seg_sum(contribute.astype(jnp.int64), gid, live_s, cap,
+                       bounds)
         return [Column(cnt, jnp.ones(cap, jnp.bool_), LongType)]
     valid = col.valid
     contribute = live_s & valid
@@ -325,7 +398,7 @@ def _update_one(agg: AggregateExpression, col, gid, live_s, cap,
         s, nvalid = _seg_multi(
             [("sum", v, contribute, 0),
              ("sum", contribute.astype(jnp.int64), live_s, 0)],
-            gid, cap)
+            gid, cap, bounds)
         sum_col = Column(s, nvalid > 0, out_t).mask_invalid()
         if f == "Sum":
             return [sum_col]
@@ -333,8 +406,9 @@ def _update_one(agg: AggregateExpression, col, gid, live_s, cap,
     if f in ("Min", "Max"):
         # distinct is a no-op for min/max
         if agg.child.dtype.is_string:
-            return [_minmax_string(f, col, gid, contribute, cap)]
-        return [_minmax(f, agg.child.dtype, col.data, gid, contribute, cap)]
+            return [_minmax_string(f, col, gid, contribute, cap, bounds)]
+        return [_minmax(f, agg.child.dtype, col.data, gid, contribute, cap,
+                        bounds)]
     raise NotImplementedError(f)
 
 
@@ -354,31 +428,33 @@ def _string_order_keys(col: Column):
     return keys
 
 
-def _minmax_string(f, scol: Column, gid, contribute, cap):
+def _minmax_string(f, scol: Column, gid, contribute, cap, bounds):
     """Per-group lexicographic min/max of a string column: iterated
     segmented reductions narrow the candidate set one 8-byte word at a
     time, then the winning row's bytes are gathered (the byte-matrix
     segment reduction the round-1 verdict flagged as pending)."""
     keys = _string_order_keys(scol)
     nvalid = _seg_sum(contribute.astype(jnp.int64), gid,
-                      jnp.ones_like(contribute), cap)
+                      jnp.ones_like(contribute), cap, bounds)
     cand = contribute
     gidc = jnp.clip(gid, 0, cap - 1)
     for k in keys:
         if f == "Min":
-            best = _seg_min(k, gid, cand, cap, jnp.int64(_I64_MAX))
+            best = _seg_min(k, gid, cand, cap, jnp.int64(_I64_MAX),
+                            bounds)
         else:
-            best = _seg_max(k, gid, cand, cap, jnp.int64(_I64_MIN))
+            best = _seg_max(k, gid, cand, cap, jnp.int64(_I64_MIN),
+                            bounds)
         cand = cand & (k == jnp.take(best, gidc))
     rowpos = jnp.arange(cap, dtype=jnp.int64)
     win = _seg_min(jnp.where(cand, rowpos, _I64_MAX), gid,
-                   jnp.ones_like(cand), cap, jnp.int64(_I64_MAX))
+                   jnp.ones_like(cand), cap, jnp.int64(_I64_MAX), bounds)
     widx = jnp.clip(win, 0, cap - 1).astype(jnp.int32)
     out = scol.take(widx)
     return out.with_valid(nvalid > 0).mask_invalid()
 
 
-def _minmax(f, dtype, vals, gid, contribute, cap):
+def _minmax(f, dtype, vals, gid, contribute, cap, bounds=None):
     ones = jnp.ones_like(contribute)
     if dtype.is_floating:
         v = vals.astype(jnp.float64)
@@ -391,7 +467,7 @@ def _minmax(f, dtype, vals, gid, contribute, cap):
                  ("sum", contribute.astype(jnp.int64), ones, 0),
                  ("sum", (contribute & ~isnan).astype(jnp.int32), ones, 0),
                  ("min", jnp.where(isnan, jnp.inf, v), contribute,
-                  jnp.float64(np.inf))], gid, cap)
+                  jnp.float64(np.inf))], gid, cap, bounds)
             # NaN only wins min when the group has NO non-NaN values
             # (min(+inf, NaN) is +inf: NaN is greatest)
             only_nan = (has_nan_i > 0) & (n_non_nan == 0)
@@ -402,7 +478,7 @@ def _minmax(f, dtype, vals, gid, contribute, cap):
                   jnp.int32(0)),
                  ("sum", contribute.astype(jnp.int64), ones, 0),
                  ("max", jnp.where(isnan, -jnp.inf, v), contribute,
-                  jnp.float64(-np.inf))], gid, cap)
+                  jnp.float64(-np.inf))], gid, cap, bounds)
             r = jnp.where(has_nan_i > 0, jnp.nan, r)  # NaN is greatest
         out = r.astype(dtype.jnp_dtype)
         return Column(out, nvalid > 0, dtype).mask_invalid()
@@ -410,11 +486,13 @@ def _minmax(f, dtype, vals, gid, contribute, cap):
     if f == "Min":
         nvalid, r = _seg_multi(
             [("sum", contribute.astype(jnp.int64), ones, 0),
-             ("min", v, contribute, jnp.int64(_I64_MAX))], gid, cap)
+             ("min", v, contribute, jnp.int64(_I64_MAX))], gid, cap,
+            bounds)
     else:
         nvalid, r = _seg_multi(
             [("sum", contribute.astype(jnp.int64), ones, 0),
-             ("max", v, contribute, jnp.int64(_I64_MIN))], gid, cap)
+             ("max", v, contribute, jnp.int64(_I64_MIN))], gid, cap,
+            bounds)
     return Column(r.astype(dtype.jnp_dtype), nvalid > 0, dtype) \
         .mask_invalid()
 
@@ -498,12 +576,11 @@ class TpuHashAggregateExec(TpuExec):
         live_s = jnp.take(live, order)
         gid = jnp.where(live_s, gid, cap - 1)
 
+        # every segmented reduction of this kernel shares one set of bounds
+        bounds = _seg_bounds(gid, cap)
         state_cols: List[Column] = []
         # group keys: first row of each group (the boundary rows, compacted)
-        first_pos = _seg_min(jnp.arange(cap, dtype=jnp.int64), gid,
-                             live_s, cap, jnp.int64(_I64_MAX))
-        first_idx = jnp.take(order,
-                             jnp.clip(first_pos, 0, cap - 1).astype(jnp.int32))
+        first_idx = jnp.take(order, _first_rows(bounds, ngroups, cap))
         for k in keys:
             state_cols.append(k.take(first_idx))
         for a in self.aggregates:
@@ -520,9 +597,10 @@ class TpuHashAggregateExec(TpuExec):
                 pos = jnp.take(rank_orig, order)
                 if f == "First":
                     best = _seg_min(pos, gid, live_s, cap,
-                                    jnp.int64(_I64_MAX))
+                                    jnp.int64(_I64_MAX), bounds)
                 else:
-                    best = _seg_max(pos, gid, live_s, cap, jnp.int64(-1))
+                    best = _seg_max(pos, gid, live_s, cap, jnp.int64(-1),
+                                    bounds)
                 # original index of the winning row: sorted position whose
                 # pos equals the group's best
                 is_best = live_s & (pos == jnp.take(best,
@@ -530,7 +608,8 @@ class TpuHashAggregateExec(TpuExec):
                                                              cap - 1)))
                 rowpos = jnp.arange(cap, dtype=jnp.int64)
                 win_sorted = _seg_min(jnp.where(is_best, rowpos, _I64_MAX),
-                                      gid, live_s, cap, jnp.int64(_I64_MAX))
+                                      gid, live_s, cap, jnp.int64(_I64_MAX),
+                                      bounds)
                 widx = jnp.take(
                     order, jnp.clip(win_sorted, 0, cap - 1).astype(jnp.int32))
                 state_cols.append(col.take(widx))
@@ -539,7 +618,7 @@ class TpuHashAggregateExec(TpuExec):
                                          LongType))
             else:
                 state_cols.extend(_update_one(a, scol, gid, live_s, cap,
-                                              dedup=dedup))
+                                              bounds, dedup=dedup))
         sel = jnp.arange(cap, dtype=jnp.int32) < ngroups
         # zero out dead state rows
         state_cols = [c.with_valid(c.valid & sel).mask_invalid()
@@ -766,11 +845,9 @@ class TpuHashAggregateExec(TpuExec):
         order, gid, boundary, ngroups = group_rows(keys, live)
         live_s = jnp.take(live, order)
         gid = jnp.where(live_s, gid, cap - 1)
+        bounds = _seg_bounds(gid, cap)
         out_cols: List[Column] = []
-        first_pos = _seg_min(jnp.arange(cap, dtype=jnp.int64), gid,
-                             live_s, cap, jnp.int64(_I64_MAX))
-        first_idx = jnp.take(order,
-                             jnp.clip(first_pos, 0, cap - 1).astype(jnp.int32))
+        first_idx = jnp.take(order, _first_rows(bounds, ngroups, cap))
         for k in keys:
             out_cols.append(k.take(first_idx))
         ci = nkeys
@@ -781,7 +858,8 @@ class TpuHashAggregateExec(TpuExec):
             ci += nfields
             if f == "Count":
                 scol = cols[0].take(order)
-                s = _seg_sum(scol.data, gid, live_s & scol.valid, cap)
+                s = _seg_sum(scol.data, gid, live_s & scol.valid, cap,
+                             bounds)
                 out_cols.append(Column(s, jnp.ones(cap, jnp.bool_),
                                        LongType))
             elif f == "Sum":
@@ -790,7 +868,7 @@ class TpuHashAggregateExec(TpuExec):
                 s, nvalid = _seg_multi(
                     [("sum", scol.data, contribute, 0),
                      ("sum", contribute.astype(jnp.int64), live_s, 0)],
-                    gid, cap)
+                    gid, cap, bounds)
                 out_cols.append(Column(s, nvalid > 0, cols[0].dtype)
                                 .mask_invalid())
             elif f == "Average":
@@ -802,7 +880,7 @@ class TpuHashAggregateExec(TpuExec):
                 s, n = _seg_multi(
                     [("sum", scol.data, contribute, 0),
                      ("sum", ccol.data, live_s & ccol.valid, 0)],
-                    gid, cap)
+                    gid, cap, bounds)
                 out_cols.append(Column(s, n > 0, DoubleType).mask_invalid())
                 out_cols.append(Column(n, jnp.ones(cap, jnp.bool_),
                                        LongType))
@@ -811,24 +889,24 @@ class TpuHashAggregateExec(TpuExec):
                 contribute = live_s & scol.valid
                 if scol.dtype.is_string:
                     out_cols.append(_minmax_string(f, scol, gid, contribute,
-                                                   cap))
+                                                   cap, bounds))
                 else:
                     out_cols.append(_minmax(f, scol.dtype, scol.data, gid,
-                                            contribute, cap))
+                                            contribute, cap, bounds))
             elif f in ("First", "Last"):
                 vcol = cols[0].take(order)
                 pcol = cols[1].take(order)
                 if f == "First":
                     best = _seg_min(pcol.data, gid, live_s, cap,
-                                    jnp.int64(_I64_MAX))
+                                    jnp.int64(_I64_MAX), bounds)
                 else:
                     best = _seg_max(pcol.data, gid, live_s, cap,
-                                    jnp.int64(-1))
+                                    jnp.int64(-1), bounds)
                 is_best = live_s & (pcol.data == jnp.take(best, gid))
                 # position of the winning row in sorted order
                 rowpos = jnp.arange(cap, dtype=jnp.int64)
                 win = _seg_min(jnp.where(is_best, rowpos, _I64_MAX), gid,
-                               live_s, cap, jnp.int64(_I64_MAX))
+                               live_s, cap, jnp.int64(_I64_MAX), bounds)
                 widx = jnp.clip(win, 0, cap - 1).astype(jnp.int32)
                 out_cols.append(vcol.take(widx))
                 out_cols.append(Column(best, jnp.ones(cap, jnp.bool_),

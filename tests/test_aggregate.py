@@ -839,7 +839,8 @@ def test_seg_sum_float_keeps_scatter_semantics():
 
 
 def test_seg_sum_gather_matches_scatter():
-    """The searchsorted/prefix-sum segmented sum must equal XLA's
+    """The prefix-sum segmented sum, read at the bounds off the sorted ids,
+    must equal XLA's
     scatter-based segment_sum on sorted ids, including empty segments,
     masked rows, and the dead-rows-at-cap-1 convention."""
     import jax
@@ -943,3 +944,138 @@ def test_seg_multi_against_numpy(op, dtype):
     assert got.dtype == dt
     np.testing.assert_array_equal(got, want)
     assert want[1] == fill and want[7] == fill and abs(want[0]) > 0
+
+
+def _seg_layout(layout):
+    """(gid, cap) of one sorted-id layout the segment bounds must read."""
+    import numpy as np
+    rng = np.random.RandomState(5)
+    if layout == "every_row_its_group":      # ngroups == cap, no dead rows
+        return np.arange(512, dtype=np.int32), 512
+    if layout == "empty_between_live":       # ids 0, 3, 4, 9, ... skipped
+        live = np.sort(rng.choice([0, 3, 4, 9, 10, 11, 40], 400))
+        return np.concatenate([live, np.full(112, 511)]).astype(np.int32), 512
+    if layout == "single_live_row":
+        return np.concatenate([[0], np.full(511, 511)]).astype(np.int32), 512
+    if layout == "no_live_row":
+        return np.full(512, 511, np.int32), 512
+    if layout == "fewer_segments_than_rows":  # the keyless kernels' form
+        return np.zeros(1024, np.int32), 1
+    assert layout == "fewer_segments_two_ids"
+    return np.sort(rng.randint(0, 2, 1024)).astype(np.int32), 2
+
+
+_SEG_LAYOUTS = ["every_row_its_group", "empty_between_live",
+                "single_live_row", "no_live_row", "fewer_segments_than_rows",
+                "fewer_segments_two_ids"]
+
+
+@pytest.mark.parametrize("layout", _SEG_LAYOUTS)
+def test_seg_bounds_against_searchsorted(layout):
+    """Every id that rows carry: [start, end) is numpy's searchsorted
+    left/right; an id no row carries: start == end."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.exec.aggregate import _seg_bounds
+    gid, cap = _seg_layout(layout)
+    start, end = (np.asarray(x) for x in _seg_bounds(jnp.asarray(gid), cap))
+    ids = np.arange(cap)
+    present = np.isin(ids, gid)
+    np.testing.assert_array_equal(
+        start[present], np.searchsorted(gid, ids[present], "left"))
+    np.testing.assert_array_equal(
+        end[present], np.searchsorted(gid, ids[present], "right"))
+    assert (start[~present] == end[~present]).all()
+
+
+@pytest.mark.parametrize("dtype", ["int64", "float64"])
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+@pytest.mark.parametrize("layout", _SEG_LAYOUTS)
+def test_seg_multi_layouts(layout, op, dtype):
+    """Each reduction against a plain loop on the layouts the bounds must
+    read: every row its own group, empty ids between live ones, one live
+    row, none, and fewer segments than rows."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.exec.aggregate import _seg_multi
+    gid, cap = _seg_layout(layout)
+    rng = np.random.RandomState(23)
+    dt = np.dtype(dtype)
+    vals = rng.randint(-1000, 1000, gid.size).astype(dt)
+    contribute = rng.rand(gid.size) < 0.8
+    if dt.kind == "f":
+        lo, hi = dt.type(-np.inf), dt.type(np.inf)
+    else:
+        lo, hi = np.iinfo(dt).min, np.iinfo(dt).max
+    fill = {"sum": 0, "min": hi, "max": lo}[op]
+    want = np.full(cap, fill, dt)
+    for g, v, c in zip(gid, vals, contribute):
+        if not c:
+            continue
+        if op == "sum":
+            want[g] += v
+        else:
+            want[g] = min(want[g], v) if op == "min" else max(want[g], v)
+    [got] = _seg_multi([(op, jnp.asarray(vals), jnp.asarray(contribute),
+                         jnp.asarray(fill, dt))], jnp.asarray(gid), cap)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("kernel", ["update", "merge"])
+def test_group_keys_are_each_groups_first_row(kernel):
+    """The key columns of the sort path's update and merge, a group's
+    first row read off the segment bounds, equal the scatter-min form
+    (the smallest sorted position of a live row of the group, cap - 1
+    where none) on every row, dead ones included; keys carry nulls, a
+    string key too."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.exec import aggregate as A
+    from spark_rapids_tpu.exec.base import ExecContext
+    n = 96
+    data = {"k": [None if i % 5 == 0 else i % 7 for i in range(n)],
+            "s": [None if i % 4 == 1 else "ab"[i % 2] * (1 + i % 3)
+                  for i in range(n)],
+            "v": list(range(n))}
+    schema = T.Schema([T.StructField("k", T.LongType),
+                       T.StructField("s", T.StringType),
+                       T.StructField("v", T.LongType)])
+    s = TpuSession({**FLOAT_AGG,
+                    "spark.rapids.sql.reader.batchSizeRows": "32"})
+    q = s.from_pydict(data, schema).filter(col("v") % 3 != 1) \
+        .group_by("k", "s").agg(f.sum(col("v")).alias("sv"))
+    agg = _find_agg(s.plan(q.plan))
+    batches = list(agg.children[0].execute(ExecContext(s.conf,
+                                                       runtime=s.runtime)))
+    assert len(batches) == 3
+    if kernel == "update":
+        batch = batches[0]
+        keys = [g.eval(batch) for g in agg.grouping]
+        got = jax.jit(agg._update_kernel)(batch)
+    else:
+        # states stacked as the whole-stage program does: dead rows
+        # between live ones
+        batch = A._flatten_stacked(
+            A._stack_states([agg._update_kernel(b) for b in batches]),
+            agg._state_schema)
+        keys = list(batch.columns[:len(agg.grouping)])
+        got = jax.jit(agg._merge_kernel)(batch)
+    cap = batch.capacity
+    order, gid, _b, ngroups = A.group_rows(keys, batch.sel)
+    live_s = jnp.take(batch.sel, order)
+    gid = jnp.where(live_s, gid, cap - 1)
+    first_pos = jax.ops.segment_min(
+        jnp.where(live_s, jnp.arange(cap, dtype=jnp.int64), A._I64_MAX),
+        gid, num_segments=cap, indices_are_sorted=True)
+    first_idx = jnp.take(order, jnp.clip(first_pos, 0, cap - 1))
+    assert 0 < int(ngroups) < cap
+    for k, c in zip(keys, got.columns):
+        want = k.take(first_idx)
+        if not k.dtype.is_string:
+            want = want.with_valid(want.valid & got.sel).mask_invalid()
+        for a, b in ((c.data, want.data), (c.valid, want.valid),
+                     (c.lengths, want.lengths)):
+            if a is not None:
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
