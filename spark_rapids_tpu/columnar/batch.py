@@ -197,7 +197,7 @@ class ColumnarBatch:
             if pa.types.is_decimal(arr.type):
                 arr = pc.cast(arr, pa.float64())
             if dt.is_string:
-                cols.append(Column.from_strings(arr.to_pylist(), capacity=cap))
+                cols.append(Column.from_arrow_strings(arr, capacity=cap))
                 continue
             if pa.types.is_date32(arr.type):
                 arr = arr.view(pa.int32())

@@ -106,6 +106,50 @@ class Column:
                       jnp.asarray(lengths))
 
     @staticmethod
+    def from_arrow_strings(arr, capacity: Optional[int] = None) -> "Column":
+        """A pyarrow `string` or `large_string` Array (one chunk, any
+        `offset`) as the column `from_strings(arr.to_pylist())` builds,
+        byte for byte, read from the array's own buffers (validity bitmap,
+        offsets, data) with numpy: no Python loop over rows."""
+        import pyarrow as pa
+        n = len(arr)
+        if n == 0:
+            return Column.from_strings([], capacity=capacity)
+        cap = capacity if capacity is not None else n
+        bitmap, offsets, data = arr.buffers()[:3]
+        wide = pa.types.is_large_string(arr.type)
+        off_dtype = np.dtype(np.int64 if wide else np.int32)
+        offs = np.frombuffer(offsets, off_dtype, n + 1,
+                             arr.offset * off_dtype.itemsize).astype(np.int64)
+        if bitmap is None or arr.null_count == 0:
+            valid = np.ones(n, dtype=np.bool_)
+        else:
+            bits = np.unpackbits(np.frombuffer(bitmap, np.uint8),
+                                 bitorder="little")
+            valid = bits[arr.offset:arr.offset + n].astype(np.bool_)
+        # a null holds no bytes, whatever its slot in the offsets spans
+        lens = np.where(valid, np.diff(offs), 0)
+        ml = bucket_strlen(int(lens.max()))
+        matrix = np.zeros((cap, ml), dtype=np.uint8)
+        total = int(lens.sum())
+        src = np.frombuffer(data, np.uint8) if total else None
+        width = int(lens[0])
+        if total and valid.all() and (lens == width).all():
+            # one width, no nulls (codes, flags): the bytes lie row after row
+            matrix[:n, :width] = src[offs[0]:offs[0] + total].reshape(n, width)
+        elif total:
+            # every byte's row and its place in the row, then one gather
+            rows = np.repeat(np.arange(n), lens)
+            pos = np.arange(total) - np.repeat(np.cumsum(lens) - lens, lens)
+            matrix[rows, pos] = src[np.repeat(offs[:-1], lens) + pos]
+        lengths = np.zeros(cap, dtype=np.int32)
+        lengths[:n] = lens
+        vfull = np.zeros(cap, dtype=np.bool_)
+        vfull[:n] = valid
+        return Column(jnp.asarray(matrix), jnp.asarray(vfull), StringType,
+                      jnp.asarray(lengths))
+
+    @staticmethod
     def all_null(dtype: DataType, capacity: int, max_len: int = 8) -> "Column":
         valid = jnp.zeros(capacity, dtype=jnp.bool_)
         if dtype.is_string:

@@ -205,8 +205,14 @@ MEMORY_SCAN_CACHE_ENABLED = _conf(
     "transfer (TPU-native storage-layer cache; Spark analogue df.cache()).",
     _to_bool)
 MEMORY_SCAN_CACHE_SIZE = _conf(
-    "spark.rapids.sql.tpu.memoryScanCache.maxSize", 4 << 30,
-    "LRU byte bound on HBM held by the in-memory scan cache.", to_bytes)
+    "spark.rapids.sql.tpu.memoryScanCache.maxSize", 0,
+    "LRU byte bound on HBM held by the in-memory scan cache.  0 (the "
+    "default) means half of the session's accounted HBM pool "
+    "(spark.rapids.memory.tpu.poolSizeBytes, else allocFraction of the "
+    "device's own memory limit): storage's share of the unified region, "
+    "as Spark's spark.memory.storageFraction default of 0.5.  About 7 GiB "
+    "on a 16 GB TPU v5e.  A byte value > 0 is the bound as given.",
+    to_bytes)
 WHOLE_STAGE_ENABLED = _conf(
     "spark.rapids.sql.tpu.wholeStage.enabled", True,
     "Compile scan->rowLocal->aggregate stages over equal-capacity batches "

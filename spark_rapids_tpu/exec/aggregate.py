@@ -1359,8 +1359,9 @@ class TpuHashAggregateExec(TpuExec):
             # retry-only: partial states are merge inputs, not splittable
             # row ranges (splitting them would change nothing — the merge
             # concat is the allocation)
-            return run_retryable(ctx, self.metrics, "aggMerge",
-                                 attempt_merge, [None])[0]
+            with named_range("agg_fold", parts=len(parts)):
+                return run_retryable(ctx, self.metrics, "aggMerge",
+                                     attempt_merge, [None])[0]
 
         # if the whole-stage probe already drained the source, stream the
         # materialized batches through the child's per-batch kernel instead

@@ -71,23 +71,29 @@ class TpuScanMemoryExec(TpuExec):
         return self._schema
 
     def execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
-        from ..config import (MEMORY_SCAN_CACHE_ENABLED,
-                              MEMORY_SCAN_CACHE_SIZE)
-        from ..utils.scan_cache import MEMORY_SCAN_CACHE
+        from ..config import MEMORY_SCAN_CACHE_ENABLED
+        from ..utils.scan_cache import MEMORY_SCAN_CACHE, resident_bound
         E.clear_input_file()  # in-memory rows have no file provenance
         rows = self.table.num_rows
         limit = min(ctx.conf.get(MAX_READER_BATCH_SIZE_ROWS), 1 << 20)
         use_cache = ctx.conf.get(MEMORY_SCAN_CACHE_ENABLED)
-        max_cache = ctx.conf.get(MEMORY_SCAN_CACHE_SIZE)
         names = tuple(self._schema.names)
         if use_cache:
             cached = MEMORY_SCAN_CACHE.get(self._cache_table, names, limit)
             if cached is not None:
-                for batch, nrows in cached:
-                    self.metrics.add(MN.NUM_OUTPUT_ROWS, nrows)
-                    self.metrics.add(MN.NUM_OUTPUT_BATCHES, 1)
-                    yield batch
+                served = 0
+                try:
+                    for batch, nrows in cached:
+                        self.metrics.add(MN.NUM_OUTPUT_ROWS, nrows)
+                        self.metrics.add(MN.NUM_OUTPUT_BATCHES, 1)
+                        served += 1
+                        yield batch
+                finally:
+                    self.metrics.add(MN.SCAN_CACHE_HIT_BATCHES, served)
                 return
+            max_cache = resident_bound(ctx.conf)
+        # present and 0 where the table was uploaded
+        self.metrics.add(MN.SCAN_CACHE_HIT_BATCHES, 0)
         produced = []
         produced_bytes = 0
         off = 0

@@ -395,6 +395,12 @@ def monitoring_doc() -> str:
         "an aggregate with no grouping keys has none: its loop is one "
         "`srt:agg_update` a batch around one `jit_agg.stream_step` "
         "(`aggSyncFreeBatches`)",
+        "`srt:agg_fold` | `exec/aggregate.py`, the GROUPED streaming "
+        "loop's `fold`, one a fold of two or more partial states: every "
+        "`agg.mergeFanIn` batches and once on the tail | `concat_batches` "
+        "of the parts (the row count it reads per part, counted in "
+        "`aggHostSyncs`, and its eager compaction) and the merge launch; "
+        "`srt:agg_merge` nests in it (`parts=` in the annotation)",
         "`srt:agg_whole_stage_bail` | `exec/aggregate.py "
         "_try_whole_stage`, zero-length | why the input takes the "
         "streaming loop (`reason=bytes`: past half of "

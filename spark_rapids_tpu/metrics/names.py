@@ -101,6 +101,14 @@ SCAN_TIME = register_metric(
     "path; on the device-decode paths (Parquet, ORC, CSV) the host time "
     "inside `srt:scan_decode` (page parsing, decompression, H2D enqueue, "
     "decode dispatches), summed over the decode threads")
+SCAN_CACHE_HIT_BATCHES = register_metric(
+    "scanCacheHitBatches", COUNTER, ESSENTIAL,
+    "batches an in-memory scan served from the device scan cache "
+    "(`utils/scan_cache.py`) instead of uploading them, added once an "
+    "execute when the scan ends, not a batch, so the hit loop stays as "
+    "it was; 0 where the scan uploaded, so a table that is not "
+    "resident (over `resident_bound`, or evicted) reads 0 and not absent; "
+    "a host integer")
 CONCAT_TIME = register_metric(
     "concatTime", TIMER, MODERATE, "batch coalesce/concat time")
 SORT_TIME = register_metric(

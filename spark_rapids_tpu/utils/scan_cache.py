@@ -11,8 +11,8 @@ the slowest resource in the system (a PCIe-class host link against
 Keys are (table identity, pruned column names, reader row limit).  A strong
 reference to the source table is held so `id()` can never be recycled to a
 different live table; pyarrow Tables are immutable, so identity implies
-content equality.  The cache is LRU-bounded by
-`spark.rapids.sql.tpu.memoryScanCache.maxSize` device bytes.
+content equality.  The cache is LRU-bounded by `resident_bound(conf)`
+device bytes.
 """
 from __future__ import annotations
 
@@ -78,6 +78,22 @@ class MemoryScanCache:
     @property
     def device_bytes(self) -> int:
         return self._bytes
+
+
+def resident_bound(conf) -> int:
+    """Device bytes the scan cache may hold: an explicit
+    `spark.rapids.sql.tpu.memoryScanCache.maxSize` > 0, else half of the
+    accounted pool (`mem/runtime.configured_pool_bytes`), as Spark's
+    `spark.memory.storageFraction` gives storage half of the unified
+    region.  The pinned batches are not registered with the runtime, so its
+    reservations do not see them: the bound is what keeps them in their
+    half."""
+    from ..config import MEMORY_SCAN_CACHE_SIZE
+    explicit = int(conf.get(MEMORY_SCAN_CACHE_SIZE))
+    if explicit > 0:
+        return explicit
+    from ..mem.runtime import configured_pool_bytes
+    return configured_pool_bytes(conf) // 2
 
 
 MEMORY_SCAN_CACHE = MemoryScanCache()

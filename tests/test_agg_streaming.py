@@ -160,6 +160,55 @@ def test_whole_stage_budget_edge(slack, streamed):
     assert moved.get("numFusedStages", 0) == (0 if streamed else 1)
 
 
+#: batches of 8,192 rows (each read for its live rows before the shrink):
+#: 18 of them and a last one of capacity 4,096, past 2 x agg.mergeFanIn (8)
+FOLD_BATCH = 8_192
+FOLD_ROWS = 18 * FOLD_BATCH + 3_000
+
+
+def test_grouped_loop_folds_mid_stream_and_on_the_tail(tmp_path):
+    """Q1 through the grouped loop over 19 batches of two capacities (the
+    whole-stage probe bails on shapes): a fold of 8 parts after the 8th
+    batch, of 9 (the running state and 8) after the 16th, of 4 on the tail,
+    each inside one `srt:agg_fold` that says how many parts it merged, with
+    `srt:agg_merge` in it.  Host reads, exactly: 18 live-row reads (the
+    4,096-row batch takes no shrink), 19 bucket checks, a count per part."""
+    import glob
+    import jax
+    query = QUERIES["q1"]
+    table = _table(query, FOLD_ROWS, seed=3_900_000_017 % 2**32)
+    s = TpuSession({**CELL_CONF, "spark.rapids.sql.reader.batchSizeRows":
+                    str(FOLD_BATCH)})
+    df = query.build(s, {"lineitem": s.from_arrow(table)})
+    df.collect()                      # the scan-cache load
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    before = dict(s.query_metrics_total)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        rows = df.collect()
+    finally:
+        jax.profiler.stop_trace()
+    moved = {k: v - before.get(k, 0) for k, v in s.query_metrics_total.items()}
+    _assert_matches(rows, query, table)
+    assert moved["scanCacheHitBatches"] == 19
+    assert moved["aggStreamedBatches"] == moved["aggBucketBatches"] == 19
+    assert moved["aggSortPathBatches"] == 0
+    assert moved["aggHostSyncs"] == 18 + 19 + (8 + 9 + 4)
+    [pb] = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                         "*.xplane.pb"))
+    [host] = [p for p in jax.profiler.ProfileData.from_file(pb).planes
+              if p.name == "/host:CPU"]
+    def spans(name):
+        return sorted([(e.start_ns, e.start_ns + e.duration_ns, e)
+                       for line in host.lines for e in line.events
+                       if e.name == name], key=lambda t: t[0])
+    folds, merges = spans("srt:agg_fold"), spans("srt:agg_merge")
+    assert [int(dict(f[2].stats)["parts"]) for f in folds] == [8, 9, 4]
+    assert len(merges) == 3
+    assert all(f[0] <= m[0] and m[1] <= f[1] for f, m in zip(folds, merges))
+
+
 def test_a_dropped_or_doubled_batch_is_caught():
     """The comparison this file rests on sees one batch of five missing or
     counted twice (the reference over four or six batches' rows)."""

@@ -130,8 +130,8 @@ def _grouped_minmax(lineitem):
 @pytest.fixture(scope="module")
 def smoke_programs(topo):
     """{query: [programs]} recorded from chip_smoke.py's q6, q1 and join
-    query (q1 over five batches too, q6 through the streaming loop, and one
-    grouped min/max) at SF1 widths,
+    query (q1 over five batches too, q6 and q1 through the streaming loop,
+    and one grouped min/max) at SF1 widths,
     run on the CPU with the engine on its TPU branches."""
     import chip_smoke
     from benchmarks.tpch import bulk
@@ -179,6 +179,11 @@ def smoke_programs(topo):
         records["q6_stream"] = []
         mp.setattr(jax, "jit", _recording_jit(records["q6_stream"]))
         assert bulk.q6(streaming.from_arrow(lineitem)).collect()
+        # q1 the same way: the GROUPED loop's bucket update a 1M-row batch
+        # (the SF10 Q1 cell's program), its folds' merge of bucket states
+        records["q1_stream"] = []
+        mp.setattr(jax, "jit", _recording_jit(records["q1_stream"]))
+        assert bulk.q1(streaming.from_arrow(lineitem)).collect()
         # the contiguous pack (shuffle/spill/broadcast unit) of one
         # reader batch of orders: ints, plus a double column for the
         # f32-pair branch
@@ -222,6 +227,9 @@ def _largest(programs, name_part):
     # the same query as the streaming loop answers it: filter, masked
     # reductions and the merge into the running 1-row state, one batch
     ("q6_stream", "agg.stream_step", 1 << 20),
+    # the grouped loop over the same batches: the bucket update alone, a
+    # batch a launch, its six groups in one dense pass
+    ("q1_stream", "agg.hashaggregate_bucket", 1 << 20),
     # the grouped aggregate over the same batches: per batch the bucket
     # update's `while` of dense passes, f64 sums and int64 counts in its
     # carry (two string keys; as a `cond` over a dense and a scatter form

@@ -103,3 +103,39 @@ def test_string_column_padding():
     c2 = c.pad_strings_to(64)
     assert c2.max_len == 64
     assert c2.to_pylist(2) == ["abc", "a-much-longer-string"]
+
+
+_EDGE = "é" * 4                      # 8 bytes: the first max_len bucket, full
+_STRING_CASES = {
+    "nulls": pa.array(["a", None, "bb", None, "ccc"]),
+    "empty_strings": pa.array(["", "x", "", None, ""]),
+    "utf8_at_the_edge": pa.array([_EDGE, "a", None, "ü"]),
+    "utf8_past_the_edge": pa.array([_EDGE + "a", "ß" * 7, None, ""]),
+    "sliced": pa.array(["skip", None, "kept", "é", None, "tail" * 5]).slice(1, 4),
+    "large_string": pa.array(["a", None, "long" * 9, ""], pa.large_string()),
+    "large_string_sliced": pa.array(
+        ["x", "yy", None, "zzz"], pa.large_string()).slice(2),
+    "one_width": pa.array(["A", "N", "R", "N"] * 300),
+    "one_width_sliced": pa.array(["AF", "NO", "RF"] * 50).slice(7, 101),
+    "chunked": pa.chunked_array([["a", None], [], ["ccc", "dd" * 5, None]]),
+    "all_null": pa.array([None, None, None], pa.string()),
+    "zero_rows": pa.array([], pa.string()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STRING_CASES))
+def test_arrow_strings_from_buffers_equal_the_row_loop(case):
+    """A string column uploaded from Arrow's buffers (offsets, data,
+    validity bitmap) is, byte for byte, what `from_strings` builds from the
+    same values as Python objects: the byte matrix, lengths, validity and
+    its `max_len` bucket."""
+    values = _STRING_CASES[case]
+    batch = ColumnarBatch.from_arrow(pa.table({"s": values}))
+    got, = batch.columns
+    want = Column.from_strings(values.to_pylist(), capacity=batch.capacity)
+    assert got.max_len == want.max_len
+    for name in ("data", "lengths", "valid"):
+        a, b = (np.asarray(getattr(c, name)) for c in (got, want))
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert np.array_equal(a, b), name
+    assert batch.to_pylist() == [(v,) for v in values.to_pylist()]

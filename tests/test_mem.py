@@ -249,6 +249,64 @@ class TestMemoryScanCache:
         df = session.from_arrow(table)
         return df.filter(col("a") > 2).agg(F.sum(col("a")).alias("s"))
 
+    #: one int64 column at 10 B a row at capacity (data, validity, selection)
+    #: in batches of 16,384 rows: 163,840 B a batch
+    RESIDENCY_BATCH = 16_384
+
+    @pytest.mark.parametrize("batches,cache_conf,resident", [
+        (16, {}, True),                 # 2.6 MB under half of the 8 MB pool
+        (32, {}, False),                # 5.2 MB over it
+        (32, {"spark.rapids.sql.tpu.memoryScanCache.maxSize": "6m"}, True),
+        (16, {"spark.rapids.sql.tpu.memoryScanCache.maxSize": "1m"}, False),
+    ], ids=["under_half_the_pool", "over_half_the_pool",
+            "explicit_bound_above", "explicit_bound_below"])
+    def test_residency_bound_is_half_the_pool(self, batches, cache_conf,
+                                              resident):
+        """The cache's bound is half of the accounted pool unless maxSize
+        says otherwise: a table under it is served from the cache from the
+        second query on (every batch, nothing crosses the host link); a
+        table over it uploads every query and the counter reads 0."""
+        import pyarrow as pa
+        from spark_rapids_tpu.engine import TpuSession
+        from spark_rapids_tpu.utils.scan_cache import (MEMORY_SCAN_CACHE,
+                                                       resident_bound)
+        MEMORY_SCAN_CACHE.clear()
+        conf = {"spark.rapids.memory.tpu.poolSizeBytes": "8m",
+                "spark.rapids.sql.reader.batchSizeRows":
+                    str(self.RESIDENCY_BATCH), **cache_conf}
+        explicit = cache_conf.get(
+            "spark.rapids.sql.tpu.memoryScanCache.maxSize")
+        assert resident_bound(TpuConf(conf)) == (
+            int(explicit[:-1]) << 20 if explicit else 4 << 20)
+        table = pa.table({"a": np.arange(batches * self.RESIDENCY_BATCH)})
+        s = TpuSession(conf)
+        df = self._q6ish(s, table)
+        moved = []
+        for _ in range(3):
+            before = dict(s.query_metrics_total)
+            assert df.collect() == [(int(np.arange(batches * self.RESIDENCY_BATCH)
+                                         [3:].sum()),)]
+            moved.append({k: v - before.get(k, 0)
+                          for k, v in s.query_metrics_total.items()})
+        assert moved[0]["scanCacheHitBatches"] == 0
+        assert moved[0]["h2dBytes"] > 0
+        for m in moved[1:]:
+            assert m["scanCacheHitBatches"] == (batches if resident else 0)
+            assert m.get("h2dBytes", 0) == (0 if resident else
+                                            moved[0]["h2dBytes"])
+        assert MEMORY_SCAN_CACHE.device_bytes == (
+            batches * self.RESIDENCY_BATCH * 10 if resident else 0)
+
+    def test_default_bound_is_half_the_detected_pool(self):
+        from spark_rapids_tpu.mem.runtime import configured_pool_bytes
+        from spark_rapids_tpu.utils.scan_cache import resident_bound
+        conf = TpuConf()
+        # the CPU backend reports no memory limit: a nominal 16 GiB, of
+        # which allocFraction 0.9 is the pool and half of that the cache's
+        assert configured_pool_bytes(conf) == int((16 << 30) * 0.9)
+        assert resident_bound(conf) == configured_pool_bytes(conf) // 2
+        assert resident_bound(conf) > 4_322_230_272   # Q1's LINEITEM at SF10
+
     def test_repeat_query_hits_cache(self):
         import pyarrow as pa
         from spark_rapids_tpu.engine import TpuSession
