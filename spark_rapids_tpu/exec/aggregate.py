@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..columnar import Column, ColumnarBatch, concat_batches
+from ..columnar import Column, ColumnarBatch, bucket_rows
+from ..columnar.batch import _normalize_devices
 from ..ops import expressions as E
 from ..ops.aggregates import AggregateExpression
 from ..ops.hashing import hash_columns_double
@@ -79,6 +80,54 @@ def _head_rows(batch: ColumnarBatch, cap: int) -> ColumnarBatch:
                    c.lengths[:cap] if c.lengths is not None else None)
             for c in batch.columns]
     return ColumnarBatch(cols, batch.sel[:cap], batch.schema)
+
+
+def _part_rows(parts: Sequence[ColumnarBatch]):
+    """int32[K]: the live rows of each part, for the host's one read."""
+    return jnp.stack([p.num_rows() for p in parts])
+
+
+def _concat_prefixes(parts: Sequence[ColumnarBatch],
+                     cap: int) -> ColumnarBatch:
+    """What `concat_batches(parts, cap)` gives, traced, for parts whose
+    live rows are a prefix (every partial state is: `sel = iota <
+    ngroups`): each part's live prefix at its running offset, in part
+    order, string columns padded to the widest part, zeros past the
+    total.  A part is written whole at its offset and the next one
+    overwrites its dead tail, so no row is sorted, compacted or scanned;
+    the buffer has room for the last part's capacity past `cap` (a
+    `dynamic_update_slice` that does not fit would be moved back)."""
+    schema = parts[0].schema
+    counts = [p.num_rows().astype(jnp.int32) for p in parts]
+    offsets = [jnp.int32(0)]
+    for n in counts[:-1]:
+        offsets.append(offsets[-1] + n)
+    keep = jnp.arange(cap, dtype=jnp.int32) < offsets[-1] + counts[-1]
+    room = cap + max(p.capacity for p in parts)
+
+    def place(leaves, dtype):
+        buf = jnp.zeros((room,) + leaves[0].shape[1:], dtype)
+        for x, off in zip(leaves, offsets):
+            buf = jax.lax.dynamic_update_slice(
+                buf, x, (off,) + (jnp.int32(0),) * (x.ndim - 1))
+        head = buf[:cap]
+        mask = keep.reshape((cap,) + (1,) * (head.ndim - 1))
+        return jnp.where(mask, head, jnp.zeros((), dtype))
+
+    cols = []
+    for ci, f in enumerate(schema):
+        pcols = [p.columns[ci] for p in parts]
+        valid = place([c.valid for c in pcols], jnp.bool_)
+        if f.dtype.is_string:
+            width = max(c.max_len for c in pcols)
+            pcols = [c.pad_strings_to(width) for c in pcols]
+            cols.append(Column(place([c.data for c in pcols], jnp.uint8),
+                               valid, f.dtype,
+                               place([c.lengths for c in pcols], jnp.int32)))
+        else:
+            cols.append(Column(place([c.data for c in pcols],
+                                     f.dtype.jnp_dtype), valid, f.dtype))
+    return ColumnarBatch(cols, keep, schema)
 
 
 def _thread_params(fn, params):
@@ -836,6 +885,17 @@ class TpuHashAggregateExec(TpuExec):
                                   .mask_invalid())
         return state_cols
 
+    def _fold_program(self, cap: int):
+        """`jit_agg.fold` at merge capacity `cap`: the streaming loop's
+        partial states (a list, live rows a prefix in each) concatenated
+        in order and merged in one launch, the merge's input the rows
+        `concat_batches` would give it at that capacity."""
+        from ..utils.kernel_cache import cached_kernel
+        merge = self._merge_kernel
+        return cached_kernel(
+            ("fold", self.kernel_key(), cap),
+            lambda: lambda parts: merge(_concat_prefixes(parts, cap)))
+
     def _merge_kernel(self, state: ColumnarBatch) -> ColumnarBatch:
         """state batch (concat of partials) -> merged state batch."""
         cap = state.capacity
@@ -1318,17 +1378,16 @@ class TpuHashAggregateExec(TpuExec):
                     base_update, b, off))
         else:
             update = cached_kernel(key + ("update",), lambda: base_update)
-        merge = cached_kernel(key + ("merge",),
-                              lambda: self._merge_kernel)
         finalize = cached_kernel(key + ("finalize",),
                                  lambda: self._finalize_kernel)
+        part_rows = cached_kernel(("part_rows",), lambda: _part_rows)
         # Deferred merging: buffer per-batch partials and merge FAN_IN at a
-        # time, so the expensive sort-based merge kernel (and the host
-        # row-count syncs inside concat_batches) run once per FAN_IN input
-        # batches instead of once per batch.  Merge aggregates are
-        # associative, and order-sensitive ones (First/Last) carry explicit
-        # row-offset tiebreak columns in the partial state, so K-way
-        # concat-then-merge equals the pairwise fold.
+        # time, so the expensive sort-based merge kernel and the host's
+        # row-count read run once per FAN_IN input batches instead of once
+        # per batch.  Merge aggregates are associative, and order-sensitive
+        # ones (First/Last) carry explicit row-offset tiebreak columns in
+        # the partial state, so K-way concat-then-merge equals the pairwise
+        # fold.
         from ..config import AGG_MERGE_FAN_IN
         fan_in = max(2, ctx.conf.get(AGG_MERGE_FAN_IN))
 
@@ -1338,6 +1397,7 @@ class TpuHashAggregateExec(TpuExec):
             parts = ([state] if state is not None else []) + pending
             if len(parts) == 1:
                 return parts[0]
+            parts = _normalize_devices(parts)
 
             def attempt_merge(_):
                 # merge allocates the K-way concat: reserve it so the
@@ -1348,14 +1408,18 @@ class TpuHashAggregateExec(TpuExec):
                 record_cost(self.metrics, hbm_read=merge_bytes,
                             flops=sum(p.capacity for p in parts)
                             * self._cost_weight())
-                with self.metrics.timer(MN.CONCAT_TIME):
-                    both = concat_batches(parts)
-                # concat_batches reads each compacted part's row count
-                self.metrics.add(MN.AGG_HOST_SYNCS, len(parts))
+                # every part's live rows are a prefix: the parts' counts,
+                # in one read, size the merge as concat_batches would
+                counts = jax.device_get(part_rows(parts))  # tpulint: disable=TPU001 the fold's one read: the merge's capacity is the bucket of the parts' live rows
+                total = sum(counts.tolist())
+                self.metrics.add(MN.AGG_HOST_SYNCS, 1)
+                fused = self._fold_program(bucket_rows(max(total, 1)))
                 with self.metrics.timer(MN.SEG_AGG_TIME), \
                         named_range("agg_merge", self.metrics,
                                     MN.MERGE_AGG_TIME):
-                    return merge(both)
+                    merged = fused(parts)
+                self.metrics.add(MN.AGG_FUSED_FOLDS, 1)
+                return merged
             # retry-only: partial states are merge inputs, not splittable
             # row ranges (splitting them would change nothing — the merge
             # concat is the allocation)

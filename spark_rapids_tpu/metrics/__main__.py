@@ -397,10 +397,12 @@ def monitoring_doc() -> str:
         "(`aggSyncFreeBatches`)",
         "`srt:agg_fold` | `exec/aggregate.py`, the GROUPED streaming "
         "loop's `fold`, one a fold of two or more partial states: every "
-        "`agg.mergeFanIn` batches and once on the tail | `concat_batches` "
-        "of the parts (the row count it reads per part, counted in "
-        "`aggHostSyncs`, and its eager compaction) and the merge launch; "
-        "`srt:agg_merge` nests in it (`parts=` in the annotation)",
+        "`agg.mergeFanIn` batches and once on the tail | one read of the "
+        "parts' live-row counts (`jit_agg.part_rows`, counted once in "
+        "`aggHostSyncs`) and the launch of `jit_agg.fold`, which places "
+        "every part's live prefix and merges in one program (counted in "
+        "`aggFusedFolds`); no eager op; `srt:agg_merge` nests in it "
+        "around the launch (`parts=` in the annotation)",
         "`srt:agg_whole_stage_bail` | `exec/aggregate.py "
         "_try_whole_stage`, zero-length | why the input takes the "
         "streaming loop (`reason=bytes`: past half of "

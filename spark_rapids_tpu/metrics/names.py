@@ -417,10 +417,18 @@ AGG_HOST_SYNCS = register_metric(
     "aggHostSyncs", COUNTER, ESSENTIAL,
     "host reads of a device value the aggregate made: the bucket "
     "update's clean check and, in the GROUPED streaming loop only, a "
-    "batch's live-row count before the shrink and the row count "
-    "concat_batches reads per part on a fold; present and 0 for a "
-    "keyless aggregate through the streaming loop, which reads nothing; "
-    "a host integer counted where the read is made")
+    "batch's live-row count before the shrink and one read a fold of "
+    "the parts' live-row counts; present and 0 for a keyless aggregate "
+    "through the streaming loop, which reads nothing; a host integer "
+    "counted where the read is made")
+AGG_FUSED_FOLDS = register_metric(
+    "aggFusedFolds", COUNTER, ESSENTIAL,
+    "folds of the GROUPED streaming loop answered by the one program "
+    "`jit_agg.fold` (the running state and the pending partial states "
+    "placed by their live prefixes and merged in one launch, after one "
+    "read of their row counts): every agg.mergeFanIn batches and once on "
+    "the tail; added on the host after the launch; a host integer, "
+    "never a sync")
 SEG_AGG_TIME = register_metric(
     "segAggTime", TIMER, MODERATE,
     "segmented-aggregation kernel time inside grouped-aggregate "

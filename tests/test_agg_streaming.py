@@ -121,9 +121,10 @@ def test_streaming_loop_against_reference(name, rows, streams_by_default):
         assert "aggSortPathBatches" in moved
         assert moved["aggSortPathBatches"] == 0
         # grouped: every batch's live rows are read once (capacity >=
-        # 8192), the bucket check once, and every fold reads a count per
-        # part: 5 pending parts at the end of the input
-        assert moved["aggHostSyncs"] == 5 + 5 + 5
+        # 8192), the bucket check once, and the one fold (of the 5 pending
+        # parts at the end of the input) reads their counts once
+        assert moved["aggHostSyncs"] == 5 + 5 + 1
+        assert moved["aggFusedFolds"] == 1
         assert moved.get("aggSyncFreeBatches", 0) == 0
     else:
         # keyless: the step program a batch reads nothing back; the
@@ -172,7 +173,9 @@ def test_grouped_loop_folds_mid_stream_and_on_the_tail(tmp_path):
     batch, of 9 (the running state and 8) after the 16th, of 4 on the tail,
     each inside one `srt:agg_fold` that says how many parts it merged, with
     `srt:agg_merge` in it.  Host reads, exactly: 18 live-row reads (the
-    4,096-row batch takes no shrink), 19 bucket checks, a count per part."""
+    4,096-row batch takes no shrink), 19 bucket checks, one read a fold.
+    A fold launches two programs, the parts' counts and `agg.fold`, and no
+    eager op: no compaction, no per-part slice or update."""
     import glob
     import jax
     query = QUERIES["q1"]
@@ -194,19 +197,30 @@ def test_grouped_loop_folds_mid_stream_and_on_the_tail(tmp_path):
     assert moved["scanCacheHitBatches"] == 19
     assert moved["aggStreamedBatches"] == moved["aggBucketBatches"] == 19
     assert moved["aggSortPathBatches"] == 0
-    assert moved["aggHostSyncs"] == 18 + 19 + (8 + 9 + 4)
+    assert moved["aggHostSyncs"] == 18 + 19 + 3
+    assert moved["aggFusedFolds"] == 3
     [pb] = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
                          "*.xplane.pb"))
     [host] = [p for p in jax.profiler.ProfileData.from_file(pb).planes
               if p.name == "/host:CPU"]
+    events = sorted([(e.start_ns, e.start_ns + e.duration_ns, e)
+                     for line in host.lines for e in line.events],
+                    key=lambda t: t[0])
+
     def spans(name):
-        return sorted([(e.start_ns, e.start_ns + e.duration_ns, e)
-                       for line in host.lines for e in line.events
-                       if e.name == name], key=lambda t: t[0])
+        return [t for t in events if t[2].name == name]
     folds, merges = spans("srt:agg_fold"), spans("srt:agg_merge")
     assert [int(dict(f[2].stats)["parts"]) for f in folds] == [8, 9, 4]
     assert len(merges) == 3
     assert all(f[0] <= m[0] and m[1] <= f[1] for f, m in zip(folds, merges))
+    for f in folds:
+        calls = [t[2].name for t in events if f[0] <= t[0] and t[1] <= f[1]
+                 and t[2].name.startswith("PjitFunction")]
+        # (a call can show on more than one line of the host plane)
+        assert set(calls) == {"PjitFunction(agg.part_rows)",
+                              "PjitFunction(agg.fold)"}, calls
+        assert calls[0] == "PjitFunction(agg.part_rows)", calls
+        assert calls[-1] == "PjitFunction(agg.fold)", calls
 
 
 def test_a_dropped_or_doubled_batch_is_caught():
@@ -453,7 +467,7 @@ def test_keyless_loop_is_one_step_dispatch_a_batch_and_nothing_else(
 
     def guarded(self, ctx, materialized):
         with monkeypatch.context() as mp:
-            mp.setattr(aggregate_module, "concat_batches", refuse)
+            mp.setattr(aggregate_module, "_concat_prefixes", refuse)
             mp.setattr(batch_module, "concat_batches", refuse)
             for method in ("shrink_to", "maybe_shrink", "compact",
                            "num_rows_host"):

@@ -1079,3 +1079,122 @@ def test_group_keys_are_each_groups_first_row(kernel):
                      (c.lengths, want.lengths)):
             if a is not None:
                 np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+_FOLD_SCHEMA = T.Schema([T.StructField("k", T.LongType),
+                         T.StructField("s", T.StringType),
+                         T.StructField("x", T.DoubleType)])
+
+
+def _fold_batch(i, n, width, cap=None):
+    """Input batch i of the fold cases: n rows, string keys up to `width`
+    bytes (the batch's string width is that width's bucket), NULL keys,
+    doubles whose sums round."""
+    from spark_rapids_tpu.columnar import ColumnarBatch
+    rows = range(i * 1000, i * 1000 + n)
+    return ColumnarBatch.from_pydict(
+        {"k": [None if r % 11 == 0 else r % 5 for r in rows],
+         "s": [None if r % 7 == 3 else "abc"[r % 3] * (1 + r % width)
+               for r in rows],
+         "x": [(r % 13) * 0.1 - 0.35 for r in rows]},
+        _FOLD_SCHEMA, capacity=cap)
+
+
+def _fold_agg(first_last):
+    from spark_rapids_tpu.engine import TpuSession
+    s = TpuSession(FLOAT_AGG)
+    df = s.from_pydict({"k": [1], "s": ["a"], "x": [0.5]}, _FOLD_SCHEMA)
+    if first_last:
+        q = df.group_by("k").agg(f.first(col("s")).alias("fs"),
+                                 f.last(col("x")).alias("lx"),
+                                 f.sum(col("x")).alias("sx"))
+    else:
+        q = df.group_by("k", "s").agg(f.sum(col("x")).alias("sx"),
+                                      f.count(lit(1)).alias("n"),
+                                      f.min(col("x")).alias("mn"),
+                                      f.max(col("x")).alias("mx"),
+                                      f.avg(col("x")).alias("ax"))
+    return _find_agg(s.plan(q.plan))
+
+
+def _bucket_states(agg, batches):
+    import jax
+    bucket = jax.jit(agg._bucket_update_kernel)
+    states = []
+    for b in batches:
+        took, state = bucket(b)
+        assert int(took) >= 0
+        states.append(state)
+    return states
+
+
+def _fold_case(case):
+    """(agg, parts): partial states as the grouped loop folds them."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar import concat_batches
+    from spark_rapids_tpu.ops import expressions as E
+    if case == "sort_first_last_unequal_caps":
+        agg = _fold_agg(first_last=True)
+        update = jax.jit(lambda b, off: E.eval_with_row_offset(
+            agg._update_kernel, b, off))
+        parts, off = [], 0
+        for i, (n, cap) in enumerate([(1500, 2048), (700, 1024),
+                                      (3000, 4096)]):
+            b = _fold_batch(i, n, 6, cap)
+            parts.append(update(b, jnp.int64(off)))
+            off += n
+        return agg, parts
+    agg = _fold_agg(first_last=False)
+    if case == "bucket_string_widths":
+        return agg, _bucket_states(agg, [_fold_batch(i, 600, w) for i, w in
+                                         enumerate([4, 12, 3, 20])])
+    if case == "empty_part":
+        b = _fold_batch(1, 600, 9)
+        empty = b.with_sel(jnp.zeros_like(b.sel))
+        return agg, _bucket_states(agg, [_fold_batch(0, 600, 5), empty,
+                                         _fold_batch(2, 600, 5)])
+    assert case == "state_plus_8"
+    first = _bucket_states(agg, [_fold_batch(i, 600, 4) for i in range(2)])
+    state = jax.jit(agg._merge_kernel)(concat_batches(first))
+    return agg, [state] + _bucket_states(
+        agg, [_fold_batch(i, 600, 3 + 2 * i) for i in range(2, 10)])
+
+
+def _bits(x):
+    import numpy as np
+    a = np.asarray(x)
+    return a.view(f"u{a.dtype.itemsize}") if a.dtype.kind == "f" else a
+
+
+@pytest.mark.parametrize("case", ["bucket_string_widths",
+                                  "sort_first_last_unequal_caps",
+                                  "empty_part", "state_plus_8"])
+def test_fold_program_equals_concat_then_merge(case):
+    """`jit_agg.fold` (the grouped loop's fold in one launch) against
+    what it replaced, `concat_batches` then `_merge_kernel`, on the same
+    partial states: the merge's input and its output equal leaf for leaf
+    and bit for bit, string widths, capacity and dead rows included."""
+    import jax
+    import numpy as np
+    from spark_rapids_tpu.columnar import bucket_rows, concat_batches
+    from spark_rapids_tpu.exec import aggregate as A
+    agg, parts = _fold_case(case)
+    total = sum(int(p.num_rows()) for p in parts)
+    assert total > 0 and len({p.capacity for p in parts}) >= 1
+    cap = bucket_rows(max(total, 1))
+    want_in = concat_batches(parts)
+    got_in = jax.jit(lambda ps: A._concat_prefixes(ps, cap))(parts)
+    want = jax.jit(agg._merge_kernel)(want_in)
+    got = agg._fold_program(cap)(parts)
+    for w, g in ((want_in, got_in), (want, got)):
+        w_leaves, w_tree = jax.tree_util.tree_flatten(w)
+        g_leaves, g_tree = jax.tree_util.tree_flatten(g)
+        assert g_tree == w_tree
+        for a, b in zip(w_leaves, g_leaves):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_array_equal(_bits(a), _bits(b))
+    if case == "sort_first_last_unequal_caps":
+        assert len({p.capacity for p in parts}) == 3
+    if case == "empty_part":
+        assert int(parts[1].num_rows()) == 0

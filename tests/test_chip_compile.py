@@ -247,10 +247,12 @@ def _largest(programs, name_part):
     # keys, where the f64 comparator took 9 minutes) DESC then o_orderdate
     ("q3_join", "sort.sort", 1 << 20),
     # the sort-path aggregate over the join's outputs: a batch's update
-    # and the fold's merge of the stacked states, their segment bounds
+    # and the fold (the states placed by their live prefixes, then the
+    # merge, one program; its arguments are the 524,288-row states, its
+    # merge runs at the bucket of their live rows), their segment bounds
     # read off the sorted group ids
     ("q3_join", "agg.hashaggregate_update", 1 << 19),
-    ("q3_join", "agg.hashaggregate_merge", 1 << 20),
+    ("q3_join", "agg.fold", 1 << 19),
     # the contiguous pack of a full reader batch (f64 leaves as f32 pairs)
     ("pack", "mem.contig_pack", 1 << 20),
 ])
@@ -267,7 +269,7 @@ def test_smoke_program_compiles_for_v5e(smoke_programs, one_chip,
         # a `while` of 21 dependent 1M-row gathers each (PR 30)
         assert len(re.findall(r"\bwhile\(", compiled.as_text())) <= 1
     if kernel in ("join.hashjoin_gather", "agg.hashaggregate_update",
-                  "agg.hashaggregate_merge"):
+                  "agg.fold"):
         # no walk in the gather: nothing loops over the stream batch; no
         # binary search over every group id in the aggregate
         assert not re.findall(r"\bwhile\(", compiled.as_text())

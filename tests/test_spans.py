@@ -249,8 +249,9 @@ def test_a_streamed_aggregate_marks_its_bail_and_spans_each_batch(
     assert moved[MN.AGG_STREAMED_BATCHES] == batches
     if grouped:
         # a live-row read and a bucket check a batch, and the last fold's
-        # count per part
-        assert moved[MN.AGG_HOST_SYNCS] == 3 * batches
+        # one read of its parts' counts
+        assert moved[MN.AGG_HOST_SYNCS] == 2 * batches + 1
+        assert moved[MN.AGG_FUSED_FOLDS] == 1
         assert moved.get(MN.AGG_SYNC_FREE_BATCHES, 0) == 0
     else:
         assert moved[MN.AGG_HOST_SYNCS] == 0
