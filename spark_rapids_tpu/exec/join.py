@@ -43,6 +43,15 @@ count-then-gather join API:
      every step, 61 ms a 1M-row batch to write 17,000 rows).
      semi/anti never reach this phase (they are a mask over the stream
      batch: counts>0 / counts==0).
+  5. PASS-THROUGH, where the gather would only copy the stream batch: no
+     live stream row has more than one candidate (`max_dup <= 1`, so no
+     row multiplies) and the output's capacity bucket is the stream
+     batch's own.  The output is then the stream batch itself, its column
+     arrays untouched, each row beside its one build row or none, the
+     selection ANDed with "matched" for an inner join.  Dead slots stay
+     in place; the live rows keep the order the gather would give them.
+     Chosen from the two integers the probe's one host read already
+     returned: a join that multiplies or compacts rows keeps phase 4.
 
 Equality uses Spark key semantics (nulls never match, NaN == NaN,
 -0.0 == 0.0), matching the CPU oracle in cpu_relational.py.
@@ -108,6 +117,23 @@ def _nth_set_bit(word, n):
         pos = jnp.where(up, pos + width, pos)
         n = jnp.where(up, n - below, n)
     return pos
+
+
+def _build_columns_at(build: ColumnarBatch, b_idx, matched):
+    """The build side's columns taken at `b_idx`, null (and zeroed) where
+    the slot has no matched build row."""
+    rcols = []
+    for c in build.columns:
+        taken = c.take(b_idx)
+        rcols.append(taken.with_valid(taken.valid & matched).mask_invalid())
+    return rcols
+
+
+def _build_hits(cap_b: int, b_idx, matched, vary_axes: tuple = ()):
+    """Which build rows a matched slot points at (a full join's tail is
+    the build rows no stream row ever matched)."""
+    b_hit = _pvary(jnp.zeros(cap_b, jnp.bool_), vary_axes)
+    return b_hit.at[jnp.where(matched, b_idx, cap_b)].set(True, mode="drop")
 
 
 class TpuReorderColumnsExec(TpuExec):
@@ -214,6 +240,20 @@ class TpuHashJoinExec(TpuExec):
                                if f.name in lschema.names else f.name,
                                f.dtype) for f in rschema]
         return lfields, rfields
+
+    def _joined_batch(self, lcols, rcols, sel, lschema: Schema,
+                      rschema: Schema) -> ColumnarBatch:
+        """The output batch from its left and right column blocks: the
+        joined fields, USING's duplicated key columns dropped, the join's
+        own schema."""
+        lfields, rfields = self._joined_fields(lschema, rschema)
+        joined = ColumnarBatch(list(lcols) + list(rcols), sel,
+                               Schema(lfields + rfields))
+        if self.using_drop:
+            keep_idx = [i for i in range(joined.num_cols)
+                        if i not in self.using_drop]
+            joined = joined.select_columns(keep_idx)
+        return ColumnarBatch(joined.columns, joined.sel, self._schema)
 
     def _pair_condition_ok(self, lbatch: ColumnarBatch,
                            build: ColumnarBatch, bidx):
@@ -345,31 +385,37 @@ class TpuHashJoinExec(TpuExec):
             matched, jnp.take(lo, l_idx, mode="clip") + offset, 0)
 
         lcols = [c.take(l_idx) for c in lbatch.columns]
-        rcols = []
-        for c in build.columns:
-            taken = c.take(b_idx)
-            rcols.append(taken.with_valid(taken.valid & matched)
-                         .mask_invalid())
-        lfields, rfields = self._joined_fields(lbatch.schema, build.schema)
-        joined = ColumnarBatch(lcols + rcols, sel,
-                               Schema(lfields + rfields))
+        rcols = _build_columns_at(build, b_idx, matched)
         # no post-filter: the residual condition (if any) was already
         # applied pair-wise in the count walk, so slots and counts
         # agree by construction
-        if self.using_drop:
-            keep_idx = [i for i in range(joined.num_cols)
-                        if i not in self.using_drop]
-            joined = joined.select_columns(keep_idx)
-        out = ColumnarBatch(joined.columns, joined.sel, self._schema)
+        out = self._joined_batch(lcols, rcols, sel, lbatch.schema,
+                                 build.schema)
         if self.join_type == "full":
             # which BUILD rows ever matched, so the stream driver can
             # emit the never-matched remainder
-            cap_b = build.capacity
-            b_hit = _pvary(jnp.zeros(cap_b, jnp.bool_), vary_axes)
-            b_hit = b_hit.at[jnp.where(matched, b_idx, cap_b)].set(
-                True, mode="drop")
-            return out, b_hit
+            return out, _build_hits(build.capacity, b_idx, matched,
+                                    vary_axes)
         return out
+
+    def _passthrough_kernel(self, lbatch: ColumnarBatch,
+                            build: ColumnarBatch, lo, hits):
+        """The right side of a pass-through output (module docstring,
+        phase 5): every live stream row has at most one candidate, `lo`,
+        and bit 0 of its first `hits` word says whether the count walk
+        verified it (keys, build row live, residual condition).  Returns
+        the output's selection, the build side's columns at stream
+        capacity and, for `full`, which build rows matched.  The stream
+        batch's columns are not outputs: an array a program returns is a
+        copy, so the caller puts the stream batch's own arrays in front."""
+        matched = lbatch.sel & ((hits[0] & jnp.uint32(1)) != 0)
+        b_idx = jnp.where(matched, lo, 0)
+        rcols = _build_columns_at(build, b_idx, matched)
+        # left / full: an unmatched live row stays, its right side null
+        sel = matched if self.join_type == "inner" else lbatch.sel
+        b_hit = _build_hits(build.capacity, b_idx, matched) \
+            if self.join_type == "full" else None
+        return sel, rcols, b_hit
 
     def _full_remainder(self, build: ColumnarBatch, b_hit) -> ColumnarBatch:
         """FULL OUTER tail: build rows no stream row ever matched, with
@@ -377,16 +423,8 @@ class TpuHashJoinExec(TpuExec):
         lschema = self.children[0].schema
         lcols = [Column.all_null(f.dtype, build.capacity)
                  for f in lschema]
-        rcols = list(build.columns)
-        sel = build.sel & ~b_hit
-        lfields, rfields = self._joined_fields(lschema, build.schema)
-        joined = ColumnarBatch(lcols + rcols, sel,
-                               Schema(lfields + rfields))
-        if self.using_drop:
-            keep_idx = [i for i in range(joined.num_cols)
-                        if i not in self.using_drop]
-            joined = joined.select_columns(keep_idx)
-        return ColumnarBatch(joined.columns, joined.sel, self._schema)
+        return self._joined_batch(lcols, build.columns, build.sel & ~b_hit,
+                                  lschema, build.schema)
 
     def _semi_kernel(self, lbatch: ColumnarBatch, counts):
         if self.join_type == "left_semi":
@@ -440,6 +478,8 @@ class TpuHashJoinExec(TpuExec):
         key = self.kernel_key()
         build_fn = cached_kernel(key + ("build",),
                                  lambda: self._build_kernel)
+        # present and 0 where the gather answers every stream batch
+        self.metrics.add(MN.JOIN_PASS_THROUGH_BATCHES, 0)
 
         def attempt_build(rb):
             # retry-only: the single-build-batch contract forbids
@@ -519,15 +559,26 @@ class TpuHashJoinExec(TpuExec):
             # the words the window width just read can reach: a walk at
             # a wider guess set no bit past it
             hits = hits[:max(1, -(-max_dup // 32))]
-            gather_fn = cached_kernel(
-                key + ("gather", len(hits), out_cap),
-                lambda: functools.partial(self._gather_kernel, out_cap))
-            out = gather_fn(lb, build, lo, counts, starts,
-                            jnp.int64(total), hits)
-            self.metrics.add(MN.JOIN_OUTPUT_SPACE_BATCHES, 1)
             b_hit = None
-            if self.join_type == "full":
-                out, b_hit = out
+            if md <= 1 and out_cap == lb.capacity:
+                # inner, left or full (semi and anti returned above): no
+                # row multiplies and the gather would write a batch of
+                # the stream's own capacity: pass the stream batch through
+                pass_fn = cached_kernel(key + ("passthrough", len(hits)),
+                                        lambda: self._passthrough_kernel)
+                sel, rcols, b_hit = pass_fn(lb, build, lo, hits)
+                out = self._joined_batch(lb.columns, rcols, sel, lb.schema,
+                                         build.schema)
+                self.metrics.add(MN.JOIN_PASS_THROUGH_BATCHES, 1)
+            else:
+                gather_fn = cached_kernel(
+                    key + ("gather", len(hits), out_cap),
+                    lambda: functools.partial(self._gather_kernel, out_cap))
+                out = gather_fn(lb, build, lo, counts, starts,
+                                jnp.int64(total), hits)
+                self.metrics.add(MN.JOIN_OUTPUT_SPACE_BATCHES, 1)
+                if self.join_type == "full":
+                    out, b_hit = out
             # the fetched total IS the live-row count: hand it to
             # downstream adaptive shrinks so they skip their sync
             out.known_rows = total

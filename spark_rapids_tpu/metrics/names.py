@@ -331,8 +331,19 @@ JOIN_OUTPUT_SPACE_BATCHES = register_metric(
     "full join whose pairs were placed from the output's side "
     "(exec/join.py _gather_kernel: the count walk's verified-candidate "
     "bits, one scatter and prefix scans; no key compared twice, no loop "
-    "over the stream batch); equals joinMergedWindowBatches for those "
-    "join types and stays 0 for semi and anti joins; a host integer")
+    "over the stream batch); counts only the batches _gather_kernel "
+    "answered, not those joinPassThroughBatches counts (the two together "
+    "equal joinMergedWindowBatches for those join types); stays 0 for "
+    "semi and anti joins; a host integer")
+JOIN_PASS_THROUGH_BATCHES = register_metric(
+    "joinPassThroughBatches", COUNTER, ESSENTIAL,
+    "stream batches of a one-chip inner, left or full join passed through "
+    "under a mask (exec/join.py _passthrough_kernel, "
+    "jit_join.hashjoin_passthrough): no live stream row had more than one "
+    "candidate and the output's capacity bucket was the stream batch's, "
+    "so the output is the stream batch's own column arrays beside the "
+    "build rows taken at stream capacity; no stream column gathered; "
+    "added on the host after the launch; a host integer")
 JOIN_SEMI_BATCHES = register_metric(
     "joinSemiBatches", COUNTER, ESSENTIAL,
     "stream batches (mesh: finished stream chunks) of a left semi or left "
@@ -340,8 +351,8 @@ JOIN_SEMI_BATCHES = register_metric(
     "_semi_kernel, jit_join.hashjoin_semi: the stream batch with its "
     "selection cut to the rows the count walk matched, or did not; no "
     "gather); added on the host where the mask's program is launched; "
-    "joinOutputSpaceBatches plus this equals joinMergedWindowBatches; a "
-    "host integer")
+    "with joinOutputSpaceBatches and joinPassThroughBatches it sums to "
+    "joinMergedWindowBatches; a host integer")
 JOIN_WALK_STEPS = register_metric(
     "joinWalkSteps", COUNTER, ESSENTIAL,
     "static step counts of the count walks the join launched, summed: "

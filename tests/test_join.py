@@ -535,3 +535,275 @@ def test_probe_kernels_against_plain_pairing(how, case, monkeypatch):
         assert join._dup_guess == 64                    # two words a row
     if case == "window_wider_than_matches":
         assert join._dup_guess >= 16
+
+
+# --------------------------------------------------------------------------
+# the pass-through output: where no live stream row has more than one
+# candidate and the output's capacity bucket is the stream batch's, the
+# stream batch itself comes out under a mask, its columns untouched
+# --------------------------------------------------------------------------
+
+def _pass_through_case(how, case):
+    """-> (join node, stream batch, build batch): 900 stream rows against
+    200 build rows of unique keys (two thirds of the stream rows match),
+    one 1,024-row stream batch, so the output's bucket is the stream's."""
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.exec.base import ExecContext
+    rng = random.Random(len(case) * 100 + len(how))
+    tag = f"{case[:5]}{len(case)}_{how}"
+    n_l, n_r = 900, 200
+    rk = rng.sample(range(300), n_r)
+    lk = [rng.randint(0, 299) for _ in range(n_l)]
+    if case == "null_stream_keys":
+        lk = [None if rng.random() < 0.2 else v for v in lk]
+    s = TpuSession({})
+    k, kr = f"k_{tag}", f"kr_{tag}"
+    if case == "using_drop":
+        kr = k
+    left = s.from_pydict(
+        {k: lk, f"a_{tag}": list(range(n_l)),
+         f"x_{tag}": [rng.randint(0, 9) for _ in lk]},
+        T.Schema([T.StructField(k, T.LongType),
+                  T.StructField(f"a_{tag}", T.LongType),
+                  T.StructField(f"x_{tag}", T.IntegerType)]))
+    right = s.from_pydict(
+        {kr: rk, f"b_{tag}": [f"b-{j}" for j in range(n_r)],
+         f"y_{tag}": [rng.randint(0, 9) for _ in rk]},
+        T.Schema([T.StructField(kr, T.LongType),
+                  T.StructField(f"b_{tag}", T.StringType),
+                  T.StructField(f"y_{tag}", T.IntegerType)]))
+    if case == "using_drop":
+        df = left.join(right, k, how if how != "full" else "inner")
+    elif case == "with_condition":
+        df = left.join(right, (col(k) == col(kr))
+                       & (col(f"x_{tag}") > col(f"y_{tag}")), "inner")
+    else:
+        df = left.join(right, col(k) == col(kr), how)
+    join = _find_join(s.plan(df.plan))
+    # the planner keeps conditional outer joins and full USING joins off
+    # the device; the kernels are the same for every type, so the inner
+    # plan's node stands in with its type set
+    join.join_type = how
+    ctx = ExecContext(s.conf, s.runtime)
+    (lb,) = list(join.children[0].execute(ctx))
+    (rb,) = list(join.children[1].execute(ctx))
+    assert (lb.capacity, rb.capacity) == (1024, 1024)
+    if case == "dead_build_rows":
+        keep = np.random.default_rng(41).random(rb.capacity) > 0.3
+        rb = rb.with_sel(rb.sel & jnp.asarray(keep))
+    return join, lb, rb
+
+
+def _probed(join, lb, rb):
+    """The build and the fused probe at the first batch's guess of 8 ->
+    (build batch, lo, counts, starts, hits, window width, total)."""
+    import jax
+    import numpy as np
+    build, bkeys, h1s = jax.jit(join._build_kernel)(rb)
+    lo, _hi, counts, starts, hits, scalars = jax.jit(
+        lambda *a: join._probe_kernel(8, *a))(lb, build, bkeys, h1s)
+    md, total = (int(x) for x in np.asarray(scalars))
+    return build, lo, counts, starts, hits, md, total
+
+
+@pytest.mark.parametrize("case", [
+    "unique_keys", "with_condition", "using_drop", "null_stream_keys",
+    "dead_build_rows"])
+@pytest.mark.parametrize("how", ["inner", "left", "full"])
+def test_pass_through_equals_output_space(how, case):
+    """The pass-through output against the output-space gather's over the
+    same probe: the same live rows in the same order, the selection the
+    matched rows (inner) or the stream's own (left, full), the right
+    side's validity slot for slot over the live rows, the same build hits
+    (full); and `_join_stream` takes it, with the stream batch's own
+    column arrays in the output and the total as its known row count."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.columnar.batch import bucket_rows
+    from spark_rapids_tpu.metrics import names as MN
+    join, lb, rb = _pass_through_case(how, case)
+    build, lo, counts, starts, hits, md, total = _probed(join, lb, rb)
+    assert md <= 1 and bucket_rows(max(total, 1)) == lb.capacity
+    hits = hits[:1]
+    placed = jax.jit(functools.partial(join._gather_kernel, lb.capacity))(
+        lb, build, lo, counts, starts, jnp.int64(total), hits)
+    sel, rcols, b_hit = jax.jit(join._passthrough_kernel)(lb, build, lo,
+                                                          hits)
+    passed = join._joined_batch(lb.columns, rcols, sel, lb.schema,
+                                build.schema)
+    if how == "full":
+        placed, placed_hit = placed
+        np.testing.assert_array_equal(np.asarray(b_hit),
+                                      np.asarray(placed_hit))
+    else:
+        assert b_hit is None
+    rows = passed.to_pylist()
+    assert rows == placed.to_pylist()
+    assert len(rows) == total and total > 100
+    live = np.asarray(passed.sel)
+    matched = np.asarray(rcols[0].valid) & np.asarray(lb.sel)
+    np.testing.assert_array_equal(
+        live, matched if how == "inner" else np.asarray(lb.sel))
+    n_left = len(lb.columns)
+    assert all(i >= n_left for i in join.using_drop)
+    assert len(passed.columns) == len(placed.columns) \
+        == n_left + len(rb.columns) - len(join.using_drop)
+    for p, g in zip(passed.columns[n_left:], placed.columns[n_left:]):
+        np.testing.assert_array_equal(np.asarray(p.valid)[live],
+                                      np.asarray(g.valid)[:total])
+    if how != "inner":
+        assert 0 < matched.sum() < total       # some rows are unmatched
+
+    outs = list(join._join_stream(rb, [lb]))
+    out = outs[0]
+    assert all(o.data is i.data for o, i in zip(out.columns, lb.columns))
+    assert out.known_rows == total and out.to_pylist() == rows
+    got = join.metrics.values
+    assert got.get(MN.JOIN_PASS_THROUGH_BATCHES, 0) == 1
+    assert got.get(MN.JOIN_OUTPUT_SPACE_BATCHES, 0) == 0
+
+
+def _bypass_query(case):
+    """-> a query whose one stream batch the pass-through must NOT take:
+    a build key duplicated (window width 2), a selective join (1,024-row
+    output bucket of a 4,096-row stream batch), or every key's hash
+    forged onto 4 prefixes (distinct build keys share a window)."""
+    def q(s):
+        rng = random.Random(len(case) + 41)
+        n_l = 4000 if case == "selective" else 900
+        lk = [rng.randint(0, n_l - 1) for _ in range(n_l)]
+        rk = rng.sample(range(n_l), 100 if case == "selective" else 200)
+        if case == "duplicate_build_key":
+            rk[1] = rk[0]
+            lk[5] = rk[0]
+        tag = f"bp{len(case)}"
+        left = s.from_pydict(
+            {f"k_{tag}": lk, f"a_{tag}": list(range(n_l))},
+            T.Schema([T.StructField(f"k_{tag}", T.LongType),
+                      T.StructField(f"a_{tag}", T.LongType)]))
+        right = s.from_pydict(
+            {f"kr_{tag}": rk, f"b_{tag}": list(range(len(rk)))},
+            T.Schema([T.StructField(f"kr_{tag}", T.LongType),
+                      T.StructField(f"b_{tag}", T.LongType)]))
+        return left.join(right, col(f"k_{tag}") == col(f"kr_{tag}"),
+                         "inner")
+    return q
+
+
+@pytest.mark.parametrize("case", ["duplicate_build_key", "selective",
+                                  "prefix_collision"])
+def test_pass_through_bypasses(case, monkeypatch):
+    """Where a row may multiply or the output compacts, the output-space
+    gather answers, as before, and the answer is the host executors'."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.exec import join as J
+    if case == "prefix_collision":
+        real = J.hash_columns_double
+
+        def forged(cols, sel):
+            h1, h2 = real(cols, sel)
+            h = ((h1 >> jnp.uint64(62)) << jnp.uint64(62)) \
+                | (h1 & jnp.uint64(0xFF))
+            return jnp.where(sel, h, jnp.uint64(2**64 - 1)), h2
+        monkeypatch.setattr(J, "hash_columns_double", forged)
+    q = _bypass_query(case)
+    s = TpuSession({})
+    before = dict(s.query_metrics_total)
+    q(s).collect()
+
+    def moved(name):
+        return s.query_metrics_total.get(name, 0) - before.get(name, 0)
+    assert moved("joinMergedWindowBatches") == 1
+    assert moved("joinOutputSpaceBatches") == 1
+    assert moved("joinPassThroughBatches") == 0
+    assert_tpu_and_cpu_are_equal(q)
+
+
+@pytest.mark.parametrize("how", ["inner", "left"])
+def test_pass_through_and_output_space_batches_counted(how):
+    """Three stream batches of 1,024 rows; the third holds the one key the
+    build side has twice: two batches pass through, the third is gathered,
+    and the two counters sum to `joinMergedWindowBatches`."""
+    from spark_rapids_tpu.engine import TpuSession
+
+    def q(s):
+        n_l = 3000
+        lk = list(range(n_l))
+        lk[2500] = 5000
+        rk = list(range(0, n_l, 5)) + [5000, 5000]
+        left = s.from_pydict(
+            {"kcnt": lk, "acnt": list(range(n_l))},
+            T.Schema([T.StructField("kcnt", T.LongType),
+                      T.StructField("acnt", T.LongType)]))
+        right = s.from_pydict(
+            {"krcnt": rk, "bcnt": list(range(len(rk)))},
+            T.Schema([T.StructField("krcnt", T.LongType),
+                      T.StructField("bcnt", T.LongType)]))
+        return left.join(right, col("kcnt") == col("krcnt"), how)
+    conf = {"spark.rapids.sql.reader.batchSizeRows": "1024"}
+    s = TpuSession(dict(conf))
+    before = dict(s.query_metrics_total)
+    rows = q(s).collect()
+    assert len(rows) == (601 if how == "inner" else 3001)
+
+    def moved(name):
+        return s.query_metrics_total.get(name, 0) - before.get(name, 0)
+    assert moved("joinMergedWindowBatches") == 3
+    assert moved("joinPassThroughBatches") == 2
+    assert moved("joinOutputSpaceBatches") == 1
+    assert_tpu_and_cpu_are_equal(q, conf)
+
+
+def test_whole_stage_over_a_join_does_not_donate_the_stream_arrays(
+        monkeypatch):
+    """The pass-through output holds the stream batch's own arrays, so no
+    consumer may donate them: the fusion pass never marks a stage over a
+    join donatable, and with donation on and the scan cache off (the case
+    where a scan's batches are donated) every stream array the join handed
+    on is alive after the query."""
+    import jax
+    from spark_rapids_tpu.engine import TpuSession
+    from spark_rapids_tpu.exec import join as J
+    from spark_rapids_tpu.exec.whole_stage import TpuWholeStageExec
+    from spark_rapids_tpu.plan.fusion import source_donatable
+    conf = {"spark.rapids.sql.tpu.memoryScanCache.enabled": "false",
+            "spark.rapids.sql.tpu.donation.enabled": "true"}
+    handed_on = []
+    real = J.TpuHashJoinExec._joined_batch
+
+    def spy(self, lcols, rcols, sel, lschema, rschema):
+        if not isinstance(sel, jax.core.Tracer):    # not inside a program
+            handed_on.extend(c.data for c in lcols)
+        return real(self, lcols, rcols, sel, lschema, rschema)
+    monkeypatch.setattr(J.TpuHashJoinExec, "_joined_batch", spy)
+
+    def q(s):
+        left = keyed_df(s, 141, 900, key_range=400, null_ratio=0.0,
+                        extra={"a": T.LongType})
+        right = s.from_pydict(
+            {"kr": list(range(0, 400, 2)), "b": list(range(200))},
+            T.Schema([T.StructField("kr", T.IntegerType),
+                      T.StructField("b", T.LongType)]))
+        return left.join(right, col("k") == col("kr"), "inner") \
+            .select((col("a") + col("b")).alias("s"), col("k"))
+    s = TpuSession(dict(conf))
+    plan = s.plan(q(s).plan)
+    stages = []
+
+    def walk(n):
+        if isinstance(n, TpuWholeStageExec) \
+                and isinstance(n.children[0], J.TpuHashJoinExec):
+            stages.append(n)
+        for c in n.children:
+            walk(c)
+    walk(plan)
+    assert stages and not any(ws.donate_inputs for ws in stages)
+    assert not source_donatable(stages[0].children[0])
+    assert_tpu_and_cpu_are_equal(q, conf)
+    assert handed_on
+    assert not any(a.is_deleted() for a in handed_on)

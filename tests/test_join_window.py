@@ -445,10 +445,13 @@ def test_merged_window_counter_counts_probe_batches():
 
 @pytest.mark.parametrize("how", ["inner", "left_semi"])
 def test_output_space_and_walk_step_counters(how):
-    """`joinOutputSpaceBatches` is `joinMergedWindowBatches` where pairs
-    are placed (inner, left, full) and 0 for a semi join; `joinWalkSteps`
-    sums the step counts the count walks were asked for: the first batch
-    probes at 8 and reads a window width of 1, the three after it at 1."""
+    """`joinOutputSpaceBatches` plus `joinPassThroughBatches` is
+    `joinMergedWindowBatches` where pairs are placed (inner, left, full)
+    and both are 0 for a semi join; here every build key is unique and the
+    output's capacity bucket (1,024) is the stream batches', so every
+    batch passes through.  `joinWalkSteps` sums the step counts the count
+    walks were asked for: the first batch probes at 8 and reads a window
+    width of 1, the three after it at 1."""
     from spark_rapids_tpu.engine import TpuSession
     s = TpuSession({"spark.rapids.sql.reader.batchSizeRows": "128"})
     left = s.from_pydict({"kc": list(range(500))},
@@ -463,6 +466,7 @@ def test_output_space_and_walk_step_counters(how):
         return s.query_metrics_total.get(name, 0) - before.get(name, 0)
     batches = moved("joinMergedWindowBatches")
     assert batches >= 1
-    assert moved("joinOutputSpaceBatches") == \
+    assert moved("joinOutputSpaceBatches") == 0
+    assert moved("joinPassThroughBatches") == \
         (batches if how == "inner" else 0)
     assert moved("joinWalkSteps") == 8 + (batches - 1)

@@ -133,9 +133,10 @@ def test_the_counters_read_the_batches_the_plan_implies():
     assert moved.get("aggStreamedBatches", 0) == 0
     assert moved.get("numCpuFallbacks", 0) == 0
     # three joins: the customer, the semi and LINEITEM's; the semi join
-    # places no pairs
+    # places no pairs, the others place them or pass the stream through
     assert moved["joinMergedWindowBatches"] == (
-        moved["joinOutputSpaceBatches"] + moved["joinSemiBatches"])
+        moved.get("joinOutputSpaceBatches", 0)
+        + moved.get("joinPassThroughBatches", 0) + moved["joinSemiBatches"])
 
 
 def test_the_q3_shape_answers_through_no_semi_join():
