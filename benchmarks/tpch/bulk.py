@@ -1,11 +1,11 @@
 """Numpy-vectorised TPC-H lineitem/orders at SF-scale row counts, and the
-three query shapes bench.py and chip_smoke.py both drive over them.
+three query shapes chip_smoke.py and the tests drive over them.
 
 `datagen.generate` builds python lists row by row (fine at SF0.001 for the
 22-query tests, hours at SF1); these generators draw whole columns at once
 so 6M lineitem rows take seconds.  Column widths are the published ones
-(64-bit ints/doubles, 1-char flags); value distributions are bench.py's
-since round 1, so its series stays comparable.
+(64-bit ints/doubles, 1-char flags); value distributions are fixed, so
+a series over them stays comparable.
 """
 from __future__ import annotations
 

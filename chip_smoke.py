@@ -45,8 +45,7 @@ def emit(**kw) -> None:
 
 
 class CompileWatch:
-    """Counts XLA compiles (eager programs included) and their seconds,
-    the way bench.py's fusion stage counts them."""
+    """Counts XLA compiles (eager programs included) and their seconds."""
 
     def __init__(self):
         import jax

@@ -7,14 +7,14 @@ cd "$(dirname "$0")/.."
 
 echo "== lint (pyflakes-level) =="
 if command -v ruff >/dev/null 2>&1; then
-    ruff check spark_rapids_tpu tests benchmarks bench.py __graft_entry__.py
+    ruff check spark_rapids_tpu tests benchmarks __graft_entry__.py
 else
-    python -m pyflakes spark_rapids_tpu tests benchmarks bench.py \
+    python -m pyflakes spark_rapids_tpu tests benchmarks \
         __graft_entry__.py 2>/dev/null || \
     python -m flake8 --select=E9,F spark_rapids_tpu tests benchmarks \
-        bench.py __graft_entry__.py 2>/dev/null || \
+        __graft_entry__.py 2>/dev/null || \
     echo "(no ruff/pyflakes/flake8 in image; syntax-checking instead)" && \
-    python -m compileall -q spark_rapids_tpu tests benchmarks bench.py \
+    python -m compileall -q spark_rapids_tpu tests benchmarks \
         __graft_entry__.py
 fi
 
@@ -192,17 +192,15 @@ python -m pytest tests/test_streaming.py -q -m "not slow" \
     -p no:cacheprovider
 echo "== streaming tier took $((SECONDS - T_STRM))s =="
 
-echo "== roofline tier =="
-# roofline-attribution profiler (ISSUE 13): cost-declaration coverage
-# (every plan node of the q1/q6 shapes names a bottleneck resource),
-# profile-tree invariants (op-row bytes never exceed the stage
-# declaration), the prometheus round-trip property (histogram buckets,
-# _sum/_count, escaped label values), SLO histogram percentiles,
-# scheduler fairness visibility, and the profiler-overhead ceiling
-T_ROOF=$SECONDS
-python -m pytest tests/test_roofline.py -q -m "not slow" \
+echo "== movement/SLO tier =="
+# data-movement counters (h2d/d2h/wire/ici bytes against what each
+# counting site moved), the prometheus round-trip property (histogram
+# buckets, _sum/_count, escaped label values), SLO histogram
+# percentiles, scheduler fairness visibility, spill-phase attribution
+T_MOVE=$SECONDS
+python -m pytest tests/test_movement_slo.py -q -m "not slow" \
     -p no:cacheprovider
-echo "== roofline tier took $((SECONDS - T_ROOF))s =="
+echo "== movement/SLO tier took $((SECONDS - T_MOVE))s =="
 
 echo "== chaos tier =="
 # fault-recovery chaos (ISSUE 15): injectCrash grammar (site/scope
@@ -297,17 +295,6 @@ else
     python -m pytest tests/ -q
 fi
 echo "== fast tier took $((SECONDS - T_TESTS))s =="
-
-echo "== profile-regression gate =="
-# ISSUE 13: a fresh roofline capture (per-operator achieved-vs-peak
-# ledgers for q1/q6 + serving SLO phase p95s + the profiler's own
-# overhead) is diffed against the checked-in BASELINE_PROFILE.json at a
-# generous (5x) tolerance — catches an operator falling off its fused
-# path or a phase exploding, not single-digit noise.  After a
-# deliberate perf change: scripts/profile_regression.py --bless
-T_PROF=$SECONDS
-JAX_PLATFORMS=cpu python scripts/profile_regression.py
-echo "== profile-regression gate took $((SECONDS - T_PROF))s =="
 
 echo "== multichip dryrun =="
 T_DRY=$SECONDS

@@ -11,7 +11,7 @@ A relative gate on a sub-second query is noise-dominated, so the gate
 passes when EITHER the relative overhead is within budget OR the
 absolute delta is under --floor-s (default 80ms): a 3% blip on a 0.4s
 query is scheduler jitter, not a regression.  Results land in
-BENCH_OBS.json next to the other committed bench artifacts.
+BENCH_OBS.json at the root of the repo.
 
 Usage: python scripts/obs_overhead.py [--rows N] [--reps K]
        [--budget-pct P] [--floor-s S] [--out BENCH_OBS.json]

@@ -147,10 +147,10 @@ TPU_ALLOC_FRACTION = _conf(
 TPU_POOL_SIZE = _conf(
     "spark.rapids.memory.tpu.poolSizeBytes", 0,
     "Absolute accounted HBM pool budget in bytes; overrides allocFraction "
-    "when > 0.  The knob memory-budget sweeps (bench.py pressure stage) "
-    "and the serving tier's per-query budgets are expressed in — an exact "
-    "byte budget is reproducible across hosts where a fraction of "
-    "detected HBM is not.", to_bytes)
+    "when > 0.  The knob memory-budget sweeps and the serving tier's "
+    "per-query budgets are expressed in — an exact byte budget is "
+    "reproducible across hosts where a fraction of detected HBM is "
+    "not.", to_bytes)
 HOST_SPILL_STORAGE_SIZE = _conf(
     "spark.rapids.memory.host.spillStorageSize", 1 << 30,
     "Bytes of host memory to use for spilled device buffers before spilling "
@@ -687,59 +687,6 @@ METRICS_JOURNAL_DIR = _conf(
     ".jsonl trace shard each (docs/monitoring.md, Distributed tracing).",
     str)
 
-# --- roofline-attribution profiler (metrics/roofline.py) ---------------------
-ROOFLINE_ENABLED = _conf(
-    "spark.rapids.sql.tpu.roofline.enabled", True,
-    "Roofline ledger annotations in EXPLAIN METRICS: each plan node's "
-    "line gains its bottleneck resource (hbm / h2d / d2h / wire / flops "
-    "/ host), achieved rate, and utilization vs the resource's peak, "
-    "joined from the operators' cost declarations and measured span "
-    "durations.  The underlying cost COUNTERS (hbmBytesRead/Written, "
-    "h2dBytes, d2hBytes, wireBytes, estFlops) are ordinary MODERATE "
-    "metrics gated by metrics.level, not by this flag.  See "
-    "docs/monitoring.md, 'Reading the roofline ledger'.", _to_bool)
-ROOFLINE_COST_ENABLED = _conf(
-    "spark.rapids.sql.tpu.roofline.costAccounting.enabled", True,
-    "Per-operator roofline cost declarations (hbmBytesRead/Written, "
-    "h2dBytes, d2hBytes, wireBytes, estFlops — free host-side metadata "
-    "increments).  Off disables the declarations entirely (every ledger "
-    "node reads host-bound), which is the A/B the bench profile stage "
-    "and tests/test_roofline.py measure profiler overhead with.  "
-    "Latched per query like the packed-sort flag: the declarations are "
-    "observability-only, so a concurrent query with a different setting "
-    "at worst records (or skips) its own declarations.", _to_bool)
-ROOFLINE_PEAK_HBM = _conf(
-    "spark.rapids.sql.tpu.roofline.peakHbmGBs", 0.0,
-    "HBM bandwidth roofline in GB/s used as the ledger's denominator "
-    "for the 'hbm' resource.  0 (default) picks the device kind's row "
-    "(819 GB/s on a v5e, a conservative 20 GB/s on the CPU "
-    "backend).  Set it to a measured STREAM-like figure for honest "
-    "utilization percentages on your hardware.", float)
-ROOFLINE_PEAK_LINK = _conf(
-    "spark.rapids.sql.tpu.roofline.peakLinkGBs", 0.0,
-    "Host<->device link roofline in GB/s ('h2d'/'d2h' resources).  "
-    "0 picks the device kind's nominal row (8 GB/s, PCIe-class: not a "
-    "published figure) — setting this to the measured "
-    "transfer_microbench number makes host-detour nodes light up "
-    "honestly.", float)
-ROOFLINE_PEAK_WIRE = _conf(
-    "spark.rapids.sql.tpu.roofline.peakWireGBs", 0.0,
-    "Socket shuffle-wire roofline in GB/s ('wire' resource).  0 picks "
-    "1 GB/s (the measured BENCH_WIRE loopback figure); set to your NIC "
-    "line rate on a real cluster.", float)
-ROOFLINE_PEAK_GFLOPS = _conf(
-    "spark.rapids.sql.tpu.roofline.peakGflops", 0.0,
-    "Compute roofline in GFLOP/s ('flops' resource).  0 picks the "
-    "device kind's row (197 TFLOP/s bf16 on a v5e, 50 GFLOP/s on the "
-    "CPU backend).", float)
-ROOFLINE_PEAK_ICI = _conf(
-    "spark.rapids.sql.tpu.roofline.peakIciGBs", 0.0,
-    "Inter-chip-interconnect roofline in GB/s ('ici' resource): the "
-    "denominator for bytes moved by mesh-lowered exchange collectives "
-    "(iciBytesMoved).  0 picks the device kind's row (1,600 Gbit/s = "
-    "200 GB/s per chip on a v5e; memcpy-class 20 GB/s on the virtual-device CPU "
-    "backend, where the 'collective' is a compiled copy).", float)
-
 # --- distributed tracing (metrics/timeline.py + shuffle wire trace) ----------
 TRACE_ENABLED = _conf(
     "spark.rapids.sql.tpu.trace.enabled", True,
@@ -906,13 +853,13 @@ POLICY_ENABLED = _conf(
     "spark.rapids.sql.tpu.policy.enabled", True,
     "Master switch for the data-movement policy engine (policy/): "
     "next-use spill victim selection, proactive unspill of soon-needed "
-    "buffers, reduce-driven shuffle flow control, and roofline-driven "
-    "codec re-selection.  The engine only CONSUMES signals the ledgers "
+    "buffers, reduce-driven shuffle flow control, and runtime codec "
+    "re-selection.  The engine only CONSUMES signals the ledgers "
     "already produce (memory ledger re-touch history, shuffle read "
-    "order, roofline wire peak) and journals every decision under kind "
-    "'policy'.  false is the kill switch: victim order, fetch admission "
-    "and wire codec revert byte-identically to the pre-policy engine "
-    "(docs/tuning-guide.md, Data-movement policy).", _to_bool)
+    "order, observed wire throughput) and journals every decision "
+    "under kind 'policy'.  false is the kill switch: victim order, fetch "
+    "admission and wire codec revert byte-identically to the pre-policy "
+    "engine (docs/tuning-guide.md, Data-movement policy).", _to_bool)
 POLICY_RETOUCH_WEIGHT = _conf(
     "spark.rapids.sql.tpu.policy.victim.retouchWeight", 4.0,
     "Score bonus protecting a spill victim per prior spill of the same "
@@ -973,7 +920,7 @@ POLICY_CODEC = _conf(
     "spark.rapids.sql.tpu.policy.codec.candidate", "lz4",
     "Codec the policy engine advises for fetches of an exchange proven "
     "wire-bound at runtime (read throughput at or above "
-    "codec.wireBoundFraction of the roofline wire peak at "
+    "codec.wireBoundFraction of the wire peak at "
     "codec.minExchangeBytes volume).  Rides the shuffle compression "
     "negotiation end to end — the server may still answer raw when the "
     "codec is unavailable there.  'none' disables re-selection; a "
@@ -986,10 +933,15 @@ POLICY_CODEC_MIN_BYTES = _conf(
     "exchanges prove nothing and never pay codec CPU.", to_bytes)
 POLICY_CODEC_WIRE_BOUND = _conf(
     "spark.rapids.sql.tpu.policy.codec.wireBoundFraction", 0.5,
-    "Fraction of the roofline wire peak (metrics/roofline.py "
-    "platform_peaks, overridable via ROOFLINE_PEAK_* confs) an "
-    "exchange's observed read throughput must reach to be judged "
-    "wire-bound for codec re-selection.", float)
+    "Fraction of the wire peak (roofline.peakWireGBs) an exchange's "
+    "observed read throughput must reach to be judged wire-bound for "
+    "codec re-selection.", float)
+POLICY_CODEC_WIRE_PEAK = _conf(
+    "spark.rapids.sql.tpu.roofline.peakWireGBs", 0.0,
+    "The codec advisor's wire peak in GB/s: the socket shuffle-wire "
+    "rate an exchange's read throughput is judged against for codec "
+    "re-selection (codec.wireBoundFraction).  0 (default) picks 1 GB/s; "
+    "set it to the NIC line rate on a real cluster.", float)
 
 # --- serving tier (serve/: scheduler, admission, plan cache) -----------------
 SERVE_MAX_CONCURRENT = _conf(

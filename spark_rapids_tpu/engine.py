@@ -30,9 +30,9 @@ from .types import Schema, StructField, from_arrow
 from .utils.tracing import named_range
 
 
-# one shared owner of jax's persistent-cache configuration: engine,
-# bench.py children and the executor worker bootstrap all call this, so
-# the cache knobs cannot drift between entry points
+# one shared owner of jax's persistent-cache configuration: engine, the
+# serving tier and the executor worker bootstrap all call this, so the
+# cache knobs cannot drift between entry points
 from .utils.compile_cache import enable_compilation_cache  # noqa: E402
 
 
@@ -164,7 +164,7 @@ class TpuSession:
     def dump_diagnostics(self, out_dir: Optional[str] = None,
                          reason: str = "manual") -> str:
         """Write a post-mortem diagnostic bundle NOW (config, EXPLAIN
-        with roofline, merged timeline, memledger replay, SLO state,
+        with metrics, merged timeline, memledger replay, SLO state,
         per-process flight-recorder rings) and return its directory.
         Render it with `python -m spark_rapids_tpu.metrics postmortem
         <bundle>` (docs/monitoring.md, Post-mortem bundles)."""

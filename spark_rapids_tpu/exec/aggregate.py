@@ -37,8 +37,7 @@ from ..ops.aggregates import AggregateExpression
 from ..ops.hashing import hash_columns_double
 from ..types import (DoubleType, LongType, Schema, StructField)
 from ..utils.tracing import named_range
-from .base import (ExecContext, ExecNode, TpuExec, record_cost,
-                   record_output_batch)
+from .base import ExecContext, ExecNode, TpuExec, record_output_batch
 from ..metrics import names as MN
 
 _I64_MAX = np.int64(2**63 - 1)
@@ -569,13 +568,6 @@ class TpuHashAggregateExec(TpuExec):
             # (the reference falls back to CPU for these shapes instead;
             # aggregate.scala GpuHashAggregateMeta.tagPlanForGpu)
             self.child_coalesce_goal = "single"
-
-    def _cost_weight(self) -> int:
-        """Per-row op-count estimate for the roofline cost declaration
-        (metrics/roofline.py): the grouped update sorts by key then runs
-        one segmented pass per aggregate — coarse, like every estFlops
-        figure outside the HLO-analyzed whole-stage programs."""
-        return max(1, len(self.grouping) + len(self.aggregates)) * 4
 
     def _distinct_child(self):
         """The single distinct-aggregate child expression, or None.
@@ -1268,15 +1260,6 @@ class TpuHashAggregateExec(TpuExec):
         key = (("whole_stage", k, cap, pre_key, str(treedef))
                + self.kernel_key())
         all_leaves = [leaf for f in flats for leaf in f]
-        # roofline: the absorbed whole-stage program reads every drained
-        # source leaf out of HBM once (metadata sizes, never a sync)
-        record_cost(self.metrics,
-                    hbm_read=sum(
-                        getattr(x, "size", 0)
-                        * getattr(getattr(x, "dtype", None), "itemsize", 1)
-                        for x in all_leaves),
-                    flops=sum(b.capacity for b in batches)
-                    * self._cost_weight())
         # buffer donation for the FINAL whole-stage program (never the
         # bucket probe — a dirty probe re-dispatches the same leaves):
         # the drained source batches are dead after this one dispatch
@@ -1402,12 +1385,10 @@ class TpuHashAggregateExec(TpuExec):
             def attempt_merge(_):
                 # merge allocates the K-way concat: reserve it so the
                 # spill cascade (and the fault injector) see the boundary
-                merge_bytes = sum(p.device_size_bytes() for p in parts)
                 if ctx.runtime is not None:
-                    ctx.runtime.reserve(merge_bytes, site="agg.merge")
-                record_cost(self.metrics, hbm_read=merge_bytes,
-                            flops=sum(p.capacity for p in parts)
-                            * self._cost_weight())
+                    ctx.runtime.reserve(
+                        sum(p.device_size_bytes() for p in parts),
+                        site="agg.merge")
                 # every part's live rows are a prefix: the parts' counts,
                 # in one read, size the merge as concat_batches would
                 counts = jax.device_get(part_rows(parts))  # tpulint: disable=TPU001 the fold's one read: the merge's capacity is the bucket of the parts' live rows
@@ -1480,11 +1461,6 @@ class TpuHashAggregateExec(TpuExec):
             if ctx.runtime is not None:
                 ctx.runtime.reserve(b.device_size_bytes(),
                                     site="agg.update")
-            # roofline: the update kernel reads the batch and does
-            # ~sort + one segmented pass per aggregate (exec/base)
-            record_cost(self.metrics, hbm_read=b.device_size_bytes(),
-                        flops=(b.known_rows if b.known_rows is not None
-                               else b.capacity) * self._cost_weight())
             partial = None
             with self.metrics.timer(MN.SEG_AGG_TIME):
                 bfn = hot["bucket_fn"]
@@ -1632,12 +1608,8 @@ class TpuHashAggregateExec(TpuExec):
             ladder catches, and it does before the step is issued (and
             the carry donated), so a retried batch and the pieces of a
             split one, IN ORDER, merge once each."""
-            nbytes = b.device_size_bytes()
             if ctx.runtime is not None:
-                ctx.runtime.reserve(nbytes, site="agg.update")
-            record_cost(self.metrics, hbm_read=nbytes,
-                        flops=(b.known_rows if b.known_rows is not None
-                               else b.capacity) * self._cost_weight())
+                ctx.runtime.reserve(b.device_size_bytes(), site="agg.update")
             record_dispatch()
             hot["carry"] = step(*pvals, hot["carry"], b)
 

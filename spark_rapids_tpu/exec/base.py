@@ -21,7 +21,6 @@ from ..metrics import names as MN
 # fold + journal integration); re-exported here because mem/runtime.py and
 # half the test suite import it from exec.base
 from ..metrics.registry import Metrics  # noqa: F401
-from ..metrics.roofline import cost_accounting_enabled
 from ..types import Schema
 from ..utils.tracing import SPAN_PREFIX
 
@@ -40,12 +39,6 @@ def record_output_batch(metrics: Metrics, batch, runtime=None) -> None:
     * ESSENTIAL: data-dependent row counting skipped entirely (the count
       of a filtered batch would cost device work)."""
     metrics.add(MN.NUM_OUTPUT_BATCHES, 1)
-    # roofline cost declaration (metrics/roofline.py): every produced
-    # batch is HBM the operator wrote.  device_size_bytes is a static
-    # METADATA bound (shapes x dtype widths, never a sync), and the
-    # metric is MODERATE-gated inside add(), so ESSENTIAL pays nothing.
-    if metrics.level >= MN.MODERATE and cost_accounting_enabled():
-        metrics.add(MN.HBM_BYTES_WRITTEN, batch.device_size_bytes())
     if batch.known_rows is not None:  # host-known: free at every level
         metrics.add(MN.NUM_OUTPUT_ROWS, batch.known_rows)
         if metrics.debug_active and runtime is not None:
@@ -60,22 +53,14 @@ def record_output_batch(metrics: Metrics, batch, runtime=None) -> None:
         metrics.add_lazy(MN.NUM_OUTPUT_ROWS, batch.num_rows())
 
 
-def record_cost(metrics: Metrics, hbm_read: int = 0, hbm_written: int = 0,
-                h2d: int = 0, d2h: int = 0, wire: int = 0, ici: int = 0,
-                flops: float = 0) -> None:
-    """Roofline cost declaration for one dispatch (metrics/roofline.py):
-    bytes the operator moved per resource (HBM, host<->device link,
-    socket wire) plus an estimated op count.  All values must be host-
-    known metadata (batch capacities x dtype widths, expression-tree op
-    counts, wire byte totals) — never a device sync.  The ledger joins
-    these against measured span durations to name each plan node's
-    bottleneck resource."""
-    if metrics.level < MN.MODERATE or not cost_accounting_enabled():
+def record_movement(metrics: Metrics, h2d: int = 0, d2h: int = 0,
+                    wire: int = 0, ici: int = 0) -> None:
+    """Bytes one dispatch moved across the host<->device link (h2d/d2h),
+    the socket shuffle wire and the chips' interconnect (ici).  Values
+    are host-known metadata (batch capacities x dtype widths, wire byte
+    totals), never a device sync; MODERATE and above only."""
+    if metrics.level < MN.MODERATE:
         return
-    if hbm_read:
-        metrics.add(MN.HBM_BYTES_READ, hbm_read)
-    if hbm_written:
-        metrics.add(MN.HBM_BYTES_WRITTEN, hbm_written)
     if h2d:
         metrics.add(MN.H2D_BYTES, h2d)
     if d2h:
@@ -84,8 +69,6 @@ def record_cost(metrics: Metrics, hbm_read: int = 0, hbm_written: int = 0,
         metrics.add(MN.WIRE_BYTES, wire)
     if ici:
         metrics.add(MN.ICI_BYTES_MOVED, ici)
-    if flops:
-        metrics.add(MN.EST_FLOPS, flops)
 
 
 class ExecContext:
@@ -95,11 +78,6 @@ class ExecContext:
                  num_partitions: int = 1, runtime=None, cluster=None,
                  journal=None, query_execution=None):
         self.conf = conf or TpuConf()
-        # roofline cost-accounting latch: observability-only, so
-        # cross-query interleaving is safe
-        from .. import config as _C
-        from ..metrics.roofline import set_cost_accounting
-        set_cost_accounting(self.conf.get(_C.ROOFLINE_COST_ENABLED))
         self.partition_id = partition_id
         self.num_partitions = num_partitions
         self.runtime = runtime  # mem.runtime.TpuRuntime when active

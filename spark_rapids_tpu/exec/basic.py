@@ -27,7 +27,7 @@ from ..ops.cpu_eval import (cpu_cols_to_table, cpu_eval, table_to_cpu_cols)
 from ..types import BooleanType, Schema, StructField
 from ..utils.tracing import named_range
 from .base import (CpuExec, ExecContext, ExecNode, TpuExec,
-                   record_cost, record_output_batch)
+                   record_movement, record_output_batch)
 
 
 def _pred_keep(col: Column):
@@ -103,10 +103,8 @@ class TpuScanMemoryExec(TpuExec):
                 batch = ColumnarBatch.from_arrow(chunk)
             self.metrics.add(MN.NUM_OUTPUT_ROWS, chunk.num_rows)
             self.metrics.add(MN.NUM_OUTPUT_BATCHES, 1)
-            # cost declaration: the H2D edge — the adopted batch crossed
-            # the host->device link and landed in HBM
-            record_cost(self.metrics, h2d=batch.device_size_bytes(),
-                        hbm_written=batch.device_size_bytes())
+            # the adopted batch crossed the host->device link
+            record_movement(self.metrics, h2d=batch.device_size_bytes())
             if use_cache:
                 # pinned BEFORE the first consumer sees it: a cached
                 # batch is re-served to later queries, so a downstream
@@ -136,30 +134,8 @@ class RowLocalExec(TpuExec):
     """A device op whose per-batch work is a pure batch->batch function —
     the fusion unit for FusedPipelineExec."""
 
-    # per-row op-count estimate of expressions(), cached lazily (roofline
-    # cost declaration; None until the first batch)
-    _flops_per_row = None
-
     def batch_fn(self):
         raise NotImplementedError
-
-    def _record_batch_cost(self, batch: ColumnarBatch) -> None:
-        """Roofline cost declaration for one dispatched input batch:
-        the kernel reads the whole input footprint from HBM and runs
-        ~flops-per-row x rows ops (metrics/roofline.py; the output
-        write side is record_output_batch's)."""
-        from ..metrics.roofline import cost_accounting_enabled
-        if self.metrics.level < MN.MODERATE \
-                or not cost_accounting_enabled():
-            return
-        if self._flops_per_row is None:
-            from ..metrics.roofline import estimate_expr_flops
-            self._flops_per_row = max(1, estimate_expr_flops(
-                self.expressions()))
-        rows = batch.known_rows if batch.known_rows is not None \
-            else batch.capacity
-        record_cost(self.metrics, hbm_read=batch.device_size_bytes(),
-                    flops=self._flops_per_row * rows)
 
     def expressions(self) -> List[E.Expression]:
         return []
@@ -250,7 +226,6 @@ class RowLocalExec(TpuExec):
                     fkey,
                     lambda: functools.partial(E.eval_with_row_offset,
                                               self.batch_fn()))
-                self._record_batch_cost(batch)
                 self.count_input(batch.capacity)
                 with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                     record_dispatch()
@@ -268,7 +243,6 @@ class RowLocalExec(TpuExec):
             for batch in self.children[0].execute(ctx):
                 fn = cached_kernel(key + (E.current_input_file(),),
                                    self.batch_fn)
-                self._record_batch_cost(batch)
                 self.count_input(batch.capacity)
                 with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                     record_dispatch()
@@ -282,7 +256,6 @@ class RowLocalExec(TpuExec):
         # recompiles per constant, so baked Parameter values stay correct)
         fn = self.parameterized_kernel()
         for batch in self.children[0].execute(ctx):
-            self._record_batch_cost(batch)
             self.count_input(batch.capacity)
             with named_range(self.name, self.metrics, MN.TOTAL_TIME):
                 record_dispatch()
@@ -429,10 +402,6 @@ class TpuCoalesceBatchesExec(TpuExec):
             yield self._flush(pending)
 
     def _flush(self, pending):
-        # cost declaration: a concat/compact reads every pending batch
-        # out of HBM (the write side is record_output_batch's)
-        record_cost(self.metrics,
-                    hbm_read=sum(b.device_size_bytes() for b in pending))
         with self.metrics.timer(MN.CONCAT_TIME):
             if len(pending) == 1:
                 out = pending[0].compact()
@@ -585,8 +554,7 @@ class HostToDeviceExec(TpuExec):
                 batch = ColumnarBatch.from_arrow(table)
             self.metrics.add(MN.NUM_OUTPUT_ROWS, table.num_rows)
             self.metrics.add(MN.NUM_OUTPUT_BATCHES, 1)
-            record_cost(self.metrics, h2d=batch.device_size_bytes(),
-                        hbm_written=batch.device_size_bytes())
+            record_movement(self.metrics, h2d=batch.device_size_bytes())
             yield batch
 
 
@@ -602,11 +570,10 @@ class DeviceToHostExec(CpuExec):
 
     def execute_cpu(self, ctx):
         for batch in self.children[0].execute(ctx):
-            # cost declaration: the D2H edge reads the batch out of HBM
-            # and moves it over the link to the host (shape metadata,
-            # never a read)
+            # the D2H edge moves the batch over the link to the host
+            # (shape metadata, never a read)
             nbytes = batch.device_size_bytes()
-            record_cost(self.metrics, d2h=nbytes, hbm_read=nbytes)
+            record_movement(self.metrics, d2h=nbytes)
             # the wait for the device and the copy to the host
             with named_range("d2h", self.metrics, MN.D2H_TIME,
                              bytes=nbytes):

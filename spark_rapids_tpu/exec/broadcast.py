@@ -19,7 +19,8 @@ import numpy as np
 
 from ..columnar import ColumnarBatch, concat_batches
 from ..mem.buffer import SpillPriorities, batch_to_host, host_to_batch
-from .base import CpuExec, ExecContext, ExecNode, TpuExec, record_cost
+from .base import (CpuExec, ExecContext, ExecNode, TpuExec,
+                   record_movement)
 from .join import TpuHashJoinExec
 from ..metrics import names as MN
 from ..utils.tracing import named_range
@@ -61,10 +62,10 @@ class TpuBroadcastExchangeExec(TpuExec):
         # leaf and is on the host already
         self.metrics.add(MN.BROADCAST_BYTES, meta.size_bytes)
         self.metrics.add(MN.BROADCAST_ROWS, int(np.count_nonzero(leaves[-1])))
-        # roofline: the broadcast payload left the device (d2h) and is
+        # the broadcast payload left the device (d2h) and is
         # re-published to every executor over the wire
-        record_cost(self.metrics, d2h=meta.size_bytes,
-                    wire=meta.size_bytes)
+        record_movement(self.metrics, d2h=meta.size_bytes,
+                        wire=meta.size_bytes)
         return leaves, meta
 
     def materialize_host(self, ctx: ExecContext):

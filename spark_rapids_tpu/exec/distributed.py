@@ -38,7 +38,7 @@ from ..parallel.distributed import (run_distributed_aggregate,
                                     run_distributed_sort)
 from ..utils.tracing import named_range
 from .aggregate import TpuHashAggregateExec
-from .base import ExecContext, record_cost, record_output_batch
+from .base import ExecContext, record_movement, record_output_batch
 from .join import TpuHashJoinExec, _empty_batch
 from .sort import TpuSortExec
 from ..metrics import names as MN
@@ -77,7 +77,7 @@ def _ici_declarer(metrics):
     interconnect bytes on the operator's metrics, from host-known metadata
     (parallel/distributed.exchange_ici_bytes), as exec/exchange.py does for
     the generic exchange."""
-    return lambda nbytes: record_cost(metrics, ici=nbytes)
+    return lambda nbytes: record_movement(metrics, ici=nbytes)
 
 
 def _stage_chunk(batches, mesh, min_cap: int):

@@ -27,7 +27,7 @@ from ..shuffle.partition import (hash_partition_ids, range_partition_ids,
                                  round_robin_partition_ids,
                                  sample_range_bounds, single_partition_ids,
                                  split_by_partition)
-from .base import (ExecContext, ExecNode, TpuExec, record_cost,
+from .base import (ExecContext, ExecNode, TpuExec, record_movement,
                    record_output_batch)
 from ..metrics import names as MN
 
@@ -220,11 +220,10 @@ class TpuShuffleExchangeExec(TpuExec):
                 st = h.stats()
                 self.metrics.add(MN.MAP_OUTPUT_BYTES, st.total_bytes)
                 self.metrics.add(MN.NUM_ICI_EXCHANGES, 1)
-                # roofline: the map phase moved every partition through
-                # the mesh collectives — LOGICAL bytes on the 'ici'
-                # resource (codec-invariant like the AQE map stats);
-                # nothing touched the host link or the socket wire
-                record_cost(self.metrics, ici=st.total_bytes)
+                # the map phase moved every partition through the mesh
+                # collectives — LOGICAL bytes (codec-invariant like the
+                # AQE map stats); nothing touched the host link or wire
+                record_movement(self.metrics, ici=st.total_bytes)
                 ctx.add_cleanup(h.release)
                 journal_event("stage", "mapStage", shuffle=h.sid,
                               partitions=n, bytes=st.total_bytes,
@@ -262,14 +261,13 @@ class TpuShuffleExchangeExec(TpuExec):
             h = _ShuffleHandle(sid, n, env=env)
         st = h.stats()
         self.metrics.add(MN.MAP_OUTPUT_BYTES, st.total_bytes)
-        # roofline: the map phase materialized every partition off the
-        # device (d2h) and declared it to the shuffle wire.  Wire
-        # declarations are LOGICAL (uncompressed) bytes on BOTH sides,
-        # like the codec-invariant AQE map stats — under a shuffle codec
-        # the physical traffic is smaller (transport counter
-        # compressed_bytes_sent has the actual figure)
-        record_cost(self.metrics, d2h=st.total_bytes,
-                    wire=st.total_bytes)
+        # the map phase materialized every partition off the device
+        # (d2h) and handed it to the shuffle wire.  Wire bytes are
+        # LOGICAL (uncompressed) on BOTH sides, like the codec-invariant
+        # AQE map stats — under a shuffle codec the physical traffic is
+        # smaller (transport counter compressed_bytes_sent has it)
+        record_movement(self.metrics, d2h=st.total_bytes,
+                        wire=st.total_bytes)
         journal_event("stage", "mapStage", shuffle=h.sid, partitions=n,
                       bytes=st.total_bytes, rows=st.total_rows,
                       maps=st.num_map_tasks, tier="socket")
@@ -329,23 +327,17 @@ class TpuShuffleExchangeExec(TpuExec):
         wire_seen = [0]
         t0 = time.perf_counter()
 
-        def with_read_cost(pairs):
-            # roofline: on the socket tier every coalesced partition
-            # batch came OFF the shuffle wire and back over the
-            # host->device link; on the mesh tier the read is a device-
-            # local split of the exchanged chunks (HBM only — the
-            # movement itself was declared as 'ici' at materialize).
-            # LOGICAL bytes either way — consistent under any codec
+        def with_read_movement(pairs):
+            # on the socket tier every coalesced partition batch came OFF
+            # the shuffle wire and back over the host->device link
+            # (LOGICAL bytes, consistent under any codec); on the mesh
+            # tier the read is a device-local split of the exchanged
+            # chunks, whose movement was counted as ici at materialize
             for p, out in pairs:
-                if out is not None:
-                    if is_mesh:
-                        record_cost(self.metrics,
-                                    hbm_read=out.device_size_bytes())
-                    else:
-                        record_cost(self.metrics,
-                                    wire=out.device_size_bytes(),
-                                    h2d=out.device_size_bytes())
-                        wire_seen[0] += out.device_size_bytes()
+                if out is not None and not is_mesh:
+                    nbytes = out.device_size_bytes()
+                    record_movement(self.metrics, wire=nbytes, h2d=nbytes)
+                    wire_seen[0] += nbytes
                 if pol is not None:
                     for rid in spec_rids[p]:
                         pol.partition_consumed(h.sid, rid)
@@ -354,15 +346,15 @@ class TpuShuffleExchangeExec(TpuExec):
         try:
             with self.metrics.timer(MN.SHUFFLE_READ_TIME):
                 if async_ok:
-                    yield from with_read_cost(
+                    yield from with_read_movement(
                         self._read_specs_async(ctx, h, specs))
                 else:
-                    yield from with_read_cost(
+                    yield from with_read_movement(
                         self._read_specs_sync(ctx, h, specs))
         finally:
             if pol is not None:
                 # runtime evidence for codec re-selection: the observed
-                # read throughput of this exchange vs the wire roofline
+                # read throughput of this exchange vs the wire peak
                 pol.observe_exchange(h.sid, wire_seen[0],
                                      time.perf_counter() - t0)
             h.release()

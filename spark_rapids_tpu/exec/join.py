@@ -72,7 +72,7 @@ from ..utils.tracing import named_range
 from ..ops import expressions as E
 from ..ops.hashing import _normalize_bits, hash_columns_double
 from ..types import Schema, StructField
-from .base import ExecContext, ExecNode, TpuExec, record_cost
+from .base import ExecContext, ExecNode, TpuExec
 from ..metrics import names as MN
 
 
@@ -487,11 +487,6 @@ class TpuHashJoinExec(TpuExec):
             if ctx is not None and ctx.runtime is not None:
                 ctx.runtime.reserve(rb.device_size_bytes(),
                                     site="join.build")
-            # roofline: the build sorts the build side by hash
-            # (~n log n) and keeps it HBM-resident for the probes
-            cap = max(2, rb.capacity)
-            record_cost(self.metrics, hbm_read=rb.device_size_bytes(),
-                        flops=cap * max(1, cap.bit_length()))
             return build_fn(rb)
 
         with named_range("join_build", self.metrics, MN.BUILD_TIME):
@@ -511,13 +506,6 @@ class TpuHashJoinExec(TpuExec):
             if ctx is not None and ctx.runtime is not None:
                 ctx.runtime.reserve(lb.device_size_bytes(),
                                     site="join.probe")
-            # roofline: each probe reads the stream batch AND re-reads
-            # the resident build side (its hashes merge with the stream's)
-            record_cost(self.metrics,
-                        hbm_read=lb.device_size_bytes()
-                        + rbatch.device_size_bytes(),
-                        flops=max(2, lb.capacity)
-                        * max(1, max(2, rbatch.capacity).bit_length()))
             # SPECULATIVE probe: window+count fuse into one dispatch
             # using the previous batch's duplication bucket (stream
             # skew is stable batch to batch); the single scalar fetch

@@ -13,8 +13,7 @@ from typing import Sequence
 from ..columnar import ColumnarBatch, concat_batches
 from ..ops import expressions as E
 from ..ops.sort_keys import sort_order
-from .base import (ExecContext, ExecNode, TpuExec, record_cost,
-                   record_output_batch)
+from .base import ExecContext, ExecNode, TpuExec, record_output_batch
 from ..metrics import names as MN
 
 # which-path record per (sort kernel key, batch capacity), written at
@@ -122,12 +121,6 @@ class TpuSortExec(TpuExec):
             # The reserve marks the sort's working-set boundary.
             if ctx.runtime is not None:
                 ctx.runtime.reserve(b.device_size_bytes(), site="sort")
-            # roofline: a device sort reads the batch and does ~n log n
-            # key comparisons per sort key (metrics/roofline.py)
-            cap = max(2, b.capacity)
-            record_cost(self.metrics, hbm_read=b.device_size_bytes(),
-                        flops=cap * max(1, cap.bit_length())
-                        * max(1, len(self.sort_exprs)))
             out = fn(b)
             if _PACKED_BY_KEY.get((skey, b.capacity)):
                 self.metrics.add(MN.NUM_PACKED_SORTS, 1)

@@ -44,7 +44,7 @@ from ..columnar import ColumnarBatch
 from ..metrics import names as MN
 from ..metrics.journal import journal_event
 from ..utils.tracing import named_range
-from .base import ExecContext, ExecNode, record_cost, record_output_batch
+from .base import ExecContext, ExecNode, record_output_batch
 from .basic import FusedPipelineExec, RowLocalExec, TpuExpandExec
 
 
@@ -67,11 +67,6 @@ class TpuWholeStageExec(FusedPipelineExec):
         self.donate_inputs = False
         self._folded_batches = 0
         self._folded_rows = 0.0
-        # roofline: stage-level cost already folded into per-op rows
-        # (lazy, like _folded_batches) and the per-op expression weights
-        # the split is proportional to
-        self._folded_cost = {}
-        self._op_weights = None
 
     def describe(self):
         inner = " -> ".join(s.name for s in self.stages)
@@ -111,26 +106,6 @@ class TpuWholeStageExec(FusedPipelineExec):
             # batches never materialize): attribute it to the last op
             self._folded_rows = rows
             self.stages[-1].metrics.add(MN.NUM_OUTPUT_ROWS, d_rows)
-        # roofline cost attribution: split the stage's declared cost
-        # across the constituent ops proportional to their expression
-        # op-count weights, rounding DOWN — so the bytes accounted by
-        # the op rows can never exceed the stage's own declaration
-        # (the profile-tree invariant tests/test_roofline.py asserts)
-        from ..metrics.roofline import (ALL_COST_METRICS,
-                                        estimate_expr_flops)
-        if self._op_weights is None:
-            self._op_weights = [max(1, estimate_expr_flops(
-                s.expressions())) for s in self.stages]
-        total_w = sum(self._op_weights) or 1
-        for mk in ALL_COST_METRICS:
-            cur = vals.get(mk, 0)
-            d = cur - self._folded_cost.get(mk, 0)
-            if d > 0:
-                self._folded_cost[mk] = cur
-                for s, w in zip(self.stages, self._op_weights):
-                    share = int(d * w // total_w)
-                    if share > 0:
-                        s.metrics.add(mk, share)
 
     # ---- execution ---------------------------------------------------------
 
@@ -154,7 +129,7 @@ class TpuWholeStageExec(FusedPipelineExec):
             yield from RowLocalExec.execute(self, ctx)
             return
         from ..utils.kernel_cache import (param_free_keys, record_dispatch,
-                                          stage_cost, stage_executable)
+                                          stage_executable)
         from .retryable import run_retryable
         from ..mem.retry import RetryExhausted, split_batch_rows
         from ..ops import expressions as E
@@ -188,15 +163,6 @@ class TpuWholeStageExec(FusedPipelineExec):
         donate_ok = bool(ctx.conf.get(C.DONATION_ENABLED)) \
             and self.donate_inputs
 
-        # roofline: the cost analysis of the LAST compiled program this
-        # stage dispatched (utils/kernel_cache.stage_cost — XLA's HLO
-        # flop/byte counts), captured per batch for the cost declaration
-        dispatch_cost = [{}]
-        cost_totals = {"flops": 0.0, "bytes": 0.0, "hlo_batches": 0}
-        from ..metrics.roofline import cost_accounting_enabled
-        moderate = self.metrics.level >= MN.MODERATE \
-            and cost_accounting_enabled()
-
         def attempt(b):
             if ctx.runtime is not None:
                 ctx.runtime.reserve(self._reserve_estimate(b),
@@ -210,14 +176,6 @@ class TpuWholeStageExec(FusedPipelineExec):
                                   metrics=self.metrics,
                                   name=f"wholeStage-{self.stage_id}",
                                   donate_argnums=(0,) if don else ())
-            # looked up BEFORE the dispatch: a donating executable
-            # deletes b's buffers, and the cost is keyed like the
-            # executable so the entry is warm right after compilation.
-            # Gated — the lookup re-flattens the args pytree, host work
-            # the costAccounting-off path must not pay per batch
-            if moderate:
-                dispatch_cost[0] = stage_cost(
-                    key, args, donate_argnums=(0,) if don else ())
             record_dispatch()
             if don:
                 donation.record_donated_dispatch(b, self.metrics)
@@ -235,10 +193,6 @@ class TpuWholeStageExec(FusedPipelineExec):
             # captured BEFORE the dispatch: a donating executable
             # consumes the batch, so no metadata read may follow it
             self.count_input(batch.capacity)
-            in_bytes = batch.device_size_bytes() if moderate else 0
-            in_rows = (batch.known_rows if batch.known_rows is not None
-                       else batch.capacity) if moderate else 0
-            dispatch_cost[0] = {}
             with named_range(f"whole_stage_{self.stage_id}", self.metrics,
                              MN.TOTAL_TIME):
                 try:
@@ -254,59 +208,13 @@ class TpuWholeStageExec(FusedPipelineExec):
                     journal_event("fallback", self.name,
                                   reason="stage_retry_exhausted",
                                   stage=self.stage_id)
-                    # the failed fused dispatch's HLO cost must not be
-                    # declared for the de-fused execution that actually
-                    # ran — fall back to the footprint estimate
-                    dispatch_cost[0] = {}
                     outs = self._run_ops_one_at_a_time(ctx, batch)
-            if moderate:
-                self._declare_batch_cost(in_rows, outs, in_bytes,
-                                         dispatch_cost[0], cost_totals)
             for out in outs:
                 record_output_batch(self.metrics, out, ctx.runtime)
                 yield out
         journal_event("stage", f"wholeStage-{self.stage_id}",
                       ops=[s.name for s in self.stages],
                       batches=n_batches)
-        if moderate and n_batches:
-            # one cost record per stage execution: the HLO-derived (or
-            # estimated) declaration the offline roofline report joins
-            # against this stage's operator spans
-            journal_event(
-                "cost", f"wholeStage-{self.stage_id}",
-                node=getattr(self, "_node_id", None),
-                flops=round(cost_totals["flops"]),
-                hbm_bytes=round(cost_totals["bytes"]),
-                source="hlo" if cost_totals["hlo_batches"] else "est",
-                batches=n_batches)
-
-    def _declare_batch_cost(self, in_rows: int, outs, in_bytes: int,
-                            cost: dict, totals: dict) -> None:
-        """Roofline cost declaration for one dispatched batch: XLA's
-        cost analysis of the compiled stage program when available
-        (flops + total bytes accessed; the output share is already
-        record_output_batch's hbmBytesWritten, so only the remainder
-        lands on hbmBytesRead), else the input footprint + an
-        expression-tree flop estimate.  Takes the input's rows/bytes
-        METADATA captured before the dispatch — a donating executable
-        consumed the batch itself (TPU008)."""
-        written = sum(o.device_size_bytes() for o in outs)
-        if cost:
-            flops = cost["flops"]
-            hbm_read = max(in_bytes, int(cost["bytes"]) - written)
-            totals["flops"] += flops
-            totals["bytes"] += cost["bytes"]
-            totals["hlo_batches"] += 1
-        else:
-            if self._flops_per_row is None:
-                from ..metrics.roofline import estimate_expr_flops
-                self._flops_per_row = max(1, estimate_expr_flops(
-                    self.expressions()))
-            flops = self._flops_per_row * in_rows
-            hbm_read = in_bytes
-            totals["flops"] += flops
-            totals["bytes"] += in_bytes + written
-        record_cost(self.metrics, hbm_read=hbm_read, flops=flops)
 
     # ---- fallback ladder ---------------------------------------------------
 

@@ -925,15 +925,14 @@ class TpuFileScanExec(TpuExec):
         yield from self._host_batches(self.files, ctx)
 
     def execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
-        from ..exec.base import record_cost
+        from ..exec.base import record_movement
         produced = False
         for batch in self._batches(ctx):
             produced = True
-            # roofline: every scan batch crossed the host->device link
-            # and landed in HBM, whichever decode branch produced it
-            # (device_size_bytes is shape metadata, never a sync)
-            sz = batch.device_size_bytes()
-            record_cost(self.metrics, h2d=sz, hbm_written=sz)
+            # every scan batch crossed the host->device link, whichever
+            # decode branch produced it (device_size_bytes is shape
+            # metadata, never a sync)
+            record_movement(self.metrics, h2d=batch.device_size_bytes())
             yield batch
         if not produced:
             yield ColumnarBatch.from_pydict(

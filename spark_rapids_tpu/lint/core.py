@@ -305,12 +305,11 @@ def discover_files(paths: Sequence[str], root: str) -> List[str]:
 
 def default_paths(root: str) -> List[str]:
     """The project surface the standing gate covers: the package, the
-    test suite, the bench harness and scripts."""
+    test suite, the query packages and scripts."""
     cands = [os.path.join(root, "spark_rapids_tpu"),
              os.path.join(root, "tests"),
              os.path.join(root, "benchmarks"),
-             os.path.join(root, "scripts"),
-             os.path.join(root, "bench.py")]
+             os.path.join(root, "scripts")]
     return [c for c in cands if os.path.exists(c)]
 
 

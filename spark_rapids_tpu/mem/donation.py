@@ -52,7 +52,7 @@ _PINNED: "weakref.WeakSet" = weakref.WeakSet()
 _DONATED: "weakref.WeakSet" = weakref.WeakSet()
 _LOCK = threading.Lock()
 
-# process-wide counters (bench.py reads donated_buffers around warm runs;
+# process-wide counters (tests read donated_buffers around warm runs;
 # mirrors kernel_cache._COUNTERS style)
 _COUNTERS = {"donated_dispatches": 0, "donated_buffers": 0, "pinned": 0}
 
@@ -109,9 +109,9 @@ def record_donation(n_buffers: int) -> None:
 def record_donated_dispatch(batch_or_count, metrics=None) -> int:
     """One-stop bookkeeping for a dispatch that donates `batch_or_count`
     (a ColumnarBatch whose leaves are all donated, or an explicit leaf
-    count): this registry's counters, the kernel-cache counter bench.py
-    reads (donated_copies_warm_run), and the dispatching operator's
-    numDonatedBuffers metric.  Returns the leaf count."""
+    count): this registry's counters, the kernel-cache counter, and the
+    dispatching operator's numDonatedBuffers metric.  Returns the leaf
+    count."""
     if isinstance(batch_or_count, int):
         n = batch_or_count
     else:

@@ -103,8 +103,8 @@ class DeviceMemoryEventHandler:
         buffer ids this round's synchronous_spill evicted."""
         store_size = self.device_store.current_size
         target = max(0, store_size - alloc_size)
-        # spillTime: the 'spill' phase of the serving SLO histograms and
-        # the roofline ledger's wait-vs-work split.  Also accumulated on
+        # spillTime: the 'spill' phase of the serving SLO histograms.
+        # Also accumulated on
         # the CALLING thread's query scope — the runtime metric is
         # shared, so under concurrent serving only the scope can say
         # WHICH query's reservation paid the cascade.

@@ -191,7 +191,8 @@ class BufferStore:
     def _priority_of(self, buffer_id: int):
         # (priority, id): equal-priority victims order by id — creation
         # order — NOT heap/dict insertion accidents, so victim sequences
-        # (and BENCH_PRESSURE churn rows) reproduce across processes
+        # (and the memory ledger's churn records) reproduce across
+        # processes
         b = self._buffers[buffer_id]
         return (b.spill_priority, buffer_id)
 

@@ -18,13 +18,6 @@ the multi-pid Chrome trace (one lane per worker, flow arrows).
 peak attribution, spill cascade chains (triggering reservation site +
 victim buffer ids), churn ratio, victim quality, and the headroom
 estimate (metrics/memledger.py).
-`python -m spark_rapids_tpu.metrics roofline <journal-dir>
-[--device-kind KIND] [--json]` — reconstruct each query journal's
-roofline-attribution ledger offline (metrics/roofline.py): operator
-spans give measured per-node SELF seconds, the finish-time metric dumps
-give each node's cost declaration (bytes per resource + estimated
-flops), and the report names every plan node's bottleneck resource with
-achieved-vs-peak utilization.
 `python -m spark_rapids_tpu.metrics postmortem <bundle-dir> [--json]` —
 render a post-mortem diagnostics bundle (metrics/bundle.py:
 dump_diagnostics output, auto-dumped on query failure / hung-task
@@ -95,8 +88,8 @@ def monitoring_doc() -> str:
         "  structured access; `.prometheus()` returns a Prometheus",
         "  text-format dump of the query.",
         "* `session.query_metrics_total` accumulates counters across every",
-        "  query the session ran (bench.py reports these in its",
-        "  `observability` block).",
+        "  query the session ran (the benchmark's per-query counters read",
+        "  it).",
         "",
         "## Event journal",
         "",
@@ -151,12 +144,6 @@ def monitoring_doc() -> str:
         "  budget) — how a chaos-soak round's recovery story reads back",
         "  (docs/tuning-guide.md, Fault tolerance, speculation, and",
         "  chaos testing).",
-        "* Whole-stage fused programs append one `cost` instant per",
-        "  stage execution — XLA's HLO cost analysis (flops + bytes",
-        "  accessed, `source=hlo`) or the batch-footprint estimate",
-        "  (`source=est`) — the declaration the offline roofline report",
-        "  joins against that stage's operator spans (see 'Reading the",
-        "  roofline ledger').",
         "* Mesh-lowered shuffle exchanges bracket every compiled",
         "  all-to-all dispatch in a `collective` span (attrs `shuffle`,",
         "  `map`, `devices`, `quota`) — the mesh tier's twin of the",
@@ -169,54 +156,27 @@ def monitoring_doc() -> str:
         "  spill victim picks, with the baseline victim and whether the",
         "  policy overrode it), `unspill` (proactive unspills charged to",
         "  the owning query), `backpressure` (serve/fetch stalls against",
-        "  the reduce-rate window) and `codec` (roofline-driven wire",
-        "  codec re-selection).  The `--memory` replay renders them as a",
+        "  the reduce-rate window) and `codec` (runtime wire codec",
+        "  re-selection).  The `--memory` replay renders them as a",
         "  'policy decisions' section from the journal shards alone",
         "  (docs/tuning-guide.md, Data-movement policy).",
         "* `spark_rapids_tpu.metrics.read_journal(path)` parses a file;",
         "  `journal.validate_events(events)` checks the schema.",
         "",
-        "## Reading the roofline ledger",
+        "## Data-movement counters",
         "",
-        "Every device operator declares the bytes it moves per RESOURCE",
-        "(`hbm` read+written, `h2d`/`d2h` link transfers, `wire` shuffle",
-        "bytes, `ici` mesh-collective bytes) plus an estimated FLOP",
-        "count (`estFlops`); whole-stage",
-        "fused programs derive theirs from XLA's cost analysis on the",
-        "compiled HLO (journal kind `cost`, `source=hlo`).  The roofline",
-        "ledger (metrics/roofline.py) joins each node's declaration",
-        "against its measured span seconds:",
-        "",
-        "* per resource, `lower_bound_s = bytes / peak` — the time the",
-        "  node would take if that resource ran at its roofline;",
-        "* the node's **bottleneck** is the resource with the largest",
-        "  lower bound; `utilization = lower_bound / measured_seconds`",
-        "  (1.0 = running AT the roofline, 0.001 = 99.9% of the wall",
-        "  time is unexplained by data movement — dispatch overhead,",
-        "  host orchestration, compile);",
-        "* a node labeled `host` declared no device cost at all",
-        "  (limits, unions, cache-served scans) — host-orchestration",
-        "  bound by construction;",
-        "* `h2d`/`d2h`-bound nodes are HOST DETOURS: data crossing the",
-        "  link that device-side decode or device-resident shuffle",
-        "  would keep in HBM.  `hbm`-bound nodes with LOW utilization",
-        "  are kernel-bound: the kernel runs far off the memory",
-        "  roofline (the kernel/fusion backlog); with HIGH utilization",
-        "  they are genuinely bandwidth-bound — the good end state.",
-        "",
-        "Surfaces: `explain_with_metrics()` appends",
-        "`<- hbm-bound (X GB/s, Y% of peak)` per node;",
-        "`session.last_execution.roofline_ledger()` returns the rows;",
-        "`python -m spark_rapids_tpu.metrics roofline <journal-dir>`",
-        "reconstructs the same report offline from query journals; and",
-        "bench.py's profile stage writes BENCH_PROFILE.json, which",
-        "`scripts/profile_regression.py` gates CI against (tolerance-",
-        "checked achieved bandwidth + SLO phase latencies; `--bless`",
-        "updates the checked-in baseline intentionally).  Peaks come",
-        "from the platform table or the",
-        "`spark.rapids.sql.tpu.roofline.peak*` confs",
-        "(docs/tuning-guide.md maps each ledger signal to the knob that",
-        "moves it).",
+        "At `metrics.level` MODERATE and above the operators count the",
+        "bytes they move off HBM, from batch metadata (capacities x",
+        "dtype widths, never a device read): `h2dBytes` (scan uploads,",
+        "host->device transitions, socket-tier shuffle reads), `d2hBytes`",
+        "(results, broadcast collects, exchange map writes), `wireBytes`",
+        "(socket shuffle wire and broadcast payloads) and `iciBytesMoved`",
+        "(mesh-exchange collectives and the SPMD aggregate, join and",
+        "sort's row exchanges).  The benchmark reads the first two and",
+        "the last per query (`chipbench/layer_metrics/h2d_mb_per_query`,",
+        "`d2h_mb_per_query`, `ici_mb_per_query`); the codec advisor",
+        "(policy/codec.py) judges wire bytes against its wire peak,",
+        "`spark.rapids.sql.tpu.roofline.peakWireGBs`.",
         "",
         "## Serving SLO histograms",
         "",
@@ -283,7 +243,7 @@ def monitoring_doc() -> str:
         "watchdog fire, retry-budget exhaustion, or SIGUSR1, the driver",
         "assembles one self-contained bundle directory",
         "(`postmortem-<seq>-<reason>-<pid>/`) holding manifest.json,",
-        "config.json, explain.txt (EXPLAIN + roofline attribution),",
+        "config.json, explain.txt (EXPLAIN with per-node metrics),",
         "progress/observability/slo JSON, timeline.json (merged cluster",
         "timeline), memledger.txt, samples.json (sampler series),",
         "ring-driver.jsonl and one ring-<executor>.jsonl per worker",
@@ -584,71 +544,6 @@ def memory_main(argv) -> int:
     return 0
 
 
-def roofline_main(argv) -> int:
-    """`roofline` driver: per-query bottleneck-resource ledgers offline
-    from a journal directory's query-*.jsonl files."""
-    import json as _json
-
-    from .roofline import ledger_from_events, platform_peaks, render
-    from .timeline import load_journal_dir
-    usage = ("usage: python -m spark_rapids_tpu.metrics roofline "
-             "<journal-dir> [--device-kind KIND] [--json]")
-    device_kind = None
-    as_json = False
-    jdir = None
-    i = 0
-    while i < len(argv):
-        arg = argv[i]
-        if arg == "--device-kind":
-            if i + 1 >= len(argv):
-                print(usage, file=sys.stderr)
-                return 2
-            device_kind = argv[i + 1]
-            from .roofline import known_device_kinds
-            if device_kind not in known_device_kinds():
-                # another device's peaks would skew every utilization
-                # figure by orders of magnitude
-                print(f"unknown --device-kind {device_kind!r}; expected "
-                      f"one of {' / '.join(known_device_kinds())}",
-                      file=sys.stderr)
-                return 2
-            i += 2
-        elif arg == "--json":
-            as_json = True
-            i += 1
-        elif arg.startswith("--") or jdir is not None:
-            print(usage, file=sys.stderr)
-            return 2
-        else:
-            jdir = arg
-            i += 1
-    if jdir is None:
-        print(usage, file=sys.stderr)
-        return 2
-    shards = [sh for sh in load_journal_dir(jdir)
-              if sh.get("base") == "driver"]
-    if not shards:
-        print(f"no query-*.jsonl journals in {jdir}", file=sys.stderr)
-        return 1
-    peaks = platform_peaks(device_kind)
-    reports = []
-    for sh in shards:
-        rows = ledger_from_events(sh["events"], peaks)
-        if not rows:
-            continue
-        reports.append({"journal": sh["label"], "ledger": rows})
-        if not as_json:
-            print(render(rows, peaks, title=f"roofline {sh['label']}"))
-    if as_json:
-        print(_json.dumps({"peaks": peaks, "queries": reports}, indent=1))
-    elif not reports:
-        print(f"journals in {jdir} carry no operator spans or metric "
-              "dumps (was the query run with a journal dir configured?)",
-              file=sys.stderr)
-        return 1
-    return 0
-
-
 def postmortem_main(argv) -> int:
     """`postmortem` driver: render a diagnostics bundle directory
     (metrics/bundle.dump_diagnostics output) as the human report, or
@@ -692,8 +587,6 @@ if __name__ == "__main__":
     if "--memory" in sys.argv:
         idx = sys.argv.index("--memory")
         sys.exit(memory_main(sys.argv[idx + 1:]))
-    if "roofline" in sys.argv[1:2]:
-        sys.exit(roofline_main(sys.argv[2:]))
     if "postmortem" in sys.argv[1:2]:
         sys.exit(postmortem_main(sys.argv[2:]))
     print(write_monitoring_docs())

@@ -8,8 +8,8 @@ failure:
                        status (a section that failed to assemble is
                        recorded, never fatal)
   config.json          the session's effective conf settings
-  explain.txt          EXPLAIN with per-node metrics + roofline
-                       attribution of the failing query
+  explain.txt          EXPLAIN with per-node metrics of the failing
+                       query
   progress.json        session/cluster progress() at dump time
   observability.json   metrics.export.session_observability
   slo.json             serving-tier scheduler stats + SLO report

@@ -391,9 +391,9 @@ def prometheus_gauge_dump(values: Dict[str, float],
 # -- bench/session rollup ----------------------------------------------------
 
 def session_observability(session) -> dict:
-    """One flat dict of the counters a benchmark row should carry
-    (bench.py `observability` block): CPU fallbacks, retry/split totals,
-    spill/pool figures, and wire bytes when a cluster is attached."""
+    """One flat dict of the session's observability counters: CPU
+    fallbacks, retry/split totals, spill/pool figures, and wire bytes
+    when a cluster is attached."""
     totals = dict(getattr(session, "query_metrics_total", {}) or {})
     out = {
         "numCpuFallbacks": int(totals.get(N.NUM_CPU_FALLBACKS, 0)),
@@ -514,10 +514,10 @@ def session_observability(session) -> dict:
 
 
 def session_adaptive(session) -> dict:
-    """Adaptive-execution rollup for bench.py's `adaptive` stage (rides
-    next to the `observability` block in the BENCH_* artifacts):
-    coalesce/skew/strategy-change counts, observed map-output bytes, and
-    stage re-plan latency accumulated across the session's queries."""
+    """Adaptive-execution rollup of the session (rides next to
+    session_observability): coalesce/skew/strategy-change counts,
+    observed map-output bytes, and stage re-plan latency accumulated
+    across the session's queries."""
     totals = dict(getattr(session, "query_metrics_total", {}) or {})
     return {
         "numCoalescedPartitions":

@@ -83,18 +83,13 @@ EVENT_KINDS = ("query", "stage", "operator", "retry", "spill", "fetch",
                # degradation after the replacement budget) — attrs name
                # the stage, task index, attempt id and executor
                "spec",
-               # cost = a roofline cost declaration (metrics/roofline.py):
-               # a whole-stage program's XLA-HLO-derived flops/bytes (one
-               # instant per executed stage, attrs flops/hbm_bytes/source)
-               # joined offline against the operator spans by the
-               # `python -m spark_rapids_tpu.metrics roofline` report
-               "cost",
                # policy = one data-movement policy decision (policy/):
                # victim (scored spill pick, with the baseline choice it
                # kept or overrode), unspill (proactive re-materialize,
                # attrs buffer/bytes/owner), backpressure (a flow-control
-               # admission stall, attrs where/window), codec (a roofline-
-               # proven wire-bound exchange flipping the fetch codec) —
+               # admission stall, attrs where/window), codec (an
+               # exchange proven wire-bound at runtime flipping the fetch
+               # codec) —
                # replayed by `python -m spark_rapids_tpu.metrics --memory`
                "policy",
                # lifecycle = one query-lifecycle decision

@@ -588,23 +588,11 @@ NUM_STATE_RECOVERIES = register_metric(
     "last committed checkpoint epoch instead of a cold full recompute "
     "(streaming/checkpoint.py recovery path)")
 
-# --- roofline cost declarations (metrics/roofline.py) ------------------------
-# Every device operator declares the bytes it moves per RESOURCE and an
-# estimated FLOP count; the roofline ledger joins these declarations
-# against measured span durations to compute achieved-vs-peak utilization
-# and name each plan node's bottleneck resource.  All are free host-side
-# increments computed from batch METADATA (capacity/dtype sizes — never a
-# device sync), gated MODERATE because they are only meaningful next to
-# the MODERATE timers they are divided by.
-HBM_BYTES_READ = register_metric(
-    "hbmBytesRead", COUNTER, MODERATE,
-    "declared bytes read from HBM by the operator's device kernels "
-    "(input batch footprints; whole-stage programs use XLA's cost "
-    "analysis on the compiled HLO minus the output share)")
-HBM_BYTES_WRITTEN = register_metric(
-    "hbmBytesWritten", COUNTER, MODERATE,
-    "declared bytes written to HBM (output batch footprints, recorded "
-    "with every record_output_batch)")
+# --- data movement (exec/base.record_movement) -------------------------------
+# Bytes an operator moved across the host<->device link, the socket
+# shuffle wire and the chips' interconnect.  Free host-side increments
+# computed from batch METADATA (capacity/dtype sizes — never a device
+# sync), gated MODERATE like the timers they sit beside.
 H2D_BYTES = register_metric(
     "h2dBytes", COUNTER, MODERATE,
     "bytes moved host->device over the link (scan adoption, shuffle "
@@ -619,18 +607,13 @@ WIRE_BYTES = register_metric(
     "wire — exchange map writes, shuffle reads, broadcast payloads")
 ICI_BYTES_MOVED = register_metric(
     "iciBytesMoved", COUNTER, MODERATE,
-    "LOGICAL bytes routed through mesh-exchange collectives (the 'ici' "
-    "roofline resource) — the same codec-invariant figure the AQE map "
-    "statistics carry, so the mesh and socket tiers declare comparable "
-    "data movement for the same exchange; the SPMD aggregate, join and "
-    "sort declare the bytes their row exchanges' collectives move, from "
-    "metadata (rows per peer x row width x n x (n-1): the quota block of "
-    "the all-to-all, the whole shard of the all-gather variant)")
-EST_FLOPS = register_metric(
-    "estFlops", COUNTER, MODERATE,
-    "estimated floating/integer operations executed by the operator's "
-    "device kernels; whole-stage programs report XLA's HLO cost "
-    "analysis, other operators an expression-tree estimate x rows")
+    "LOGICAL bytes routed through mesh-exchange collectives — the same "
+    "codec-invariant figure the AQE map statistics carry, so the mesh "
+    "and socket tiers declare comparable data movement for the same "
+    "exchange; the SPMD aggregate, join and sort declare the bytes "
+    "their row exchanges' collectives move, from metadata (rows per "
+    "peer x row width x n x (n-1): the quota block of the all-to-all, "
+    "the whole shard of the all-gather variant)")
 SPILL_TIME = register_metric(
     "spillTime", TIMER, MODERATE,
     "wall-clock time spent inside synchronous spill cascades (the "

@@ -14,8 +14,8 @@ After the query runs, the same object is the reporting surface:
     when `spark.rapids.sql.explain=METRICS`);
   * `prometheus()` — Prometheus text-format dump of every node metric plus
     the runtime pool/retry counters (export.py);
-  * `node_metrics()` / `aggregate()` — structured access for bench.py and
-    the tests.
+  * `node_metrics()` / `aggregate()` — structured access for the tests
+    and tools.
 
 Instrumentation notes: `execute` wrappers are plain functions that emit
 the span-begin eagerly at CALL time and delegate to the original
@@ -54,7 +54,6 @@ class QueryExecution:
         self.runtime = runtime
         self.conf = conf
         self.level = parse_level(conf.get(C.METRICS_LEVEL))
-        self._roofline = bool(conf.get(C.ROOFLINE_ENABLED))
         jdir = str(conf.get(C.METRICS_JOURNAL_DIR) or "")
         self.journal: Optional[EventJournal] = None
         self._owns_journal = True
@@ -287,11 +286,9 @@ class QueryExecution:
                 parts.append(f"{k}: {v:.3f}")
         return f" [{', '.join(parts)}]" if parts else ""
 
-    def _render(self, node, indent: int, lines: List[str],
-                annotations: Optional[Dict[int, str]] = None) -> None:
-        note = (annotations or {}).get(getattr(node, "_node_id", None), "")
+    def _render(self, node, indent: int, lines: List[str]) -> None:
         lines.append(" " * indent + node.describe()
-                     + self._fmt_metrics(node.metrics.snapshot()) + note)
+                     + self._fmt_metrics(node.metrics.snapshot()))
         if hasattr(node, "op_rows"):
             # whole-stage fused node: render the constituent operators
             # with their *(N) prefix and the stage-level counts folded
@@ -300,44 +297,16 @@ class QueryExecution:
                 lines.append(" " * (indent + 2) + desc
                              + self._fmt_metrics(m.snapshot()))
         for c in node.children:
-            self._render(c, indent + 2, lines, annotations)
-
-    def roofline_ledger(self, peaks: Optional[Dict[str, float]] = None
-                        ) -> List[dict]:
-        """The roofline-attribution ledger of this query: one row per
-        plan node joining its cost declaration (bytes per resource +
-        estimated flops) against its measured span seconds, naming the
-        bottleneck resource and achieved-vs-peak utilization
-        (metrics/roofline.py; docs/monitoring.md, 'Reading the roofline
-        ledger')."""
-        from .roofline import ledger_from_execution
-        return ledger_from_execution(self, peaks=peaks)
-
-    def _roofline_annotations(self) -> Dict[int, str]:
-        """{node_id: explain suffix} when the roofline layer is on and
-        cost declarations were recorded (MODERATE+)."""
-        if not self._roofline or self.level < N.MODERATE:
-            return {}
-        try:
-            from .roofline import explain_annotation, platform_peaks
-            peaks = platform_peaks(conf=self.conf)
-            return {row["node"]: explain_annotation(row, peaks)
-                    for row in self.roofline_ledger(peaks)}
-        except Exception:  # noqa: BLE001 — annotation is best-effort
-            return {}
+            self._render(c, indent + 2, lines)
 
     def explain_with_metrics(self) -> str:
         """The executed plan tree with each node's accumulated metrics —
-        what the reference surfaces per-node in the Spark SQL UI — plus
-        each node's roofline bottleneck annotation (bottleneck resource,
-        achieved rate, utilization vs peak) when the roofline layer is
-        enabled."""
+        what the reference surfaces per-node in the Spark SQL UI."""
         lines = [f"== Query {self.query_id} "
                  f"({N.LEVEL_NAMES[self.level]}"
                  + (f", {self.duration:.3f}s" if self.duration is not None
                     else "") + ") =="]
-        self._render(self.physical, 0, lines,
-                     self._roofline_annotations())
+        self._render(self.physical, 0, lines)
         delta = self.runtime_delta()
         if delta:
             parts = ", ".join(f"{k}: {int(v) if v == int(v) else v}"

@@ -243,7 +243,11 @@ def _spark_hash_string(col: Column, seed):
 
         active = (jnp.arange(nblocks_max, dtype=jnp.int32)[None, :]
                   < nblocks[:, None])
-        h, _ = jax.lax.scan(step, h0, (words.T, active.T))
+        # the carry takes the rows' type: inside a shard_map the rows vary
+        # along the mesh axis and a constant seed does not, and scan wants
+        # the carry's type to equal the body's output's
+        h, _ = jax.lax.scan(step, h0 + jnp.zeros_like(lens, jnp.uint32),
+                            (words.T, active.T))
     else:
         h = h0
     # tail: Spark's hashUnsafeBytes mixes each remaining byte individually

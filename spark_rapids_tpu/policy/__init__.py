@@ -1,9 +1,9 @@
 """Data-movement policy engine: the decision tier over the measured
 memory hierarchy (ROADMAP item 3).
 
-PR 8's memory-pressure ledger and PR 13's roofline ledger made every
-movement decision *measurable* — spill churn, victim re-touch quality,
-headroom, per-node bottleneck resource — but the decisions themselves
+The memory-pressure ledger and the operators' byte counters made
+every movement decision *measurable* — spill churn, victim re-touch
+quality, headroom, bytes over the wire — but the decisions themselves
 stayed blind: victims were picked purely by (priority, id) order,
 unspill was reactive, a slow reduce side could balloon host memory, and
 the shuffle codec was fixed at plan time.  This package closes the
@@ -21,7 +21,7 @@ to the pre-policy engine):
   * end-to-end flow control (flow.py FlowController): map-side serve and
     `fetch_partitions_async` admission ride a windowed in-flight-bytes
     budget driven by the reduce side's observed consumption rate;
-  * roofline-driven codec re-selection (codec.py CodecAdvisor): an
+  * runtime codec re-selection (codec.py CodecAdvisor): an
     exchange proven wire-bound at runtime flips none->lz4/zstd through
     the PR 5 negotiation path for subsequent fetches.
 

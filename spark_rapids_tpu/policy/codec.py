@@ -1,17 +1,16 @@
-"""Roofline-driven codec re-selection: wire-bound exchanges flip the
-shuffle codec at runtime.
+"""Runtime codec re-selection: wire-bound exchanges flip the shuffle
+codec at runtime.
 
 The static plan fixes the wire codec at session start
-(spark.rapids.shuffle.compression.codec, default none).  The roofline
-ledger (PR 13) can *prove* at runtime that an exchange was wire-bound —
-its read phase moved bytes at a significant fraction of the platform's
-wire peak — which is exactly the regime where paying codec CPU to shrink
-wire bytes wins.  `CodecAdvisor` watches each exchange's observed read
-throughput against `platform_peaks()["wire"]` and, once an exchange
-crosses the wire-bound threshold at sufficient volume, advises the
-configured candidate codec (none->lz4/zstd) for that shuffle id AND for
-subsequent exchanges of the session (sticky, the same way AQE re-plans
-on observed sizes).
+(spark.rapids.shuffle.compression.codec, default none).  An exchange whose
+read phase moved bytes at a significant fraction of the wire peak is
+wire-bound — exactly the regime where paying codec CPU to shrink wire
+bytes wins.  `CodecAdvisor` watches each exchange's observed read
+throughput against the wire peak (roofline.peakWireGBs, 1 GB/s unless
+set) and, once an exchange crosses the wire-bound threshold at
+sufficient volume, advises the configured candidate codec
+(none->lz4/zstd) for that shuffle id AND for subsequent exchanges of the
+session (sticky, the same way AQE re-plans on observed sizes).
 
 The advice rides the existing PR 5 negotiation path end to end: the
 reader names the advised codec in its MetadataRequest, the server
@@ -39,20 +38,15 @@ class CodecAdvisor:
         self.candidate = str(conf.get(C.POLICY_CODEC)).lower()
         self.min_bytes = int(conf.get(C.POLICY_CODEC_MIN_BYTES))
         self.bound_fraction = float(conf.get(C.POLICY_CODEC_WIRE_BOUND))
+        # bytes/s; the default is a loopback socket's rate
+        peak_gbs = float(conf.get(C.POLICY_CODEC_WIRE_PEAK))
+        self.wire_peak = (peak_gbs if peak_gbs > 0 else 1.0) * 1e9
         self.metrics = metrics
         self._conf = conf
         self._lock = threading.Lock()
         self._overrides: Dict[int, str] = {}
         self._sticky: Optional[str] = None
         self._reader_policy = None
-        self._wire_peak: Optional[float] = None
-
-    def _peak(self) -> float:
-        if self._wire_peak is None:
-            from ..metrics.roofline import platform_peaks
-            peaks = platform_peaks(conf=self._conf)
-            self._wire_peak = float(peaks.get("wire") or 0.0)  # tpulint: disable=TPU009 idempotent lazy cache: every racer computes the same conf-derived value, so the last write is indistinguishable from the first
-        return self._wire_peak
 
     def observe_exchange(self, shuffle_id: int, wire_bytes: int,
                          seconds: float) -> bool:
@@ -61,10 +55,7 @@ class CodecAdvisor:
         if self.candidate in ("", "none") or seconds <= 0 \
                 or wire_bytes < self.min_bytes:
             return False
-        peak = self._peak()
-        if peak <= 0:
-            return False
-        utilization = (wire_bytes / seconds) / peak
+        utilization = (wire_bytes / seconds) / self.wire_peak
         if utilization < self.bound_fraction:
             return False
         from ..compress import is_codec_available

@@ -31,7 +31,7 @@ flight.  Two admission points ride the window:
     the bytes and feeds the rate.
 
 Stalls are counted (numBackpressureStalls) and journaled (kind `policy`,
-name `backpressure`) so BENCH_WIRE / the memory CLI can attribute them.
+name `backpressure`) so the memory CLI can attribute them.
 """
 from __future__ import annotations
 

@@ -7,8 +7,8 @@ per-query memory budgets feeding the existing reserve()/RetryOOM spill
 machinery, and a parameterized plan cache that lifts literals out of
 physical plans so the 2nd..Nth literal-variant submission replays the
 1st submission's traced+compiled whole-stage executables instead of
-paying warmup again (BENCH_HEADLINE: q1 spends 27.9s compiling vs 1.3s
-executing — the cache is what makes a second user cheap).
+paying warmup again (a cold query's compile time dwarfs its execution;
+the cache is what makes a second user cheap).
 
 Entry point: `TpuSession.submit(df, priority=..., memory_need=...,
 deadline_ms=...)` returns a `QueryFuture`; the blocking `collect()`

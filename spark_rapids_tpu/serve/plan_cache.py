@@ -1,7 +1,7 @@
 """Parameterized plan cache: normalize literals out of a query plan.
 
-The warmup killer (ROADMAP item 2): BENCH_HEADLINE shows q1 spending
-27.9s compiling vs 1.3s executing, and a second user running the SAME
+The warmup killer (ROADMAP item 2): a cold query spends far longer
+compiling than executing, and a second user running the SAME
 query shape with different literals (a different date cutoff, a
 different discount band) pays the whole warmup again, because literal
 values are baked into every kernel-cache key (utils/kernel_cache.expr_key
@@ -32,7 +32,7 @@ This module fixes the second user:
 
   * `PlanCache` is the bookkeeping layer the QueryScheduler consults:
     LRU-bounded entries, hit/miss/lifted counters (surfaced as
-    planCacheHits/planCacheMisses metrics and in BENCH_SERVE.json).
+    planCacheHits/planCacheMisses metrics).
     Execution ALWAYS uses the incoming normalized plan — never a cached
     object — so a fingerprint collision can only miscount, never
     mis-execute, and concurrent submissions share no mutable plan state.

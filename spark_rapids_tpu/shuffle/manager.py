@@ -611,7 +611,7 @@ class ShuffleEnv:
                 tcomp = getattr(self.transport, "compression", None)
                 client = self.transport.make_client(peer)
                 if tcomp is None or not tcomp.enabled:
-                    # roofline-driven re-selection (policy/codec.py): a
+                    # runtime re-selection (policy/codec.py): a
                     # session WITHOUT configured wire compression rides
                     # the advised codec through the same negotiation;
                     # clients are per-fetch objects, so the override

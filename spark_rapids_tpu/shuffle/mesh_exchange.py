@@ -1,7 +1,7 @@
 """Mesh-native generic exchange: the ICI lowering of TpuShuffleExchangeExec.
 
-ROADMAP item 1.  The socket tier pays D2H -> wire (~1 GB/s loopback per
-BENCH_WIRE) -> H2D for every generic exchange even when every
+ROADMAP item 1.  The socket tier pays D2H -> wire (~1 GB/s on loopback)
+-> H2D for every generic exchange even when every
 participating partition lives on devices of ONE jax Mesh — exactly the
 data-movement tax the reference's UCX shuffle plugin exists to eliminate.
 This module keeps the data in device memory instead: when the exchange's

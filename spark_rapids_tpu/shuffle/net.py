@@ -1052,7 +1052,7 @@ class SocketTransport(ShuffleTransport):
             pool_size = int(C.SHUFFLE_BOUNCE_POOL_SIZE.default)
         if chunk_size is None:
             chunk_size = int(C.SHUFFLE_BOUNCE_CHUNK_SIZE.default)
-        # measured on 128MB partitions (BENCH_WIRE.json): the pipelined
+        # measured on 128MB partitions on a CPU host: the pipelined
         # chunked stream does ~1.05 GB/s on loopback while the serial
         # fill-then-copy shm path does ~0.7 GB/s — so the stream is the
         # default and shm stays an option for CPU-constrained hosts

@@ -16,8 +16,7 @@ executed incrementally.  Each epoch:
      keys the stamped streaming scan by source identity + schema
      (serve/plan_cache.py), so every epoch after the first is a plan-
      cache hit replaying the already-compiled stages: warm epochs
-     perform ZERO stage compiles (asserted in tests/test_streaming.py
-     and recorded in BENCH_STREAM.json).
+     perform ZERO stage compiles (asserted in tests/test_streaming.py).
   3. The delta's output is renamed positionally onto the aggregate's
      partial-state schema and folded into the device-resident state
      with the aggregate's own merge kernel (state.py).

@@ -2,8 +2,8 @@
 
 ONE idempotent helper owns jax's persistent-cache configuration so the
 knobs cannot drift between call sites: `engine.TpuSession`, the serving
-tier's QueryScheduler (a restarted server replays kernels from disk), the
-executor worker bootstrap (shuffle/worker.py) and bench.py's children.
+tier's QueryScheduler (a restarted server replays kernels from disk) and
+the executor worker bootstrap (shuffle/worker.py).
 
 Where the cache lives (the path is part of jax's cache key, so a
 directory that moves never hits):

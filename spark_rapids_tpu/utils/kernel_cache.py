@@ -38,14 +38,8 @@ _CACHE_LOCK = threading.Lock()
 _STAGE_EXECUTABLES: "OrderedDict[tuple, Callable]" = OrderedDict()
 _STAGE_EXECUTABLES_MAX = 512
 
-# XLA cost analysis of each compiled whole-stage program, keyed like
-# _STAGE_EXECUTABLES (pruned with it): {"flops": float, "bytes": float,
-# "source": "hlo"} — the roofline ledger's per-stage cost declaration
-# (metrics/roofline.py).  Empty dict when the backend exposes no
-# Compiled.cost_analysis for the program.
-_STAGE_COSTS: Dict[tuple, dict] = {}
-
-# process-wide counters bench.py's fusion/serve stages read (stats()):
+# process-wide counters (stats(); chipbench/run.py reads builds and
+# stage_compiles around its window, the tests read the rest):
 # builds = distinct jitted programs constructed through cached_kernel,
 # stage_compiles = AOT whole-stage programs compiled,
 # dispatches = per-batch device program invocations through this layer,
@@ -140,15 +134,15 @@ def named_jit(builder: Callable[[], Callable], role: str, **jit_kw):
 def record_dispatch(n: int = 1) -> None:
     # dict[k] += n is a read-modify-write: under concurrent serving the
     # scheduler's worker threads dispatch simultaneously and an unlocked
-    # fold silently loses counts (bench reads these as accept gates)
+    # fold silently loses counts (the tests assert them exactly)
     with _CACHE_LOCK:
         _COUNTERS["dispatches"] += n
 
 
 def record_donated(n_buffers: int) -> None:
     """Count input buffers donated to a compiled program (the HBM copies
-    a warm dispatch did not pay); bench.py reads this around warm runs
-    (donated_copies_warm_run) like it reads dispatches."""
+    a warm dispatch did not pay), read like dispatches around warm
+    runs."""
     with _CACHE_LOCK:
         _COUNTERS["donated_buffers"] += n_buffers
 
@@ -224,7 +218,6 @@ def stage_executable(key: tuple, builder: Callable[[], Callable],
     finally:
         if timer is not None:
             timer.__exit__(None, None, None)
-    cost = _extract_cost_analysis(fn)
     with _CACHE_LOCK:
         _COUNTERS["stage_compiles"] += 1
     if metrics is not None:
@@ -233,56 +226,17 @@ def stage_executable(key: tuple, builder: Callable[[], Callable],
                   trace_s=round(t_lowered - t0, 6),
                   compile_s=round(t_compiled - t_lowered, 6),
                   trace_only_s=round(t_traced - t0, 6),
-                  signature_leaves=len(k[1]),
-                  **({"hlo_flops": cost["flops"],
-                      "hlo_bytes": cost["bytes"]} if cost else {}))
+                  signature_leaves=len(k[1]))
     with _CACHE_LOCK:
         _STAGE_EXECUTABLES[k] = fn
-        _STAGE_COSTS[k] = cost
         while len(_STAGE_EXECUTABLES) > _STAGE_EXECUTABLES_MAX:
-            old, _ = _STAGE_EXECUTABLES.popitem(last=False)
-            _STAGE_COSTS.pop(old, None)
+            _STAGE_EXECUTABLES.popitem(last=False)
     return fn
-
-
-def _extract_cost_analysis(compiled) -> dict:
-    """XLA's cost analysis of a Compiled program, normalized to
-    {"flops", "bytes", "source"} (metrics/roofline.py consumes this as
-    the whole-stage cost declaration).  Returns {} when the backend does
-    not expose the analysis — callers fall back to the declared
-    batch-footprint estimate."""
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        if not ca:
-            return {}
-        flops = float(ca.get("flops", 0.0) or 0.0)
-        nbytes = float(ca.get("bytes accessed", 0.0) or 0.0)
-        if flops <= 0.0 and nbytes <= 0.0:
-            return {}
-        return {"flops": flops, "bytes": nbytes, "source": "hlo"}
-    except Exception:  # noqa: BLE001 — cost analysis is best-effort
-        return {}
-
-
-def stage_cost(key: tuple, args: tuple,
-               donate_argnums: tuple = ()) -> dict:
-    """The XLA cost analysis recorded when stage_executable compiled the
-    program for (key, signature-of-args) — same key mangling, so a caller
-    that just dispatched can attribute the dispatch's HLO-derived cost.
-    {} when unknown (evicted, never compiled, no cost analysis)."""
-    if donate_argnums:
-        key = key + ("donate", tuple(donate_argnums))
-    k = (key, input_signature(args))
-    with _CACHE_LOCK:
-        return _STAGE_COSTS.get(k, {})
 
 
 def clear_stage_executables() -> None:
     with _CACHE_LOCK:
         _STAGE_EXECUTABLES.clear()
-        _STAGE_COSTS.clear()
 
 
 # --- plan-cache parameter keying --------------------------------------------
@@ -386,4 +340,3 @@ def clear():
     with _CACHE_LOCK:
         _CACHE.clear()
         _STAGE_EXECUTABLES.clear()
-        _STAGE_COSTS.clear()

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import pytest
 
 SMOKE_ROWS = 6_000_000          # chip_smoke.LINEITEM_ROWS
-HBM_BYTES = 16 << 30            # one v5e chip
+HBM_CAPACITY = 16 << 30         # one v5e chip
 
 
 @pytest.fixture(scope="module")
@@ -209,7 +209,7 @@ def _compile_for_chip(prog: _Recorded, sharding):
                                  **place(prog.kwargs)).compile()
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
-            + mem.output_size_in_bytes) < HBM_BYTES
+            + mem.output_size_in_bytes) < HBM_CAPACITY
     return compiled
 
 

@@ -35,7 +35,7 @@ pytestmark = pytest.mark.memledger
 
 # the spill-cascade slice: partitioned join -> grouped agg -> sort with a
 # pool budget far below the working set, so the device->host->disk
-# cascade genuinely engages (same shape the BENCH_PRESSURE sweep runs)
+# cascade genuinely engages
 _CASCADE_CONF = {
     "spark.rapids.sql.variableFloatAgg.enabled": "true",
     "spark.rapids.memory.tpu.poolSizeBytes": str(2 << 20),

@@ -334,7 +334,8 @@ def test_chrome_trace_from_journal(tmp_path):
 
 
 def test_bench_observability_shape():
-    """bench.py's observability block: keys present and integer-valued."""
+    """The session's observability rollup: keys present and
+    integer-valued."""
     from spark_rapids_tpu.metrics.export import session_observability
     s, df = _slice_session()
     df.collect()

@@ -170,7 +170,7 @@ def test_three_surfaces_agree_on_rows_and_retry_spill_counts():
                      if name == "spark_rapids_tpu_oom_spill_retries")
     assert prom_spill == spill
 
-    # the retries also appear in the session rollup bench.py reports
+    # the retries also appear in the session's observability rollup
     from spark_rapids_tpu.metrics.export import session_observability
     obs = session_observability(s)
     assert obs["retries"] == retry_total

@@ -57,9 +57,9 @@ POLICY_OFF = {"spark.rapids.sql.tpu.policy.enabled": "false"}
 # synchronously so every assertion sees a deterministic state
 NO_THREAD = {"spark.rapids.sql.tpu.policy.unspill.intervalMs": "0"}
 
-# the spill-cascade slice (same shape test_memledger / BENCH_PRESSURE
-# run): pool budget far below the working set, so victim selection runs
-# on every reserve round
+# the spill-cascade slice (same shape test_memledger runs): pool budget
+# far below the working set, so victim selection runs on every reserve
+# round
 _CASCADE_CONF = {
     "spark.rapids.sql.variableFloatAgg.enabled": "true",
     "spark.rapids.memory.tpu.poolSizeBytes": str(2 << 20),
@@ -528,7 +528,7 @@ def test_env_async_fetch_rides_the_policy_flow_controller():
 
 
 # --------------------------------------------------------------------------
-# codec re-selection: roofline evidence -> PR 5 negotiation round trip
+# codec re-selection: wire evidence -> negotiation round trip
 # --------------------------------------------------------------------------
 
 # a wire peak so low ANY observed exchange is wire-bound
@@ -557,6 +557,14 @@ def test_codec_advisor_needs_volume_and_wire_bound_evidence():
     assert adv.wire_codec(1) is None and fast.wire_codec(2) is None
 
 
+def test_codec_advisor_wire_peak_defaults_to_one_gbs():
+    assert CodecAdvisor(TpuConf()).wire_peak == 1e9
+    adv = CodecAdvisor(TpuConf())
+    # 64 MiB in 0.1 s is 0.67 GB/s: past half the default peak
+    assert adv.observe_exchange(3, 64 << 20, 0.1) is True
+    assert adv.observe_exchange(4, 64 << 20, 1.0) is False
+
+
 def test_codec_reselection_round_trips_negotiation():
     """An advised fetch negotiates the candidate codec end to end over
     the loopback wire: rows bit-for-bit, compressed bytes counted on the
@@ -572,7 +580,7 @@ def test_codec_reselection_round_trips_negotiation():
     sid = 77
     writer.write_partition(sid, 0, 1, b)
     pol = reader.runtime.policy
-    # roofline evidence arrives (as exec/exchange.py would feed it)
+    # wire evidence arrives (as exec/exchange.py would feed it)
     assert pol.codec.observe_exchange(sid, 64 << 20, 1.0)
     assert pol.wire_codec(sid) == "lz4"
     got = [r for p in reader.fetch_partition(sid, 1,

@@ -742,7 +742,7 @@ def test_scheduler_observability_block():
 def test_kernel_cache_counters_exact_under_concurrency():
     """record_dispatch/record_donated are read-modify-writes on a module
     dict; before ISSUE 12 they ran unlocked and concurrent serving
-    threads lost increments (bench reads these as accept gates)."""
+    threads lost increments (the tests assert them exactly)."""
     from spark_rapids_tpu.utils import kernel_cache as kc
     base = kc.stats()["dispatches"]
     base_don = kc.stats()["donated_buffers"]
