@@ -382,6 +382,12 @@ def q20(t):
 
 
 def q21(t):
+    """An equivalent rewrite of query 21, not the published form: EXISTS
+    and NOT EXISTS become two counts of distinct suppliers an order (all
+    of them and the late ones), joined back and filtered.
+    `chipbench/queries/q21.py` is the published form (a `left_semi` and a
+    `left_anti` self-join of LINEITEM, each with the supplier inequality
+    as a residual condition)."""
     nation = t["nation"].filter(col("n_name") == "SAUDI ARABIA")
     f_orders = t["orders"].filter(col("o_orderstatus") == "F") \
         .select(col("o_orderkey"))

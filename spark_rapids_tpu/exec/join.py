@@ -31,7 +31,13 @@ count-then-gather join API:
      per-row `uint32` word (`ceil(max_dup / 32)` words a row).  Prefix
      sums give each row's output start and the total (the host reads the
      total with the window width and picks the power-of-two output
-     capacity bucket).
+     capacity bucket).  A join with a residual condition gathers the
+     build's columns at every step and evaluates it on the pair; its
+     probe and recount are named apart (`hashjoin_probe_residual`,
+     `hashjoin_count_residual`).  A semi or anti join needs membership
+     alone: where a window outgrows the guess (often two keys whose hash
+     prefixes collide) only its undecided rows, those without a match
+     among their first `guess` candidates, walk on (`hashjoin_rest`).
   4. GATHER, in OUTPUT space: nothing is verified twice and no loop runs.
      Every stream row with output writes its row number at its first
      output slot (one sort that compacts those rows, one scatter a batch)
@@ -74,6 +80,11 @@ from ..ops.hashing import _normalize_bits, hash_columns_double
 from ..types import Schema, StructField
 from .base import ExecContext, ExecNode, TpuExec
 from ..metrics import names as MN
+
+
+#: undecided semi / anti rows walked on their own after a failed guess;
+#: past it the whole batch is recounted
+_REST_ROWS = 1024
 
 
 def _pvary(x, axes):
@@ -327,6 +338,28 @@ class TpuHashJoinExec(TpuExec):
         starts = jnp.cumsum(counts) - counts
         return counts, starts, jnp.sum(counts), tuple(hits)
 
+    def _rest_kernel(self, first: int, max_dup: int, lbatch: ColumnarBatch,
+                     build: ColumnarBatch, bkeys, lo, hi, counts):
+        """A semi or anti join's rows the speculative walk left
+        undecided: live, no match among their first `first` candidates and
+        a window wider than that.  One single-operand sort brings up to
+        `_REST_ROWS` of them to the front, the count walk runs over those
+        rows alone at `max_dup` steps, and their counts go back in place.
+        Returns the counts and how many rows were undecided: past
+        `_REST_ROWS` the caller recounts the whole batch."""
+        cap = lbatch.capacity
+        undecided = lbatch.sel & (counts == 0) & (hi - lo > first)
+        rows = jnp.arange(cap, dtype=jnp.int32)
+        idx = jax.lax.sort(jnp.where(undecided, rows, cap), dimension=0,
+                           is_stable=False)[:min(_REST_ROWS, cap)]
+        valid = idx < cap
+        sub = lbatch.take(jnp.where(valid, idx, 0), sel=valid)
+        rest, _starts, _total, _hits = self._count_kernel(
+            max_dup, sub, build, bkeys, jnp.take(lo, idx, mode="clip"),
+            jnp.take(hi, idx, mode="clip"))
+        counts = counts.at[idx].set(rest, mode="drop")
+        return counts, jnp.sum(undecided)
+
     def _gather_kernel(self, out_cap: int, lbatch: ColumnarBatch,
                        build: ColumnarBatch, lo, counts, starts, total,
                        hits, vary_axes: tuple = ()):
@@ -478,8 +511,14 @@ class TpuHashJoinExec(TpuExec):
         key = self.kernel_key()
         build_fn = cached_kernel(key + ("build",),
                                  lambda: self._build_kernel)
-        # present and 0 where the gather answers every stream batch
+        # present and 0 where the gather answers every stream batch, and
+        # where the join has no residual condition
         self.metrics.add(MN.JOIN_PASS_THROUGH_BATCHES, 0)
+        self.metrics.add(MN.JOIN_RESIDUAL_BATCHES, 0)
+        # a residual condition's walk gathers the build's condition
+        # columns every step: its probe and recount carry names of their
+        # own (jit_join.hashjoin_probe_residual), an equi join's keep theirs
+        residual = "_residual" if self.condition is not None else ""
 
         def attempt_build(rb):
             # retry-only: the single-build-batch contract forbids
@@ -514,21 +553,41 @@ class TpuHashJoinExec(TpuExec):
             # the kernel-cache key would recompile per distinct skew.
             guess = getattr(self, "_dup_guess", 8)
             probe_fn = cached_kernel(
-                key + ("probe", guess),
+                key + ("probe" + residual, guess),
                 lambda: functools.partial(self._probe_kernel, guess))
             lo, hi, counts, starts, hits, scalars_t = probe_fn(
                 lb, build, bkeys, h1s)
             self.metrics.add(MN.JOIN_MERGED_WINDOW_BATCHES, 1)
+            if residual:
+                self.metrics.add(MN.JOIN_RESIDUAL_BATCHES, 1)
             self.metrics.add(MN.JOIN_WALK_STEPS, guess)
             self.metrics.add(MN.JOIN_HOST_SYNCS, 1)
             md, total = (int(x) for x in np.asarray(scalars_t))
             max_dup = _pow2_bucket(md)
             self._dup_guess = max_dup
-            if max_dup > guess:
+            recount = max_dup > guess
+            if recount and self.join_type in ("left_semi", "left_anti"):
+                # membership only: a row with a match among its first
+                # `guess` candidates is decided; walk the few undecided
+                # ones to the end (a wide window is often two keys whose
+                # hash prefixes collide) and keep the guess; the guess
+                # says which rows are undecided, so it keys the program
+                rest_fn = cached_kernel(
+                    key + ("rest" + residual, guess, max_dup),
+                    lambda: functools.partial(self._rest_kernel, guess,
+                                              max_dup))
+                counts, undecided_t = rest_fn(lb, build, bkeys, lo, hi,
+                                              counts)
+                self.metrics.add(MN.JOIN_WALK_STEPS, max_dup)
+                self.metrics.add(MN.JOIN_HOST_SYNCS, 1)
+                recount = int(undecided_t) > _REST_ROWS
+                if not recount:
+                    self._dup_guess = guess
+            if recount:
                 # speculation failed (skew grew): recount with the
                 # right bucket — one extra dispatch+sync, this batch
                 count_fn = cached_kernel(
-                    key + ("count", max_dup),
+                    key + ("count" + residual, max_dup),
                     lambda: functools.partial(self._count_kernel,
                                               max_dup))
                 counts, starts, total_t, hits = count_fn(
@@ -541,6 +600,8 @@ class TpuHashJoinExec(TpuExec):
                                         lambda: self._semi_kernel)
                 out = semi_fn(lb, counts)
                 self.metrics.add(MN.JOIN_SEMI_BATCHES, 1)
+                if self.join_type == "left_anti":
+                    self.metrics.add(MN.JOIN_ANTI_BATCHES, 1)
                 out = ColumnarBatch(out.columns, out.sel, self._schema)
                 return out, None, total
             out_cap = bucket_rows(max(total, 1))

@@ -409,7 +409,11 @@ def monitoring_doc() -> str:
         "module raises).  `<role>` is the cache key's string head (an",
         "operator's class name shortened: `TpuHashJoinExec` -> `hashjoin`)",
         "plus the word the call site appended after the key's last tuple",
-        "(`probe`, `merge`, `bucket`), or `stage_executable`'s `name`.  A",
+        "(`probe`, `merge`, `bucket`; a join with a residual condition",
+        "launches its probe and recount as `hashjoin_probe_residual` and",
+        "`hashjoin_count_residual`, a semi or anti join's walk of its",
+        "undecided rows as `hashjoin_rest`), or `stage_executable`'s",
+        "`name`.  A",
         "launch that carries no such name is an eager `jnp` op outside any",
         "compiled program.",
         "",

@@ -353,19 +353,37 @@ JOIN_SEMI_BATCHES = register_metric(
     "gather); added on the host where the mask's program is launched; "
     "with joinOutputSpaceBatches and joinPassThroughBatches it sums to "
     "joinMergedWindowBatches; a host integer")
+JOIN_ANTI_BATCHES = register_metric(
+    "joinAntiBatches", COUNTER, ESSENTIAL,
+    "stream batches (mesh: finished stream chunks) of a left anti join "
+    "answered by the membership mask (the rows the count walk matched "
+    "none of); a subset of joinSemiBatches; added on the host where the "
+    "mask's program is launched; a host integer")
+JOIN_RESIDUAL_BATCHES = register_metric(
+    "joinResidualBatches", COUNTER, ESSENTIAL,
+    "probed stream batches (mesh: finished stream chunks) of a join with "
+    "a residual condition, whose count walk evaluated it on every "
+    "candidate pair (exec/join.py _pair_condition_ok: one gather of the "
+    "build's columns a step; programs jit_join.hashjoin_probe_residual, "
+    "hashjoin_rest_residual and hashjoin_count_residual); present and 0 "
+    "where a join has none; "
+    "a host integer")
 JOIN_WALK_STEPS = register_metric(
     "joinWalkSteps", COUNTER, ESSENTIAL,
     "static step counts of the count walks the join launched, summed: "
     "the probe's speculative duplication bucket a stream batch, a "
-    "recount's bucket, the mesh drivers' max_dup a chunk and a retry; "
-    "each step gathers the build side's keys over the whole stream "
-    "batch, so against joinMergedWindowBatches it says how wide the "
+    "recount's bucket, a semi or anti join's walk of its undecided rows "
+    "(jit_join.hashjoin_rest: the rows alone, at most 1,024), the mesh "
+    "drivers' max_dup a chunk and a retry; each step of the others "
+    "gathers the build side's keys over the whole stream batch, so "
+    "against joinMergedWindowBatches it says how wide the "
     "walks ran; a host integer")
 JOIN_HOST_SYNCS = register_metric(
     "joinHostSyncs", COUNTER, ESSENTIAL,
     "host reads of a device value the join made (exec/join.py): the "
     "probe's two scalars a stream batch (the duplication bucket and the "
-    "output row count), a recount's total where the bucket grew, the "
+    "output row count), a recount's total or a semi or anti join's "
+    "count of undecided rows where the bucket grew, the "
     "build side's live-row count before its shrink, a full join's tail "
     "count; the join's twin of aggHostSyncs; a host integer counted where "
     "the read is made")

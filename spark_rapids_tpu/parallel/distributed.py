@@ -551,8 +551,12 @@ def distributed_join_step(join, mesh: Mesh, max_dup: int, out_cap: int,
 def _count_finished_chunk(join):
     """The host counters of one stream chunk through the SPMD probe."""
     join.metrics.add(MN.JOIN_MERGED_WINDOW_BATCHES, 1)
+    if join.condition is not None:
+        join.metrics.add(MN.JOIN_RESIDUAL_BATCHES, 1)
     if join.join_type in ("left_semi", "left_anti"):
         join.metrics.add(MN.JOIN_SEMI_BATCHES, 1)
+        if join.join_type == "left_anti":
+            join.metrics.add(MN.JOIN_ANTI_BATCHES, 1)
     else:
         join.metrics.add(MN.JOIN_OUTPUT_SPACE_BATCHES, 1)
 
